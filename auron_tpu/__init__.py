@@ -31,28 +31,6 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-# jax version-compatibility gates: this image's jax still hosts these
-# APIs under jax.experimental (they were promoted to the jax namespace
-# later).  Shim rather than pin — the engine code targets the promoted
-# names.
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _xp_shard_map
-
-    def _shard_map_compat(f, mesh, in_specs, out_specs,
-                          check_vma=None, **kw):
-        # newer kwarg name: check_vma superseded check_rep
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return _xp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-
-    jax.shard_map = _shard_map_compat
-
-if not hasattr(jax, "enable_x64"):
-    from jax.experimental import enable_x64 as _xp_enable_x64
-
-    jax.enable_x64 = _xp_enable_x64
-
 __version__ = "0.1.0"
 
 from auron_tpu.config import conf  # noqa: E402

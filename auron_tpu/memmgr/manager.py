@@ -294,16 +294,20 @@ class MemManager:
         if override:
             return override
         frac = float(conf.get("auron.memory.fraction"))
-        try:
-            import jax
-            dev = jax.devices()[0]
-            stats = dev.memory_stats() or {}
-            limit = stats.get("bytes_limit")
-            if limit:
-                return int(limit * frac)
-        except Exception:
-            pass
-        return int(4 * (1 << 30) * frac)  # fallback: 4GB-class device
+        import jax
+        dev = jax.devices()[0]
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if limit:
+            return int(limit * frac)
+        if dev.platform != "cpu":
+            # budgeting an accelerator from a guessed size either wastes
+            # most of its memory or spills far too late
+            raise RuntimeError(
+                f"{dev.device_kind} reports no memory limit "
+                f"(memory_stats() has no 'bytes_limit'); set "
+                f"auron.memory.budget.bytes")
+        # the CPU backend reports no memory stats: a 4GB-class budget
+        return int(4 * (1 << 30) * frac)
 
     # -- effective budget / reservations ----------------------------------
 

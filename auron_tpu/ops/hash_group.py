@@ -150,10 +150,18 @@ def onehot_segment_sum(x, seg, num_segments: int):
     sr = seg.reshape(-1, chunk)
     gids = jnp.arange(num_segments, dtype=sr.dtype)
 
+    # XLA:TPU's x64 rewrite has no 64-bit integer dot (count/bigint
+    # sums): those reduce the masked expansion directly, same n*G work
+    wide_int = jnp.issubdtype(x.dtype, jnp.integer) and \
+        x.dtype.itemsize > 4
+
     def body(acc, args):
         xc, sc = args
-        oh = (sc[:, None] == gids[None, :]).astype(x.dtype)
-        return acc + xc @ oh, None
+        oh = sc[:, None] == gids[None, :]
+        if wide_int:
+            return acc + jnp.sum(jnp.where(oh, xc[:, None], 0),
+                                 axis=0), None
+        return acc + xc @ oh.astype(x.dtype), None
 
     acc, _ = lax.scan(body, jnp.zeros((num_segments,), x.dtype), (xr, sr))
     return acc

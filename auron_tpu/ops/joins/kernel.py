@@ -221,7 +221,8 @@ class BuildTable:
             from auron_tpu.ops.radix_sort import stable_argsort_u64
             perm = stable_argsort_u64(h)
         else:
-            perm = jnp.argsort(h).astype(jnp.int32)
+            from auron_tpu.ops.sort_keys import stable_argsort
+            perm = stable_argsort(h)
         sorted_hashes = jnp.take(h, perm)
         probe = build_probe_index(sorted_hashes) \
             if join_probe_strategy(cap) == "partitioned" else None

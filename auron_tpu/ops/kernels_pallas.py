@@ -137,9 +137,22 @@ def _radix_hist_kernel(hi_ref, out_ref, *, b_bits: int):
     hi = hi_ref[:]
     digit = (hi >> np.uint32(32 - b_bits)).astype(jnp.int32)
     # B is small and static: the bucket loop unrolls into B vector
-    # compare+reduce chains over the tile — pure VPU work, no scatter
-    for b in range(1 << b_bits):
-        out_ref[0, b] = jnp.sum((digit == b).astype(jnp.int32))
+    # compare+reduce chains over the tile — pure VPU work, no scatter.
+    # The counts are assembled into ONE lane vector and stored once
+    # (Mosaic has no scalar store into a VMEM block), and each count
+    # is reduced rows-then-lanes down to a (1, 1) vector: a reduction
+    # over ALL axes is re-traced by Mosaic at lowering time, outside
+    # this kernel's 32-bit scope, where the engine's global x64 widens
+    # it to an int64 Mosaic refuses.
+    n_buckets = 1 << b_bits
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_buckets), 1)
+    hist = jnp.zeros((1, n_buckets), jnp.int32)
+    for b in range(n_buckets):
+        per_lane = jnp.sum((digit == b).astype(jnp.int32), axis=0,
+                           keepdims=True)
+        count = jnp.sum(per_lane, axis=1, keepdims=True)
+        hist = jnp.where(lane == b, count, hist)
+    out_ref[0] = hist
 
 
 def radix_bucket_hist_xla(hi, b_bits: int, tile_rows: int = _MAX_TILE_ROWS):
@@ -168,14 +181,20 @@ def radix_bucket_hist(hi, b_bits: int, interpret: bool = False):
         tile_rows -= 1
     hi2 = hi.astype(jnp.uint32).reshape(rows, _LANES)
     grid = (rows // tile_rows,)
+    # one (1, B) histogram row per tile, kept as the LAST TWO dims of a
+    # 3-D output: Mosaic wants a block's last two dims (8, 128)-aligned
+    # or equal to the array's, and a (1, B) block of an (n_tiles, B)
+    # array is neither
+    n_buckets = 1 << b_bits
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_radix_hist_kernel, b_bits=b_bits),
             out_shape=jax.ShapeDtypeStruct(
-                (rows // tile_rows, 1 << b_bits), jnp.int32),
+                (rows // tile_rows, 1, n_buckets), jnp.int32),
             grid=grid,
             in_specs=[pl.BlockSpec((tile_rows, _LANES), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((1, 1 << b_bits), lambda i: (i, 0)),
+            out_specs=pl.BlockSpec((1, 1, n_buckets),
+                                   lambda i: (i, 0, 0)),
             interpret=interpret,
         )(hi2)
-    return out
+    return out.reshape(rows // tile_rows, n_buckets)

@@ -266,20 +266,33 @@ def multipass_enabled() -> bool:
     return _jax.default_backend() != "cpu"
 
 
+def stable_argsort(key):
+    """Stable argsort of ONE key vector -> int32[n] permutation.
+
+    `jnp.argsort` carries its row numbers as an iota of the default int
+    dtype — int64 under the engine's global x64 — so every sort moves a
+    64-bit payload the TPU has to split in two.  Capacities are < 2^31
+    by construction: an int32 payload halves what the sort moves and,
+    on XLA:TPU, its compile time (a u64 key at 2^17 rows: 34 s against
+    60 s; a u32 key: 19 s against 38 s — v5e, ahead of time, PR 22)."""
+    from jax import lax
+    return lax.sort((key, lax.iota(jnp.int32, key.shape[0])),
+                    num_keys=1, is_stable=True)[1]
+
+
 def _multipass_lexsort(keys: List[Any]):
     """Composed stable single-key argsorts, least-significant key first
     (classic LSD composition — equivalent to jnp.lexsort, which takes
     its PRIMARY key last).  Why: on the TPU backend the multi-operand
     comparator sort jnp.lexsort lowers to compiles superlinearly in
-    operand count x rows (measured 201s for ONE 3-operand 4M-row
-    lexsort vs ~2s per single-key argsort); K+1 cheap passes keep the
-    whole agg/sort/window program compile in seconds, and each pass
-    runs at the same dispatch-floor speed the r03 chip profile measured
-    for argsort."""
+    operand count x rows (201 s for ONE 3-operand 4M-row lexsort, round
+    4); passes over one key dtype are one sort computation to XLA, so
+    five u64 passes compile in the time of one (36 s against 34 s at
+    2^17 rows — v5e, ahead of time, PR 22)."""
     perm = None
     for k in keys:
         data = k if perm is None else jnp.take(k, perm)
-        p = jnp.argsort(data, stable=True)
+        p = stable_argsort(data)
         perm = p if perm is None else jnp.take(perm, p)
     return perm
 
@@ -305,7 +318,7 @@ def lexsort_indices_live(words: List[Any], live,
     # jnp.lexsort: last key is primary
     keys = list(reversed([pad_rank] + words))
     if multipass_enabled():
-        return _multipass_lexsort(keys).astype(jnp.int32)
+        return _multipass_lexsort(keys)
     return jnp.lexsort(tuple(keys)).astype(jnp.int32)
 
 

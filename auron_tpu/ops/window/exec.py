@@ -422,19 +422,10 @@ def _seg_total(x, c):
 
 
 def _seg_running_minmax(x, c, is_min: bool):
-    import jax.lax as lax
-    # associative scan with segment reset: combine (flag, value)
-    flags = c["part_bound"]
-
-    def combine(a, b):
-        af, av = a
-        bf, bv = b
-        keep_b = bf
-        merged = jnp.minimum(av, bv) if is_min else jnp.maximum(av, bv)
-        return (jnp.logical_or(af, bf), jnp.where(keep_b, bv, merged))
-
-    _, out = lax.associative_scan(combine, (flags, x))
-    return out
+    # row 0 opens a partition whenever it is live; with no live row at
+    # all it has no boundary, and the scan wants one there
+    first = c["part_bound"].at[0].set(True)
+    return segments.segmented_running(x, first, is_min)
 
 
 def _seg_total_minmax(x, c, is_min: bool):

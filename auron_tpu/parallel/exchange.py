@@ -57,7 +57,8 @@ def _scatter_to_send(data, dest, valid, n_dev: int, quota: int):
         order = radix_sort_indices([safe_dest.astype(jnp.uint32)],
                                    [max(int(n_dev).bit_length(), 1)])
     else:
-        order = jnp.argsort(safe_dest, stable=True)    # groups by dest
+        from auron_tpu.ops.sort_keys import stable_argsort
+        order = stable_argsort(safe_dest)              # groups by dest
     sorted_dest = jnp.take(safe_dest, order)
     idx = jnp.arange(cap, dtype=jnp.int32)
     # start offset of each dest group in sorted order

@@ -53,7 +53,8 @@ __all__ = [
     "wrap", "enabled", "configure", "record", "declare_estimator",
     "estimator_for", "snapshot", "rooflines", "kernel_seconds",
     "kernel_bytes", "live_profile", "export_profile", "profile_version",
-    "machine_peak_gbps", "measure_peak", "attribution_scope",
+    "machine_peak_gbps", "measure_peak", "device_peak_gbps",
+    "DEVICE_PEAK_GBPS", "attribution_scope",
     "reset_state", "render_report",
 ]
 
@@ -438,11 +439,30 @@ def wrap(site: str, fn: Callable) -> Callable:
 
 
 # ---------------------------------------------------------------------------
-# machine peak (STREAM-style memcpy probe, verdict cached like bench.py's)
+# machine peak: a published-peaks table on accelerators, a STREAM-style
+# memcpy probe of host RAM (verdict cached) on the CPU
 # ---------------------------------------------------------------------------
+
+# published HBM bandwidth of one chip in GB/s, keyed by jax's
+# `device_kind`.  Source: Google Cloud documentation, "TPU v5e" (16 GB
+# of HBM at 819 GB/s); a v5e chip reports itself as "TPU v5 lite".
+DEVICE_PEAK_GBPS: Dict[str, float] = {"TPU v5 lite": 819.0}
 
 _PEAK_CACHE: Dict[str, float] = {}   # platform -> GB/s (process cache)
 _PEAK_PROBE_BYTES = 1 << 26          # 64 MiB working set
+
+
+def device_peak_gbps(device_kind: str) -> float:
+    """Published peak memory bandwidth of an accelerator.  A device that
+    is not in the table is an error, never a default: a roofline share
+    against the wrong ceiling is worse than none."""
+    try:
+        return DEVICE_PEAK_GBPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {device_kind!r}: add it "
+            f"to perfscope.DEVICE_PEAK_GBPS with its source, or set "
+            f"auron.perf.peak.gbps") from None
 
 
 def _peak_cache_file() -> str:
@@ -487,13 +507,17 @@ def measure_peak(reps: int = 5) -> float:
 
 def machine_peak_gbps() -> float:
     """The peak the rooflines divide by: the `auron.perf.peak.gbps`
-    override when set, else the cached probe verdict (one measurement
-    per platform, persisted next to the bench probe verdict), else a
+    override when set; on an accelerator the published peak of its
+    `device_kind` (DEVICE_PEAK_GBPS — the host's memcpy speed says
+    nothing about HBM); on the CPU the cached probe verdict, else a
     fresh probe whose verdict is cached best-effort."""
     forced = _conf_float("auron.perf.peak.gbps", 0.0)
     if forced > 0:
         return forced
     plat = _platform()
+    if plat != "cpu":
+        import jax
+        return device_peak_gbps(jax.devices()[0].device_kind)
     with _LOCK:
         if plat in _PEAK_CACHE:
             return _PEAK_CACHE[plat]

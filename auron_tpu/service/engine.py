@@ -30,7 +30,6 @@ from __future__ import annotations
 import io
 import json
 import logging
-import os
 import socket
 import socketserver
 import threading
@@ -294,15 +293,6 @@ def serve(host: Optional[str] = None, port: int = 0,
           advertise_host: Optional[str] = None) -> None:
     """Blocking entry point (`python -m auron_tpu.service.engine`)."""
     from auron_tpu import config
-    platform = os.environ.get("JAX_PLATFORMS")
-    if platform:
-        # some TPU platform plugins override the env var; pin the
-        # requested backend through the config API before first use
-        try:
-            import jax
-            jax.config.update("jax_platforms", platform)
-        except Exception:
-            pass
     if host is None:
         host = config.net_bind_host()
     s = EngineServer(host, port)

@@ -769,15 +769,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "per-worker slice of the federated budget)")
     args = ap.parse_args(argv)
 
-    platform = os.environ.get("JAX_PLATFORMS")
-    if platform:
-        # some TPU platform plugins override the env var; pin the
-        # requested backend through the config API before first use
-        try:
-            import jax
-            jax.config.update("jax_platforms", platform)
-        except Exception:
-            pass
     if args.conf:
         for key, value in json.loads(args.conf).items():
             conf.set(key, value)

@@ -6,6 +6,8 @@ import json
 import logging
 import urllib.request
 
+import pytest
+
 from auron_tpu import config
 from auron_tpu.build_info import build_info
 from auron_tpu.runtime import profiling, task_logging
@@ -110,6 +112,98 @@ def test_config_doc_covers_all_options():
     for opt in config.conf.options():
         assert f"`{opt.key}`" in committed, \
             f"CONFIG.md is stale: regenerate with python -m auron_tpu.config"
+
+
+# -- the compile cache can be placed (config.apply_compile_cache) ----------
+
+@pytest.fixture
+def jax_cache_config():
+    """Hand the test jax's cache settings and put them back after: a
+    directory left set would turn the CPU cache on for the rest of the
+    suite (see the note in conftest.py)."""
+    import jax
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        yield jax.config
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+def _repo_cache_dir():
+    import pathlib
+    return str(pathlib.Path(__file__).resolve().parent.parent /
+               ".jax_cache")
+
+
+def test_compile_cache_env_var_stands(monkeypatch, tmp_path,
+                                      jax_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling stands, on any
+    backend, whatever explicit path the option names."""
+    import jax
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    # what jax does with the variable when it is imported
+    jax_cache_config.update("jax_compilation_cache_dir", env_dir)
+    for backend in ("tpu", "cpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert config.apply_compile_cache() == env_dir
+        with config.conf.scoped(
+                {"auron.compile.cache.dir": str(tmp_path / "explicit")}):
+            assert config.apply_compile_cache() == env_dir
+        assert jax_cache_config.jax_compilation_cache_dir == env_dir
+
+
+def test_compile_cache_defaults_to_the_repo_on_a_device(
+        monkeypatch, tmp_path, jax_cache_config):
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax_cache_config.update("jax_compilation_cache_dir", None)
+    # the CPU stays uncached under 'auto' ...
+    assert config.apply_compile_cache() is None
+    assert jax_cache_config.jax_compilation_cache_dir is None
+    # ... a device backend gets the one fixed directory ...
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert config.apply_compile_cache() == _repo_cache_dir()
+    assert jax_cache_config.jax_compilation_cache_dir == _repo_cache_dir()
+    assert config.apply_compile_cache() == _repo_cache_dir()  # idempotent
+    # ... and an explicit path works while the variable is unset
+    explicit = str(tmp_path / "explicit")
+    with config.conf.scoped({"auron.compile.cache.dir": explicit}):
+        assert config.apply_compile_cache() == explicit
+    assert jax_cache_config.jax_compilation_cache_dir == explicit
+
+
+def test_compile_cache_off_sets_nothing(monkeypatch, jax_cache_config):
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax_cache_config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for off in ("off", ""):
+        with config.conf.scoped({"auron.compile.cache.dir": off}):
+            assert config.apply_compile_cache() is None
+        assert jax_cache_config.jax_compilation_cache_dir is None
+
+
+def test_chip_smoke_refuses_a_cpu(tmp_path):
+    """No CPU stand-in: without an accelerator the smoke exits non-zero
+    before it generates anything and never prints its ok line."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    proc = subprocess.run(
+        [sys.executable, str(repo / "chip_smoke.py"), "--sf", "0.01",
+         "--out", str(tmp_path)],
+        cwd=str(tmp_path), capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert "needs a TPU" in proc.stderr
+    assert not os.listdir(tmp_path)
 
 
 def test_input_batch_statistics_option():

@@ -155,6 +155,35 @@ def test_window_plan():
     assert {"rn", "rk", "dr", "lg", "rs"} <= set(got[0].keys())
 
 
+@pytest.mark.parametrize("fn", ["min", "max"])
+@pytest.mark.parametrize("kind", ["int", "float"])
+def test_window_running_extremes(fn, kind):
+    """Running MIN/MAX over an ordered window — the segmented scan of
+    ops/segments.py restarting at every partition — with nulls, against
+    the reference engine."""
+    rng = np.random.default_rng(31)
+    dt = DataType.int64() if kind == "int" else DataType.float64()
+
+    def value():
+        if rng.random() < 0.15:
+            return None
+        return int(rng.integers(-500, 500)) if kind == "int" \
+            else float(rng.normal(0, 100))
+    rows = [{"g": int(rng.integers(0, 9)), "o": int(rng.integers(0, 40)),
+             "v": value()} for _ in range(700)]
+    src, res = ffi_source(rows)
+    plan = P.Window(
+        child=src,
+        window_funcs=(P.WindowFuncCall(
+            fn="agg", agg=AggExpr(fn=fn, children=(col("v"),),
+                                  return_type=dt),
+            return_type=dt, name="run"),),
+        partition_by=(col("g"),),
+        order_by=(SortExpr(child=col("o")),))
+    got = check_plan(plan, res)
+    assert any(r["run"] is not None for r in got)
+
+
 def test_window_spill_tiny_budget():
     """Window staging must spill as sorted runs and reassemble whole
     partitions from the run merge (VERDICT r1: window had a non-spillable

@@ -462,7 +462,7 @@ def test_strategy_fingerprint_tracks_knobs():
 
 
 # ---------------------------------------------------------------------------
-# bench probe-verdict cache (satellite)
+# bench.py names the device it ran on; only a TPU run may say "/chip"
 # ---------------------------------------------------------------------------
 
 def _bench_module():
@@ -475,27 +475,21 @@ def _bench_module():
     return mod
 
 
-def test_probe_verdict_cache_roundtrip(tmp_path, monkeypatch):
+@pytest.mark.parametrize("platform,kind,per_chip", [
+    ("tpu", "TPU v5 lite", True), ("cpu", "cpu", False)])
+def test_bench_summary_names_its_device(platform, kind, per_chip):
     bench = _bench_module()
-    monkeypatch.setattr(bench, "_probe_cache_file",
-                        lambda: str(tmp_path / "probe_verdict.json"))
-    monkeypatch.setenv("JAX_PLATFORMS", "")
-    assert bench._load_probe_verdict() is None
-    bench._save_probe_verdict("dead", None)
-    ent = bench._load_probe_verdict()
-    assert ent and ent["verdict"] == "dead"
-    # the verdict is keyed per platform pin
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench._load_probe_verdict() is None
-    bench._save_probe_verdict("ok", 1.5)
-    assert bench._load_probe_verdict()["seconds"] == 1.5
-    # TTL expiry
-    monkeypatch.setenv("AURON_BENCH_PROBE_CACHE_TTL_S", "0")
-    assert bench._load_probe_verdict() is None
-    # kill switch
-    monkeypatch.delenv("AURON_BENCH_PROBE_CACHE_TTL_S")
-    monkeypatch.setenv("AURON_BENCH_PROBE_CACHE", "0")
-    assert bench._load_probe_verdict() is None
+    dev = {"platform": platform, "device_kind": kind, "device_count": 1}
+    out = bench._summarize(
+        {"engine": dict(dev, seconds=2.0, rows=1000),
+         "fused": dict(dev, seconds=1.0, rows=1000)}, 100.0, [])
+    assert ("/chip" in out["unit"]) is per_chip
+    assert {k: out[k] for k in dev} == dev
+    # the fused-only summary (engine worker failed) obeys the same rule
+    out = bench._summarize({"fused": dict(dev, seconds=1.0, rows=1000)},
+                           100.0, ["engine: boom"])
+    assert ("/chip" in out["unit"]) is per_chip
+    assert out["diagnostics"] == ["engine: boom"]
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ import pytest
 
 from auron_tpu.config import conf
 from auron_tpu.memmgr.manager import (
-    MemConsumer, get_manager, reset_manager,
+    MemConsumer, MemManager, get_manager, reset_manager,
 )
 from auron_tpu.runtime import tracing
 from auron_tpu.runtime.metrics import MetricNode
@@ -173,6 +173,32 @@ def test_watermark_and_spill_trace_events():
     assert sp.args["freed_bytes"] == 1200
     # exports as valid Chrome-trace instants
     assert tracing.validate_chrome_trace(rec.to_chrome_trace()) == []
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("tpu", {"bytes_limit": 16 << 30}, "fraction"),   # reported limit
+    ("cpu", None, "4gb"),                             # CPU reports none
+    ("tpu", None, "error"),           # an accelerator must never guess
+    ("tpu", {}, "error"),
+])
+def test_default_budget_never_guesses_an_accelerator(monkeypatch, platform,
+                                                     stats, want):
+    import types
+
+    import jax
+    dev = types.SimpleNamespace(platform=platform, device_kind="fake",
+                                memory_stats=lambda: stats)
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+    frac = float(conf.get("auron.memory.fraction"))
+    if want == "error":
+        with pytest.raises(RuntimeError, match="no memory limit"):
+            MemManager._default_budget()
+        # the explicit budget is the way out
+        with conf.scoped({"auron.memory.budget.bytes": 123}):
+            assert MemManager._default_budget() == 123
+    else:
+        size = (16 << 30) if want == "fraction" else (4 << 30)
+        assert MemManager._default_budget() == int(size * frac)
 
 
 def test_reservations_shrink_effective_budget():

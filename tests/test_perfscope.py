@@ -260,6 +260,27 @@ def test_peak_override_and_cache_file(tmp_path):
     perfscope._PEAK_CACHE.clear()
 
 
+def test_accelerator_peak_comes_from_the_published_table(monkeypatch):
+    """Off the CPU the roofline ceiling is the chip's published HBM
+    peak by device_kind, never the host memcpy probe; a device the
+    table does not know is an error, not a default."""
+    import types
+
+    import jax
+    monkeypatch.setattr(perfscope, "_platform", lambda: "tpu")
+    monkeypatch.setattr(perfscope, "measure_peak", lambda reps=5: 1 / 0)
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        types.SimpleNamespace(device_kind="TPU v5 lite")])
+    assert perfscope.machine_peak_gbps() == 819.0
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        types.SimpleNamespace(device_kind="TPU v9 imaginary")])
+    with pytest.raises(ValueError, match="no published peak"):
+        perfscope.machine_peak_gbps()
+    # the explicit override still wins
+    with config.conf.scoped({"auron.perf.peak.gbps": 5.0}):
+        assert perfscope.machine_peak_gbps() == 5.0
+
+
 def test_rooflines_table_shape():
     perfscope.record("unit.roof", 0.001, 10 ** 6, signature="s")  # 1 GB/s
     with config.conf.scoped({"auron.perf.peak.gbps": 10.0}):

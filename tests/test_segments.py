@@ -88,6 +88,39 @@ def test_scatter_fallback_path():
         conf.set("auron.segments.sorted.enable", old)
 
 
+@pytest.mark.parametrize("n", [1, 2, 17, 1000, 4096, 5001])
+def test_segmented_scan_equals_a_per_segment_numpy_scan(n):
+    """The segmented scan (a rolled doubling loop on every backend:
+    XLA:TPU compiles the unrolled associative scan superlinearly in n)
+    against numpy's running sum / min / max of each segment alone: float
+    sums to tolerance — the association differs — with exact-zero
+    segments exactly zero, integer sums and min/max bit-equal."""
+    rng = np.random.default_rng(n)
+    seg = np.sort(rng.integers(0, max(n // 7, 1), n)).astype(np.int32)
+    is_first = np.concatenate([[True], seg[1:] != seg[:-1]])
+    xf = rng.uniform(1e4, 1e6, n)
+    xf[seg % 3 == 1] = 0.0                     # whole segments of zeros
+    xi = rng.integers(-1000, 1000, n)
+    starts = np.flatnonzero(is_first)
+
+    def per_segment(x, ufunc):
+        return np.concatenate([ufunc.accumulate(part)
+                               for part in np.split(x, starts[1:])])
+
+    def scan(x, op):
+        return np.asarray(jax.jit(
+            lambda a, f: segments._segmented_scan(a, f, op))(
+                jnp.asarray(x), jnp.asarray(is_first)))
+
+    got = scan(xf, jnp.add)
+    np.testing.assert_allclose(got, per_segment(xf, np.add), rtol=1e-12)
+    assert (got[seg % 3 == 1] == 0.0).all()
+    for x, op, ufunc in [(xi, jnp.add, np.add),
+                         (xf, jnp.minimum, np.minimum),
+                         (xi, jnp.maximum, np.maximum)]:
+        np.testing.assert_array_equal(scan(x, op), per_segment(x, ufunc))
+
+
 @pytest.mark.slow   # PR 18 tier-1 re-split (7.6s; exactness property
 #   — the deterministic segment-sum units keep the family fast)
 def test_sorted_segment_sum_exact_zero_segments():

@@ -959,11 +959,11 @@ def test_source_cache_budget_zero_flushes_and_scan_fp_invalidates(tmp_path):
                           resource_id="t"),
         exprs=(col("k"),), names=("k",))
     execute_plan_spmd(proj, ctx, mesh, {"t": t})
-    assert len(S._DEVICE_SHARDS._entries) == 1
+    assert len(S._DEVICE_SHARDS.values()) == 1
     with conf.scoped({"auron.spmd.source.cache.mb": 0}):
         # a lookup under budget 0 flushes the retained entries
         assert S._DEVICE_SHARDS.get(t, ()) is None
-        assert len(S._DEVICE_SHARDS._entries) == 0
+        assert len(S._DEVICE_SHARDS.values()) == 0
 
     # scan fingerprint: rewrite the file between executes -> re-read
     path = str(tmp_path / "scan.parquet")

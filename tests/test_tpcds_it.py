@@ -28,7 +28,7 @@ def small_catalog(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
-def runner(catalog):
+def runner(catalog, tmp_path_factory):
     # round 4: the stage path (default on) + device-resident source
     # caching killed the per-execute fixed cost the old 0.8s floor and
     # the three SMJ-chain waivers excused (corpus median warm/oracle
@@ -37,15 +37,10 @@ def runner(catalog):
     r = QueryRunner(catalog=catalog, perf_factor=3.0, perf_floor_s=0.2,
                     perf_waivers={})
     yield r
-    # per-query perf artifact for the driver to archive (VERDICT r2 #8):
-    # native/oracle/warm seconds per corpus query
-    out = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "IT_PERF.json")
-    try:
-        with open(out, "w") as f:
-            f.write(r.to_json() + "\n")
-    except OSError:
-        pass
+    # per-query native/oracle/warm seconds of this (CPU) run, kept with
+    # pytest's own temporary files: a test run changes no tracked file
+    out = tmp_path_factory.mktemp("it_perf") / "IT_PERF.json"
+    out.write_text(r.to_json() + "\n")
 
 
 # tier-1 keeps a representative subset of the corpus (every operator

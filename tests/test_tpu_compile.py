@@ -1,0 +1,304 @@
+"""Ask the TPU's compiler, without the TPU.
+
+libtpu is installed in the CPU sandbox and compiles for a chip that is
+described, not attached (the on-chip-measurement guide, section 2,
+rehearsal 3).  These tests lower the kernel families the stage tracer calls
+on TPC-DS q01/q07/q19 — in their TPU branches — and the two Pallas kernels
+with `interpret=False`, for one v5e chip, from `ShapeDtypeStruct`s.  What
+Mosaic or XLA:TPU would refuse on the chip it refuses here, at no chip time.
+A compile that passes is not a chip run: nothing executes, so nothing here
+says anything about results or speed (`python chip_smoke.py` does).
+
+Capacities: `CAP` is the bucket dsdgen-SF1-cardinality `store_sales`
+(3,000,000 rows) lands in, 2^22.  The sort-bearing families stay at
+`SORT_CAP` = 2^13 rows: XLA:TPU's sort compile grows with capacity (one
+stable u64 argsort with the engine's int32 row numbers, compiled here for
+v5e: 7 s at 2^14, 34 s at 2^17, 43 s at 2^20, 52 s at 2^22; with
+`jnp.argsort`'s int64 ones 13 s, 60 s, 99 s, 111 s — CHANGES.md, PR 22),
+and a test is kept to a few seconds.
+
+`jax.default_backend()` still answers "cpu" here, so every site that picks
+its branch by backend would compile its CPU branch: the `tpu_branches`
+fixture steers them in the test (tri-state options set to what `auto`
+resolves to on a TPU, `jax.default_backend` patched for the sites that have
+only the backend test).  The topology is described inside a module-scoped
+fixture — never at import, in a `skipif` or in `parametrize` arguments:
+only one process may load libtpu, and every xdist worker imports this file.
+All compiles run in this test's own process, in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from auron_tpu.columnar.batch import DeviceColumn, bucket_capacity
+from auron_tpu.config import conf
+from auron_tpu.ir.schema import DataType
+
+SF1_STORE_SALES_ROWS = 3_000_000
+CAP = bucket_capacity(SF1_STORE_SALES_ROWS)
+SORT_CAP = 1 << 13      # largest capacity at which the sort families
+#                         compile in a few seconds (module docstring)
+BUILD_CAP = 1 << 18     # a dimension-side build table (agg capacity hint)
+
+I32, I64, F64 = DataType.int32(), DataType.int64(), DataType.float64()
+
+# what `auto` resolves to when the backend is a TPU
+TPU_OPTIONS = {
+    "auron.sort.multipass.enable": "on",
+    "auron.sort.f64.exactbits": "on",
+    "auron.agg.grouping.strategy": "sort",
+    "auron.spmd.gather.compact": "on",
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """Steer the branch-by-backend sites into their TPU branches."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with conf.scoped(TPU_OPTIONS):
+        yield
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip can be written to JAX's persistent
+    cache but never read back without the chip; keep these silent and
+    uncached whatever the environment says."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shape(one_chip, n, dtype):
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+
+def _column(one_chip, dt, cap, exact_bits=False):
+    """A DeviceColumn of shapes: data, validity and — for ingested f64 —
+    the exact-bits sidecar."""
+    return DeviceColumn(
+        dt, _shape(one_chip, cap, dt.numpy_dtype()),
+        _shape(one_chip, cap, jnp.bool_),
+        _shape(one_chip, cap, jnp.uint64) if exact_bits else None)
+
+
+def _compile(fn, *args):
+    """Lower the raw function (jitcheck is armed in the suite: never a
+    site's wrapper) and compile it for the described chip."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+# ---------------------------------------------------------------------------
+# XLA:TPU — the stage tracer's kernel families, TPU branches
+# ---------------------------------------------------------------------------
+
+def test_murmur3_hash_and_pmod_at_sf1_capacity(one_chip, tpu_branches,
+                                               no_persistent_cache):
+    """The exchange/partition hash (parallel/stage.py `_exchange`):
+    murmur3 + pmod over an int64 key, and over an f64 key through its
+    exact-bits sidecar (exprs/hashing.py hashes f32 bits otherwise)."""
+    from auron_tpu.exprs import hashing as H
+
+    def pid(col):
+        return H.pmod(H.hash_columns([col], seed=42), 200)
+    _compile(pid, _column(one_chip, I64, CAP))
+    _compile(pid, _column(one_chip, F64, CAP, exact_bits=True))
+    _compile(pid, _column(one_chip, F64, CAP))      # device-computed f64
+
+
+def test_f64_key_order_encoding_exact_bits_at_sf1_capacity(
+        one_chip, tpu_branches, no_persistent_cache):
+    """ORDER BY on float64 money columns (q01, q19): the u64 key words
+    come from the ingest-captured bits, or from a pure-integer f32->f64
+    widening for device-computed sums — no 64-bit bitcast either way."""
+    from auron_tpu.ops.sort_keys import (
+        encode_sort_keys, f64_exact_bits_enabled,
+    )
+    assert f64_exact_bits_enabled()
+
+    def words(col):
+        return encode_sort_keys([col], [(False, True)])
+    _compile(words, _column(one_chip, F64, CAP, exact_bits=True))
+    _compile(words, _column(one_chip, F64, CAP))
+
+
+def test_join_probe_and_pair_expansion_at_sf1_capacity(
+        one_chip, tpu_branches, no_persistent_cache):
+    """A fact-side probe of a sorted-hash build table: the two-seed key
+    hash, the double searchsorted range lookup and the pair expansion
+    (ops/joins/kernel.py) at fact capacity against a dimension-sized
+    build side."""
+    from auron_tpu.ops.joins.kernel import (
+        expand_pairs, join_key_hash, probe_ranges,
+    )
+    from auron_tpu.ops.strategy import join_probe_strategy
+    assert join_probe_strategy(BUILD_CAP) == "searchsorted"
+
+    def ranges(pkey, sorted_hashes, plive):
+        ph, pvalid = join_key_hash([pkey], CAP)
+        return probe_ranges(sorted_hashes, ph, pvalid, plive)
+    _compile(ranges, _column(one_chip, I64, CAP),
+             _shape(one_chip, BUILD_CAP, jnp.uint64),
+             _shape(one_chip, CAP, jnp.bool_))
+    _compile(lambda lo, counts: expand_pairs(lo, counts, 0, CAP),
+             _shape(one_chip, CAP, jnp.int32),
+             _shape(one_chip, CAP, jnp.int64))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64, jnp.int64])
+def test_onehot_group_reduce_at_sf1_capacity(one_chip, tpu_branches,
+                                             no_persistent_cache, dtype):
+    """The one-hot/matmul segment reduce `auto` picks on TPU-class
+    backends for small static segment counts (ops/strategy.py)."""
+    from auron_tpu.ops.hash_group import onehot_segment_sum
+    from auron_tpu.ops.strategy import group_strategy
+    assert group_strategy(64) == "onehot"
+    _compile(lambda x, seg: onehot_segment_sum(x, seg, 64),
+             _shape(one_chip, CAP, dtype), _shape(one_chip, CAP, jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.int64])
+def test_sorted_segment_sum_at_sf1_capacity(one_chip, tpu_branches,
+                                            no_persistent_cache, dtype):
+    """The segment sum behind every SUM/AVG/COUNT of the sort-based
+    group-reduce (ops/segments.py).  Its float form was the compile
+    blow-up of this path: as an unrolled associative scan it took 124 s
+    at 2^20 rows and 376 s at 2^21; as a rolled loop it is seconds at
+    any size — which is why this test can afford the real capacity."""
+    from auron_tpu.ops.segments import sorted_segment_sum
+    _compile(lambda x, seg: sorted_segment_sum(x, seg, CAP),
+             _shape(one_chip, CAP, dtype), _shape(one_chip, CAP, jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.int64])
+def test_segmented_running_extreme_at_sf1_capacity(
+        one_chip, tpu_branches, no_persistent_cache, dtype):
+    """The same rolled scan under MIN/MAX: sorted-segment extremes of the
+    group-reduce and the window operator's running min/max
+    (ops/window/exec.py) both go through `segmented_running`."""
+    from auron_tpu.ops.segments import segmented_running
+    _compile(lambda x, first: segmented_running(x, first, True),
+             _shape(one_chip, CAP, dtype), _shape(one_chip, CAP, jnp.bool_))
+
+
+def test_multipass_key_sort(one_chip, tpu_branches, no_persistent_cache):
+    """The composed stable single-key argsorts the chip runs instead of
+    one multi-operand comparator sort (ops/sort_keys.py), over a nullable
+    int64 key and a nullable exact-bits f64 key: u32 rank words and u64
+    value words."""
+    from auron_tpu.ops.sort_keys import (
+        encode_sort_keys, encode_sort_keys_bits, lexsort_indices_live,
+        multipass_enabled,
+    )
+    from auron_tpu.ops.strategy import sort_strategy
+    assert multipass_enabled()
+    assert sort_strategy(SORT_CAP, 4) == "argsort"
+
+    def order(k1, k2, live):
+        keys = [k1, k2]
+        words = encode_sort_keys(keys, [(True, True), (False, False)])
+        return lexsort_indices_live(words, live,
+                                    encode_sort_keys_bits(keys))
+    _compile(order, _column(one_chip, I64, SORT_CAP),
+             _column(one_chip, F64, SORT_CAP, exact_bits=True),
+             _shape(one_chip, SORT_CAP, jnp.bool_))
+
+
+@pytest.mark.parametrize("merge", [False, True],
+                         ids=["partial-update", "final-merge"])
+def test_sort_strategy_group_reduce(one_chip, tpu_branches,
+                                    no_persistent_cache, merge):
+    """The sort-based group-reduce of q07's shape (one key; avg, avg,
+    count) as the stage tracer calls it (ops/agg/exec.py
+    `_group_reduce_body`), update and merge forms."""
+    from auron_tpu.ops.agg.exec import _group_reduce_body
+    from auron_tpu.ops.agg.functions import make_spec
+    specs = [make_spec("avg", F64, F64, "agg1"),
+             make_spec("avg", F64, F64, "agg2"),
+             make_spec("count", I32, I64, "cnt")]
+
+    def state_cols(spec):
+        if not merge:
+            return [_column(one_chip, spec.in_dtype, SORT_CAP,
+                            exact_bits=spec.in_dtype == F64)]
+        return [_column(one_chip, f.dtype, SORT_CAP)
+                for f in spec.state_fields()]
+
+    def reduce(key, vcols, live):
+        return _group_reduce_body([key], vcols, live, specs,
+                                  ((True, True),), merge)
+    _compile(reduce, _column(one_chip, I64, SORT_CAP),
+             [state_cols(s) for s in specs],
+             _shape(one_chip, SORT_CAP, jnp.bool_))
+
+
+def test_compact_gather_permutation(one_chip, tpu_branches,
+                                    no_persistent_cache):
+    """The live-rows-to-the-front permutation of the two-phase compact
+    gather and of join-chain compaction (parallel/stage.py
+    `_live_first_perm`: a bool-key sort with an int32 payload), then the
+    column gather."""
+    from auron_tpu.ops.strategy import sort_strategy
+    from auron_tpu.parallel.stage import _live_first_perm
+    assert sort_strategy(SORT_CAP) == "argsort"
+
+    def compact(col, live):
+        perm = _live_first_perm(live)
+        ok = jnp.take(live, perm)
+        return col.gather(perm, ok), ok
+    _compile(compact, _column(one_chip, F64, SORT_CAP, exact_bits=True),
+             _shape(one_chip, SORT_CAP, jnp.bool_))
+
+
+# ---------------------------------------------------------------------------
+# Mosaic — the two Pallas kernels, interpret=False
+# ---------------------------------------------------------------------------
+
+def _is_mosaic(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_hash_partition_ids_compiles_for_the_chip(
+        one_chip, no_persistent_cache):
+    from auron_tpu.ops import kernels_pallas as KP
+    # underneath the jit site's wrapper: its own jitted function
+    compiled = KP.hash_partition_ids_i64.__wrapped__.lower(
+        _shape(one_chip, CAP, jnp.int64), _shape(one_chip, CAP, jnp.bool_),
+        n_parts=200, interpret=False).compile()
+    assert _is_mosaic(compiled)
+
+
+@pytest.mark.parametrize("b_bits", [4, 8])
+def test_pallas_radix_bucket_hist_compiles_for_the_chip(
+        one_chip, no_persistent_cache, b_bits):
+    from auron_tpu.ops import kernels_pallas as KP
+    compiled = KP.radix_bucket_hist.__wrapped__.lower(
+        _shape(one_chip, CAP, jnp.uint32), b_bits=b_bits,
+        interpret=False).compile()
+    assert _is_mosaic(compiled)
