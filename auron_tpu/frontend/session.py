@@ -93,13 +93,19 @@ class SessionResult:
             explain_analyze as _ea, metric_totals,
         )
         totals = metric_totals(self.metrics)
+        stage_plan = None
+        if self.spmd:
+            # the stage program's operators under the labels its device
+            # time is filed under (python -m auron_tpu.trace device)
+            from auron_tpu.parallel.stage import explain_stage
+            stage_plan = explain_stage(self.converted, self.ctx)
         return _ea(self.metrics, query_id=self.query_id,
                    wall_s=self.wall_s, rows=self.table.num_rows,
                    spmd=self.spmd,
                    retries=totals.get("num_retries", 0),
                    fallbacks=totals.get("num_fallbacks", 0),
                    aqe=self.aqe_decisions,
-                   normalize=normalize)
+                   normalize=normalize, stage_plan=stage_plan)
 
     def all_native(self) -> bool:
         """True when no foreign section remains (the

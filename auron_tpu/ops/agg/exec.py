@@ -827,37 +827,43 @@ def _group_reduce_body(keys: List[Any], value_cols: List[List[Any]],
                        live, specs, orders, merge: bool):
     """Pure-jax sort-based group reduction over an explicit live mask.
     Live rows sort first (pad rank), so sorted-live = arange < sum(live).
-    Returns (out_cols, n_groups) with n_groups a device scalar."""
+    Returns (out_cols, n_groups) with n_groups a device scalar.  The two
+    steps carry named scopes (`group`, `reduce`): a device profile files
+    the sort and the segment arithmetic apart (auron_tpu.trace device)."""
     from auron_tpu.ops.sort_keys import encode_sort_keys_bits
     capacity = live.shape[0]
-    n_live = jnp.sum(live.astype(jnp.int32))
-    words = encode_sort_keys(keys, orders)
-    perm = lexsort_indices_live(words, live, encode_sort_keys_bits(keys))
-    slive = jnp.arange(capacity, dtype=jnp.int32) < n_live
-    sorted_words = [jnp.take(w, perm) for w in words]
-    if sorted_words:
-        eq_prev = keys_equal_prev(sorted_words)
-    else:
-        # global agg: every row belongs to the single segment
-        eq_prev = jnp.arange(capacity, dtype=jnp.int32) != 0
-    is_boundary = jnp.logical_and(jnp.logical_not(eq_prev), slive)
-    seg_of_sorted = jnp.cumsum(is_boundary.astype(jnp.int32)) - 1
-    seg_of_sorted = jnp.where(slive, seg_of_sorted, capacity - 1)
-    n_groups = jnp.sum(is_boundary.astype(jnp.int32))
-    first_sorted_idx = jnp.nonzero(is_boundary, size=capacity,
-                                   fill_value=0)[0].astype(jnp.int32)
-    key_src = jnp.take(perm, first_sorted_idx)
-    g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
-    out_cols: List[Any] = []
-    for k in keys:
-        out_cols.append(k.gather(key_src, g_valid))
-    for spec, cols in zip(specs, value_cols):
-        scols = [_gather_col(c, perm) for c in cols]
-        if merge:
-            states = spec.merge_segments(scols, seg_of_sorted, capacity)
+    with jax.named_scope("group"):
+        n_live = jnp.sum(live.astype(jnp.int32))
+        words = encode_sort_keys(keys, orders)
+        perm = lexsort_indices_live(words, live,
+                                    encode_sort_keys_bits(keys))
+        slive = jnp.arange(capacity, dtype=jnp.int32) < n_live
+        sorted_words = [jnp.take(w, perm) for w in words]
+        if sorted_words:
+            eq_prev = keys_equal_prev(sorted_words)
         else:
-            states = spec.update_segments(scols, seg_of_sorted, capacity)
-        out_cols.extend(_clip_states(states, n_groups))
+            # global agg: every row belongs to the single segment
+            eq_prev = jnp.arange(capacity, dtype=jnp.int32) != 0
+        is_boundary = jnp.logical_and(jnp.logical_not(eq_prev), slive)
+        seg_of_sorted = jnp.cumsum(is_boundary.astype(jnp.int32)) - 1
+        seg_of_sorted = jnp.where(slive, seg_of_sorted, capacity - 1)
+        n_groups = jnp.sum(is_boundary.astype(jnp.int32))
+        first_sorted_idx = jnp.nonzero(is_boundary, size=capacity,
+                                       fill_value=0)[0].astype(jnp.int32)
+        key_src = jnp.take(perm, first_sorted_idx)
+        g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
+        out_cols: List[Any] = []
+        for k in keys:
+            out_cols.append(k.gather(key_src, g_valid))
+    with jax.named_scope("reduce"):
+        for spec, cols in zip(specs, value_cols):
+            scols = [_gather_col(c, perm) for c in cols]
+            if merge:
+                states = spec.merge_segments(scols, seg_of_sorted, capacity)
+            else:
+                states = spec.update_segments(scols, seg_of_sorted,
+                                              capacity)
+            out_cols.extend(_clip_states(states, n_groups))
     return out_cols, n_groups
 
 
@@ -871,17 +877,18 @@ def _group_reduce_body_hash(keys: List[Any], value_cols: List[List[Any]],
     from auron_tpu.ops import segments
     from auron_tpu.ops.hash_group import hash_group_structure
     capacity = live.shape[0]
-    words = encode_sort_keys(keys, orders)
-    if words:
-        seg, key_src, n_groups = hash_group_structure(words, live)
-    else:
-        first = jnp.argmax(live).astype(jnp.int32)
-        n_groups = jnp.any(live).astype(jnp.int32)
-        seg = jnp.where(live, 0, max(capacity - 1, 0)).astype(jnp.int32)
-        key_src = jnp.zeros(capacity, jnp.int32).at[0].set(first)
-    g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
-    out_cols: List[Any] = [k.gather(key_src, g_valid) for k in keys]
-    with segments.unsorted_segments():
+    with jax.named_scope("group"):
+        words = encode_sort_keys(keys, orders)
+        if words:
+            seg, key_src, n_groups = hash_group_structure(words, live)
+        else:
+            first = jnp.argmax(live).astype(jnp.int32)
+            n_groups = jnp.any(live).astype(jnp.int32)
+            seg = jnp.where(live, 0, max(capacity - 1, 0)).astype(jnp.int32)
+            key_src = jnp.zeros(capacity, jnp.int32).at[0].set(first)
+        g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
+        out_cols: List[Any] = [k.gather(key_src, g_valid) for k in keys]
+    with jax.named_scope("reduce"), segments.unsorted_segments():
         for spec, cols in zip(specs, value_cols):
             if merge:
                 states = spec.merge_segments(cols, seg, capacity)

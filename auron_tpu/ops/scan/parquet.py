@@ -22,6 +22,7 @@ from auron_tpu.ir.plan import FileGroup
 from auron_tpu.ir.schema import Schema, to_arrow_schema
 from auron_tpu.ops.base import Operator, TaskContext, batch_size
 from auron_tpu.ops.scan.pushdown import expr_to_arrow_filter
+from auron_tpu.runtime import tracing
 
 
 def _open_for_read(path: str):
@@ -93,9 +94,19 @@ class ParquetScanExec(Operator):
                 continue
             avail = set(pf.schema_arrow.names)
             cols = [n for n in names if n in avail]
-            for rb in pf.iter_batches(batch_size=batch_size(),
-                                      row_groups=row_groups, columns=cols):
-                yield self._to_batch(rb, names, pvals)
+            pulls = pf.iter_batches(batch_size=batch_size(),
+                                    row_groups=row_groups, columns=cols)
+            while True:
+                # parquet -> Arrow; the last pull finds the end
+                with tracing.span("scan.decode", cat="scan") as sp:
+                    rb = next(pulls, None)
+                    if rb is not None and sp.armed:
+                        sp.set_args(rows=rb.num_rows, bytes=rb.nbytes)
+                if rb is None:
+                    break
+                with tracing.span("scan.to_device", cat="scan"):
+                    batch = self._to_batch(rb, names, pvals)
+                yield batch
 
     def _prune_row_groups(self, pf: pq.ParquetFile, filt) -> List[int]:
         from auron_tpu.ops.scan.pushdown import prune_parquet_row_groups

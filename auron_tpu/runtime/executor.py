@@ -145,8 +145,13 @@ def execute_task(task: P.TaskDefinition,
                 # convert BEFORE the row-count check: to_arrow fetches
                 # count + columns in one round trip, while `b.num_rows`
                 # alone would pay a separate sync for lazy batches
-                return [rb for rb in (b.to_arrow() for b in rt.batches())
-                        if rb.num_rows > 0]
+                out = []
+                for b in rt.batches():
+                    with tracing.span("task.to_host", cat="task"):
+                        rb = b.to_arrow()
+                    if rb.num_rows > 0:
+                        out.append(rb)
+                return out
 
     def _count_retry(_attempt_no, _exc):
         retries_box[0] += 1

@@ -248,14 +248,16 @@ def explain_analyze(trees: List[MetricNode],
                     retries: int = 0,
                     fallbacks: int = 0,
                     aqe: Optional[List[Dict[str, Any]]] = None,
-                    normalize: bool = False) -> str:
+                    normalize: bool = False,
+                    stage_plan: Optional[str] = None) -> str:
     """The full EXPLAIN ANALYZE text: a summary header + the annotated
     executed plan.  `normalize=True` omits the volatile header fields
     (query id, wall time) and metric values — the golden-comparable
     canonical form.  `aqe` lists the adaptive replan decisions
     (SessionResult.aqe_decisions); in the canonical form only the
     decision kind + exchange ordinal survive (byte counts and
-    groupings move with codec/format)."""
+    groupings move with codec/format).  `stage_plan` is the stage
+    path's labelled operator tree (parallel/stage.py::explain_stage)."""
     head = ["== EXPLAIN ANALYZE"]
     if not normalize:
         if query_id:
@@ -274,11 +276,16 @@ def explain_analyze(trees: List[MetricNode],
             line += f" ({d['reason']})"
         out.append(line)
     if not trees:
-        out.append("(no per-operator metrics: the query compiled to one "
-                   "SPMD stage program; run with "
-                   "auron.spmd.singleDevice.enable=false for the "
-                   "per-operator serial view)" if spmd else
-                   "(no per-operator metrics collected)")
+        out.append("(no per-operator host metrics: the query compiled to "
+                   "one SPMD stage program.  Device time per operator: "
+                   "profile a run (jax.profiler.trace) and read it with "
+                   "`python -m auron_tpu.trace device <profile dir>`, "
+                   "which files every device operation under the labels "
+                   "below; auron.spmd.singleDevice.enable=false gives "
+                   "the serial engine's per-operator view instead)"
+                   if spmd else "(no per-operator metrics collected)")
+        if spmd and stage_plan:
+            out.append(stage_plan)
         return "\n".join(out)
     out.append(render_analyzed(trees, normalize=normalize))
     return "\n".join(out)

@@ -17,9 +17,14 @@ Design constraints (the <2% serial-bench overhead gate):
   is armed, allocating nothing.
 - ON allocates one small Span record per site; timestamps are
   ``perf_counter_ns`` deltas against the recorder's epoch (no wall-clock
-  reads on the hot path) and the recorder is bounded
-  (``auron.trace.max.events``; overflow increments ``dropped`` instead
-  of growing without bound).
+  reads on the hot path; the epoch's own unix time is kept as
+  ``epoch_unix_ns``, so a saved trace can be laid beside a profile) and
+  the recorder is bounded (``auron.trace.max.events``; overflow
+  increments ``dropped`` instead of growing without bound).
+- ON also enters a ``jax.profiler.TraceAnnotation`` of the span's name:
+  while a profile runs, every program span lies in its host plane, on
+  its own thread's line and on the device operations' clock; with no
+  profile running that is one atomic read.
 - Propagation is contextvar-based, seeded by a per-query id minted in
   ``AuronSession.execute``: ``task_pool.run_tasks`` copies the ambient
   context into its worker threads, so spans recorded on pool threads
@@ -37,6 +42,7 @@ server's `/queries` page and the Prometheus `/metrics` aggregation.
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import logging
 import os
@@ -45,6 +51,8 @@ import time
 import uuid
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from auron_tpu.config import conf
 from auron_tpu.runtime import lockcheck
@@ -75,6 +83,20 @@ class Span:
     tid: int
     thread: str
     args: Optional[Dict[str, Any]] = None
+    # this span's id within its recorder, and the id of the span that
+    # caused it: the enclosing span on the same thread, else the span
+    # that submitted the task (0 = none; instants carry no id)
+    id: int = 0
+    parent: int = 0
+
+
+def _export_args(s: Span) -> Optional[Dict[str, Any]]:
+    """A span's args as exported: its own, plus `id` and `parent` —
+    nesting as recorded, not guessed from intervals (which parallel scan
+    tasks break)."""
+    if not s.id:
+        return s.args
+    return {**(s.args or {}), "id": s.id, "parent": s.parent}
 
 
 class TraceRecorder:
@@ -82,8 +104,13 @@ class TraceRecorder:
 
     def __init__(self, query_id: str, max_events: Optional[int] = None):
         self.query_id = query_id
+        # the two clocks read back to back: t0_ns + epoch_unix_ns is a
+        # span's start on the unix clock, the one a profile is anchored
+        # on (its "Task Environment" plane's profile_start_time)
         self.epoch_ns = time.perf_counter_ns()
-        self.wall_start = time.time()
+        self.epoch_unix_ns = time.time_ns()
+        self.wall_start = self.epoch_unix_ns / 1e9
+        self._ids = itertools.count(1)
         self.max_events = int(conf.get("auron.trace.max.events")) \
             if max_events is None else int(max_events)
         self.spans: List[Span] = []
@@ -97,11 +124,12 @@ class TraceRecorder:
 
     # hot path — called from _SpanCtx.__exit__ and event()
     def add(self, name: str, cat: str, t0_ns: int, dur_ns: int,
-            args: Optional[Dict[str, Any]]) -> None:
+            args: Optional[Dict[str, Any]], span_id: int = 0,
+            parent: int = 0) -> None:
         t = threading.current_thread()
         s = Span(name=name, cat=cat, t0_ns=t0_ns - self.epoch_ns,
                  dur_ns=dur_ns, tid=t.ident or 0, thread=t.name,
-                 args=args or None)
+                 args=args or None, id=span_id, parent=parent)
         first_drop = False
         with self._lock:
             if len(self.spans) >= self.max_events:
@@ -181,10 +209,18 @@ class TraceRecorder:
                 ev["dur"] = s.dur_ns / 1000.0
             else:
                 ev["s"] = "t"   # instant scope: thread
-            if s.args:
-                ev["args"] = s.args
+            args = _export_args(s)
+            if args:
+                ev["args"] = args
             events.append(ev)
         return events
+
+    def _other_data(self) -> Dict[str, Any]:
+        return {"query_id": self.query_id,
+                "dropped_events": self.dropped,
+                "trace_truncated": self.dropped > 0,
+                "wall_start": self.wall_start,
+                "epoch_unix_ns": self.epoch_unix_ns}
 
     def to_chrome_trace(self) -> Dict[str, Any]:
         """Chrome trace-event JSON (the `traceEvents` array form): spans
@@ -193,10 +229,7 @@ class TraceRecorder:
         return {"traceEvents": self._span_events(self.snapshot(),
                                                  os.getpid()),
                 "displayTimeUnit": "ms",
-                "otherData": {"query_id": self.query_id,
-                              "dropped_events": self.dropped,
-                              "trace_truncated": self.dropped > 0,
-                              "wall_start": self.wall_start}}
+                "otherData": self._other_data()}
 
     def export_spans(self, spans: List[Span],
                      next_since: Optional[int] = None) -> Dict[str, Any]:
@@ -205,11 +238,7 @@ class TraceRecorder:
         carrying the cursor the next poll should pass as `since`."""
         doc = {"traceEvents": self._span_events(spans, os.getpid()),
                "displayTimeUnit": "ms",
-               "otherData": {"query_id": self.query_id,
-                             "dropped_events": self.dropped,
-                             "trace_truncated": self.dropped > 0,
-                             "wall_start": self.wall_start,
-                             "partial": True}}
+               "otherData": {**self._other_data(), "partial": True}}
         if next_since is not None:
             doc["otherData"]["next_since"] = int(next_since)
         return doc
@@ -223,6 +252,8 @@ class TraceRecorder:
 class _NoopSpan:
     """Shared do-nothing context manager: the OFF path allocates zero."""
     __slots__ = ()
+    # a site whose args cost something to compute asks first
+    armed = False
 
     def __enter__(self) -> "_NoopSpan":
         return self
@@ -238,7 +269,9 @@ _NOOP = _NoopSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("_rec", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_rec", "_name", "_cat", "_args", "_t0", "_id", "_parent",
+                 "_tok", "_ann")
+    armed = True
 
     def __init__(self, rec: TraceRecorder, name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -248,6 +281,13 @@ class _SpanCtx:
         self._args = args
 
     def __enter__(self) -> "_SpanCtx":
+        self._id = next(self._rec._ids)
+        # task_pool runs each task in a copy of the submitting context,
+        # so a task's first span finds its submitter's span here
+        self._parent = _span_id.get()
+        self._tok = _span_id.set(self._id)
+        self._ann = TraceAnnotation(self._name, **(self._args or {}))
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -261,11 +301,19 @@ class _SpanCtx:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        try:
+            _span_id.reset(self._tok)
+        except ValueError:
+            # closed in another context than it was opened in (a
+            # generator finalized from another thread)
+            _span_id.set(self._parent)
         if exc is not None:
             args = dict(self._args or {})
             args["error"] = f"{type(exc).__name__}: {exc}"
             self._args = args
-        self._rec.add(self._name, self._cat, self._t0, dur, self._args)
+        self._rec.add(self._name, self._cat, self._t0, dur, self._args,
+                      self._id, self._parent)
         return False
 
 
@@ -304,6 +352,9 @@ _query_id: contextvars.ContextVar[Optional[str]] = \
     contextvars.ContextVar("auron_query_id", default=None)
 _stats: contextvars.ContextVar[Optional[QueryStats]] = \
     contextvars.ContextVar("auron_query_stats", default=None)
+# id of the innermost open span (0 = none); only armed spans touch it
+_span_id: contextvars.ContextVar[int] = \
+    contextvars.ContextVar("auron_span_id", default=0)
 
 # recorders of queries currently IN FLIGHT, keyed by query id — the
 # incremental trace drain (`GET /queries/<id>/trace?since=`) and the
@@ -680,7 +731,7 @@ def _span_abs(rec: TraceRecorder, s: Span) -> Dict[str, Any]:
     return {"name": s.name, "cat": s.cat,
             "ts_us": rec.wall_start * 1e6 + s.t0_ns / 1e3,
             "dur_us": s.dur_ns / 1e3 if s.dur_ns >= 0 else -1,
-            "tid": s.tid, "thread": s.thread, "args": s.args}
+            "tid": s.tid, "thread": s.thread, "args": _export_args(s)}
 
 
 def _doc_abs_spans(doc: Dict[str, Any]) -> List[Dict[str, Any]]:

@@ -3,6 +3,7 @@
     python -m auron_tpu.trace run --query q01 --sf 0.002 -o /tmp/q01.json
     python -m auron_tpu.trace validate /tmp/q01.json
     python -m auron_tpu.trace summary /tmp/q01.json --top 15
+    python -m auron_tpu.trace device /tmp/profile_dir --top 20
 
 `run` executes one TPC-DS corpus query with `auron.trace.enable` on and
 writes the Chrome-trace JSON (load in chrome://tracing or
@@ -10,14 +11,28 @@ ui.perfetto.dev); `validate` re-checks the schema invariants the
 Perfetto importer relies on (exit 2 on any error); `summary` prints
 per-span aggregates and the critical path.  This is the command-line
 face of runtime/tracing.py, wired into CI by tools/trace_check.sh.
+
+`device` is the stage path's per-operator view: it reads the
+`.xplane.pb` a `jax.profiler` trace left under the directory, takes
+each device operation's self time (a while loop without its children)
+and files it under the plan-operator label (`<kind>#<i>`, the
+`jax.named_scope` of `_StageTracer.eval_node`; EXPLAIN ANALYZE prints
+the labelled plan) and sub-scope (`build`, `probe`, `group`, ...) found
+in the operation's scope path.  A program loaded from a persistent
+compile cache carries the scopes of the process that compiled it: JAX
+keeps debug info out of the cache key, so a program cached before the
+scopes existed files under `unlabelled` until the cache is cleared.
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import json
+import os
+import re
 import sys
-from typing import List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from auron_tpu.runtime.tracing import (
     summarize_chrome_trace, validate_chrome_trace,
@@ -118,6 +133,235 @@ def _cmd_run(args: argparse.Namespace) -> int:
     return 0
 
 
+# ---------------------------------------------------------------------------
+# device profile -> seconds per plan operator
+# ---------------------------------------------------------------------------
+
+# one device operation: name, scope path, start_ns, duration_ns
+DeviceOp = Tuple[str, str, float, float]
+
+UNLABELLED = "unlabelled"
+_OP_LABEL = re.compile(r"^[a-z_]+#[0-9]+$")
+# the scopes opened beneath an operator's (parallel/stage.py,
+# ops/agg/exec.py::_group_reduce_body); anything else that follows a
+# label in a scope path is a jit or primitive name
+_SUB_SCOPES = frozenset({"build", "probe", "exchange", "broadcast",
+                         "group", "reduce", "compact"})
+
+
+def label_of(scope_path: str) -> Tuple[str, str]:
+    """(operator label, sub-scope) of a scope path such as
+    `jit(program)/agg#0/broadcast_join#2/probe/jit(_take)/gather`: the
+    innermost operator label and the sub-scope right under it; the
+    stage program's `epilogue`; else `unlabelled`."""
+    parts = scope_path.split("/")
+    for i in range(len(parts) - 1, -1, -1):
+        if _OP_LABEL.match(parts[i]):
+            sub = parts[i + 1] if i + 1 < len(parts) and \
+                parts[i + 1] in _SUB_SCOPES else ""
+            return parts[i], sub
+    if "epilogue" in parts:
+        return "epilogue", ""
+    return UNLABELLED, ""
+
+
+def self_times(ops: Sequence[DeviceOp]) -> List[float]:
+    """Self nanoseconds of each operation, in the order given: a parent
+    (a while loop, a fusion's caller) without the children nested inside
+    its interval."""
+    order = sorted(range(len(ops)), key=lambda i: (ops[i][2], -ops[i][3]))
+    self_ns = [op[3] for op in ops]
+    stack: List[int] = []
+    for i in order:
+        _name, _scope, start, dur = ops[i]
+        while stack and ops[stack[-1]][2] + ops[stack[-1]][3] <= start:
+            stack.pop()
+        if stack:
+            top = ops[stack[-1]]
+            self_ns[stack[-1]] -= min(dur, top[2] + top[3] - start)
+        stack.append(i)
+    return self_ns
+
+
+def device_summary(ops: Sequence[DeviceOp]) -> Dict[str, object]:
+    """Self time by (operator label, sub-scope) and by operation name.
+    `rows`: [label, sub, seconds, share of busy, operations, longest
+    operation's name, its seconds], most seconds first; `ops`: [name,
+    label, sub, seconds, count] likewise; shares sum to 1."""
+    self_ns = self_times(ops)
+    busy = sum(self_ns)
+    groups: Dict[Tuple[str, str], List] = {}
+    by_name: Dict[Tuple[str, str, str], List] = {}
+    for (name, scope, _start, _dur), ns in zip(ops, self_ns):
+        key = label_of(scope)
+        n = by_name.setdefault((name, *key), [0.0, 0])
+        n[0] += ns
+        n[1] += 1
+        g = groups.setdefault(key, [0.0, 0])
+        g[0] += ns
+        g[1] += 1
+    longest: Dict[Tuple[str, str], Tuple[str, float]] = {}
+    for (name, label, sub), (ns, _n) in by_name.items():
+        if ns > longest.get((label, sub), ("", -1.0))[1]:
+            longest[(label, sub)] = (name, ns)
+    rows = [[label, sub, ns / 1e9, ns / busy if busy else 0.0, n,
+             longest[(label, sub)][0], longest[(label, sub)][1] / 1e9]
+            for (label, sub), (ns, n) in groups.items()]
+    rows.sort(key=lambda r: -r[2])
+    names = [[name, label, sub, ns / 1e9, n]
+             for (name, label, sub), (ns, n) in by_name.items()]
+    names.sort(key=lambda r: -r[3])
+    labelled = sum(r[2] for r in rows if r[0] != UNLABELLED)
+    return {"busy_s": busy / 1e9, "labelled_s": labelled,
+            "rows": rows, "ops": names}
+
+
+_SHAPE = re.compile(r"[a-z]+[0-9]*\[[0-9,]*\]")
+_OPCODE = re.compile(r" [a-z][a-z0-9\-]*\(")
+
+
+def short_op_name(hlo: str) -> str:
+    """`%fusion.7 = u32[4096]{..} fusion(u32[8]{..} %a, s32[4096]{..} %b),
+    kind=..` as `fusion.7 u32[4096]<-u32[8],s32[4096]`: XLA's name for
+    the operation with the shapes that say what it is."""
+    name, eq, rest = hlo.partition(" = ")
+    name = name.lstrip("%")
+    call = _OPCODE.search(rest) if eq else None
+    if call is None:
+        return name
+    shapes = _SHAPE.findall(rest[:call.start()])
+    args = _SHAPE.findall(rest[call.end():].split("), ")[0])
+    return f"{name} {','.join(shapes)}<-{','.join(args)}"[:120]
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        if b < 0x80:
+            return out, i
+        shift += 7
+
+
+def _pb_fields(buf):
+    """(field number, value) of every field of one serialized protobuf
+    message: an int for a varint, the bytes for a length-delimited or
+    fixed-width field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            value, i = buf[i:i + size], i + size
+        else:
+            raise ValueError(f"protobuf wire type {wire} at byte {i}")
+        yield key >> 3, value
+
+
+def read_scope_paths(xplane_path: str) -> Dict[str, Dict[str, str]]:
+    """{device plane: {event name: scope path}}.  On the v5e under jax
+    0.9.0 a device event's name is its HLO text and its own stats are
+    times only; the HLO metadata's op_name — the `jax.named_scope` path —
+    is the stat `tf_op` of the event's *metadata*, which
+    `jax.profiler.ProfileData` does not hand out.  So the few fields that
+    hold it are read from the file itself (tsl's xplane.proto: XSpace.planes
+    = 1; XPlane.name = 2, .event_metadata = 4, .stat_metadata = 5, both
+    maps; XEventMetadata.name = 2, .stats = 5; XStat.metadata_id = 1,
+    .str_value = 5, .ref_value = 7; XStatMetadata.id = 1, .name = 2)."""
+    with open(xplane_path, "rb") as f:
+        space = memoryview(f.read())
+    out: Dict[str, Dict[str, str]] = {}
+    for field, plane in _pb_fields(space):
+        if field != 1:
+            continue
+        plane_name, stat_names, events = "", {}, []
+        for pf, value in _pb_fields(plane):
+            if pf == 2:
+                plane_name = bytes(value).decode()
+            elif pf == 5:
+                meta = dict(_pb_fields(dict(_pb_fields(value))[2]))
+                stat_names[meta[1]] = bytes(meta.get(2, b"")).decode()
+            elif pf == 4:
+                events.append(dict(_pb_fields(value))[2])
+        if not plane_name.startswith("/device:"):
+            continue
+        scopes = out.setdefault(plane_name, {})
+        for meta in events:
+            name, scope = "", ""
+            for mf, value in _pb_fields(meta):
+                if mf == 2:
+                    name = bytes(value).decode()
+                elif mf == 5:
+                    stat = dict(_pb_fields(value))
+                    if stat_names.get(stat.get(1)) != "tf_op":
+                        continue
+                    scope = bytes(stat[5]).decode() if 5 in stat \
+                        else stat_names.get(stat.get(7), "")
+            scopes[name] = scope
+    return out
+
+
+def read_device_ops(profile_dir: str) -> Dict[str, List[DeviceOp]]:
+    """Per device plane, the events of its "XLA Ops" line (one per
+    executed HLO operation), each with its scope path."""
+    from jax.profiler import ProfileData
+    paths = [profile_dir] if os.path.isfile(profile_dir) else sorted(
+        glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                  recursive=True))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
+    scopes = read_scope_paths(paths[-1])
+    out: Dict[str, List[DeviceOp]] = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        of_event = scopes.get(plane.name, {})
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                out.setdefault(plane.name, []).extend(
+                    (short_op_name(e.name), of_event.get(e.name, ""),
+                     float(e.start_ns), float(e.duration_ns))
+                    for e in line.events)
+    return out
+
+
+def _cmd_device(args: argparse.Namespace) -> int:
+    try:
+        planes = read_device_ops(args.profile)
+    except FileNotFoundError as e:
+        print(f"trace: {e}", file=sys.stderr)
+        return 2
+    if not planes:
+        print("trace: the profile holds no device plane with an "
+              "'XLA Ops' line (a CPU profile has none)", file=sys.stderr)
+        return 2
+    for plane, ops in sorted(planes.items()):
+        doc = device_summary(ops)
+        busy = doc["busy_s"]
+        print(f"{plane}: {len(ops)} operations, busy {busy:.6f} s, "
+              f"{100 * doc['labelled_s'] / busy if busy else 0:.2f} % "
+              f"under an operator label")
+        print(f"{'label':28} {'scope':10} {'seconds':>11} {'share':>7} "
+              f"{'ops':>6}  longest operation (its seconds)")
+        for label, sub, sec, share, n, name, name_s in \
+                doc["rows"][:args.top]:
+            print(f"{label[:28]:28} {sub:10} {sec:11.6f} "
+                  f"{100 * share:6.2f}% {n:6d}  {name} ({name_s:.6f})")
+        print(f"the {args.ops} operations with most self time:")
+        for name, label, sub, sec, n in doc["ops"][:args.ops]:
+            where = f"{label}/{sub}" if sub else label
+            print(f"  {sec:11.6f} s  x{n:<4d} {where:32} {name}")
+    return 0
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(prog="auron_tpu.trace")
     sub = ap.add_subparsers(dest="cmd", required=True)
@@ -154,6 +398,17 @@ def main(argv: Optional[List[str]] = None) -> int:
     summ.add_argument("file")
     summ.add_argument("--top", type=int, default=10)
     summ.set_defaults(fn=_cmd_summary)
+
+    dev = sub.add_parser(
+        "device", help="device seconds per plan operator, from a "
+                       "jax.profiler trace directory of a stage-path run")
+    dev.add_argument("profile", help="the directory handed to "
+                     "jax.profiler.start_trace, or one .xplane.pb")
+    dev.add_argument("--top", type=int, default=30,
+                     help="rows of (label, sub-scope) to print")
+    dev.add_argument("--ops", type=int, default=15,
+                     help="single operations to print")
+    dev.set_defaults(fn=_cmd_device)
 
     args = ap.parse_args(argv)
     return args.fn(args)

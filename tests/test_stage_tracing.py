@@ -1,0 +1,408 @@
+"""Every second of a stage-path execute under a name (PR 26): a named
+scope per plan operator inside the stage program, leaf spans under
+`task.execute` / `spmd.shard` / `spmd.gather`, the program's spans on the
+profiler's clock, and the `python -m auron_tpu.trace device` reduction."""
+
+import glob
+import os
+
+import numpy as np
+import pyarrow as pa
+import pyarrow.parquet as pq
+import pytest
+
+import jax
+
+from auron_tpu import trace as trace_cli
+from auron_tpu.config import conf
+from auron_tpu.frontend.converters import BroadcastJob, ShuffleJob
+from auron_tpu.ir import plan as P
+from auron_tpu.ir.expr import AggExpr, SortExpr, col, lit
+from auron_tpu.ir import expr as E
+from auron_tpu.ir.plan import FileGroup, JoinOn
+from auron_tpu.ir.schema import DataType, Field, Schema
+from auron_tpu.parallel import stage as S
+from auron_tpu.parallel.mesh import data_mesh
+from auron_tpu.runtime import tracing
+
+I64 = DataType.int64()
+F64 = DataType.float64()
+
+# the leaves of the issue's table and the span each lies under
+LEAVES = {
+    "scan.decode": "task.execute", "scan.to_device": "task.execute",
+    "task.to_host": "task.execute", "spmd.tail": "spmd.launch",
+    "shard.pad": "spmd.shard", "shard.put": "spmd.shard",
+    "spmd.wait": "spmd.gather", "spmd.fetch": "spmd.gather",
+}
+# operators that bind or pass a table on and trace no operation
+PASS_THROUGH = ("parquet_scan", "broadcast_join_build_hash_map")
+
+
+class _Ctx:
+    def __init__(self):
+        self.exchanges = {}
+        self.broadcasts = {}
+
+
+@pytest.fixture(scope="module")
+def files(tmp_path_factory):
+    """A fact table in three files and two dimension tables."""
+    d = tmp_path_factory.mktemp("stage_tracing")
+    rng = np.random.default_rng(7)
+    fact = []
+    for i in range(3):
+        path = str(d / f"fact{i}.parquet")
+        pq.write_table(pa.table({
+            "k1": rng.integers(0, 16, 400).astype(np.int64),
+            "k2": rng.integers(0, 8, 400).astype(np.int64),
+            "amount": rng.normal(10, 3, 400)}), path)
+        fact.append(path)
+    dims = {}
+    for name, n in (("d1", 16), ("d2", 8)):
+        dims[name] = str(d / f"{name}.parquet")
+        pq.write_table(pa.table({
+            f"{name}_key": np.arange(n, dtype=np.int64),
+            f"{name}_grp": (np.arange(n, dtype=np.int64) % 4)}),
+            dims[name])
+    return {"fact": fact, **dims}
+
+
+def build_plan(files, uid):
+    """scan -> filter -> broadcast join x2 -> partial agg -> hash exchange
+    -> final agg -> sort (the driver tail); resource ids as one conversion
+    (`uid`) would mint them."""
+    ctx = _Ctx()
+    fact = P.ParquetScan(
+        schema=Schema((Field("k1", I64), Field("k2", I64),
+                       Field("amount", F64))),
+        file_groups=tuple(FileGroup(paths=(p,)) for p in files["fact"]))
+    node = P.Filter(child=fact, predicates=(
+        E.BinaryExpr(op=">", left=col("amount"), right=lit(0.0)),))
+    for name, key in (("d1", "k1"), ("d2", "k2")):
+        dim = P.ParquetScan(
+            schema=Schema((Field(f"{name}_key", I64),
+                           Field(f"{name}_grp", I64))),
+            file_groups=(FileGroup(paths=(files[name],)),))
+        rid = f"bc:{uid}:{name}"
+        ctx.broadcasts[rid] = BroadcastJob(rid=rid, child=dim, schema=None)
+        node = P.BroadcastJoin(
+            left=node, right=P.IpcReader(schema=None, resource_id=rid),
+            on=JoinOn(left_keys=(col(key),),
+                      right_keys=(col(f"{name}_key"),)),
+            join_type="inner", broadcast_side="right")
+    agg = dict(grouping=(col("d1_grp"),), grouping_names=("d1_grp",),
+               aggs=(AggExpr(fn="sum", children=(col("amount"),),
+                             return_type=F64),), agg_names=("s",))
+    rid = f"ex:{uid}"
+    ctx.exchanges[rid] = ShuffleJob(
+        rid=rid, child=P.Agg(child=node, exec_mode="partial", **agg),
+        partitioning=P.Partitioning(mode="hash", num_partitions=8,
+                                    expressions=(col("d1_grp"),)),
+        schema=None)
+    final = P.Agg(child=P.IpcReader(schema=None, resource_id=rid),
+                  exec_mode="final", **agg)
+    return P.Sort(child=final,
+                  sort_exprs=(SortExpr(child=col("d1_grp")),)), ctx
+
+
+def test_operator_scopes_in_the_lowered_stage_program(files):
+    mesh = data_mesh(8)
+    p1, c1 = build_plan(files, "aaaa1111")
+    p2, c2 = build_plan(files, "bbbb2222")
+    before = set(S._PROGRAM_CACHE)
+    got1 = S.execute_plan_spmd(p1, c1, mesh, {})
+    [key] = set(S._PROGRAM_CACHE) - before
+    shard, schema_box = S._PROGRAM_CACHE[key]
+    calls = []
+
+    def spy(inputs):
+        calls.append(inputs)
+        return shard(inputs)
+
+    S._PROGRAM_CACHE[key] = (spy, schema_box)
+    try:
+        got2 = S.execute_plan_spmd(p2, c2, mesh, {})
+    finally:
+        S._PROGRAM_CACHE[key] = (shard, schema_box)
+    # the second conversion ran the first one's program
+    assert len(calls) == 1 and set(S._PROGRAM_CACHE) - before == {key}
+    assert got1.to_pylist() == got2.to_pylist()
+
+    text = shard.__wrapped__.lower(calls[0]).as_text(debug_info=True)
+    explained = S.explain_stage(p1, c1)
+    assert explained == S.explain_stage(p2, c2)
+    assert explained.splitlines()[0].startswith("sort (driver tail")
+    labels = [line.split()[0] for line in explained.splitlines()[1:]]
+    assert len(labels) == len(set(labels)) == 11
+    kinds = [lb.split("#")[0] for lb in labels]
+    assert kinds.count("broadcast_join") == 2 and kinds.count("agg") == 2
+    for label in labels:
+        if label.startswith(PASS_THROUGH):
+            continue
+        # the root's path starts the scope string, a child's follows "/"
+        assert f'"{label}/' in text or f"/{label}/" in text, label
+        if label.startswith("broadcast_join#"):
+            assert f"{label}/build/" in text and f"{label}/probe/" in text
+        if label.startswith("agg#"):
+            assert f"{label}/group/" in text and f"{label}/reduce/" in text
+    # the collectives lie under their boundary's label
+    assert "/exchange/" in text and "/broadcast/" in text
+    assert '"epilogue/' in text
+    # nesting reads back to the tree: a child's scope inside its parent's
+    join_labels = [lb for lb in labels if lb.startswith("broadcast_join#")]
+    assert f"{join_labels[0]}/{join_labels[1]}/probe/" in text
+
+
+def _traced_execute(files, uid, scope):
+    plan, ctx = build_plan(files, uid)
+    rec = tracing.TraceRecorder(uid, max_events=10_000)
+    S.clear_source_caches()
+    with conf.scoped(scope), tracing.trace_scope(recorder=rec,
+                                                 query_id=uid):
+        with tracing.span("query", cat="query", query_id=uid):
+            table = S.execute_plan_spmd(plan, ctx, data_mesh(8), {})
+    return table, rec
+
+
+def test_leaf_spans_nest_under_their_parents(files):
+    table, rec = _traced_execute(
+        files, "leaves", {"auron.spmd.gather.compact": "on",
+                          "auron.task.parallelism": 4})
+    assert table.num_rows == 4
+    spans = [s for s in rec.snapshot() if s.dur_ns >= 0]
+    by_id = {s.id: s for s in spans}
+    assert len(by_id) == len(spans) and 0 not in by_id
+    names = {s.name for s in spans}
+    assert set(LEAVES) <= names, set(LEAVES) - names
+    for s in spans:
+        if s.name not in LEAVES:
+            continue
+        parent = by_id[s.parent]
+        assert parent.name == LEAVES[s.name], (s.name, parent.name)
+        assert parent.t0_ns <= s.t0_ns and \
+            s.t0_ns + s.dur_ns <= parent.t0_ns + parent.dur_ns
+    # children of one thread sum to no more than their parent
+    for parent in spans:
+        per_thread = {}
+        for s in spans:
+            if s.parent == parent.id:
+                per_thread[s.tid] = per_thread.get(s.tid, 0) + s.dur_ns
+        assert all(v <= parent.dur_ns for v in per_thread.values()), \
+            parent.name
+    # a scan task's first span finds the span that submitted it
+    ingest = next(s for s in spans if s.name == "spmd.ingest")
+    tasks = [s for s in spans if s.name == "task.execute"
+             and s.parent == ingest.id]
+    assert len(tasks) == 5            # three fact files, two dimensions
+    decode = [s for s in spans if s.name == "scan.decode" and s.args]
+    assert sum(s.args["rows"] for s in decode) == 1200 + 16 + 8
+    assert all(s.args["bytes"] > 0 for s in decode)
+    pads = [s for s in spans if s.name == "shard.pad"]
+    assert sorted(s.args["rows"] for s in pads) == [8, 16, 1200]
+    fetch = next(s for s in spans if s.name == "spmd.fetch")
+    assert fetch.args["rows"] == 4 and fetch.args["bytes"] > 0
+    # exported: the id and the parent of every span, and the unix anchor
+    doc = rec.to_chrome_trace()
+    assert tracing.validate_chrome_trace(doc) == []
+    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert all(e["args"]["id"] and "parent" in e["args"] for e in xs)
+    assert doc["otherData"]["epoch_unix_ns"] == rec.epoch_unix_ns
+    assert not any(s.name == "execute" for s in spans)
+
+
+def test_tracing_off_records_and_allocates_nothing(files, tmp_path):
+    plan, ctx = build_plan(files, "off")
+    assert tracing.current_recorder() is None
+    table = S.execute_plan_spmd(plan, ctx, data_mesh(8), {})
+    assert table.num_rows == 4
+    noop = tracing.span("scan.decode", cat="scan")
+    assert noop is tracing.span("shard.put") and not noop.armed
+    # through a session: the result carries no recorder
+    from auron_tpu.frontend.session import AuronSession
+    from auron_tpu.it import queries
+    from auron_tpu.it.datagen import generate
+    from auron_tpu.it.oracle import PyArrowEngine
+    catalog = generate(str(tmp_path / "tpcds"), sf=0.002)
+    session = AuronSession(foreign_engine=PyArrowEngine())
+    res = session.execute(queries.build("q03", catalog))
+    assert res.spmd and res.trace is None
+    text = res.explain_analyze()
+    assert "python -m auron_tpu.trace device" in text
+    assert "broadcast_join#" in text and "(driver tail" in text
+    assert text == res.explain_analyze()
+
+
+def test_program_spans_on_the_profilers_clock(files, tmp_path):
+    """Under a profile every program span is an annotation in the host
+    plane, and `epoch_unix_ns + t0_ns` is its start on the profile's
+    clock: this jax's profile counts nanoseconds from the
+    `profile_start_time` (unix) of its "Task Environment" plane."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _table, rec = _traced_execute(
+            files, "profiled", {"auron.spmd.gather.compact": "on"})
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                    "*", "*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    start_unix = next(dict(p.stats)["profile_start_time"]
+                      for p in data.planes if p.name == "Task Environment")
+    recorded = [s for s in rec.snapshot() if s.dur_ns >= 0]
+    wanted = {s.name for s in recorded}
+    assert set(LEAVES) | {"query", "task.execute"} <= wanted
+    # the enqueue: `spmd.compile` where this process runs the program first
+    assert wanted & {"spmd.run", "spmd.compile"}
+    found = {}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in wanted:
+                    found.setdefault(e.name, []).append(e)
+    for name in wanted:
+        assert len(found.get(name, ())) == \
+            sum(s.name == name for s in recorded), name
+    [query] = [s for s in recorded if s.name == "query"]
+    [mark] = found["query"]
+    assert dict(mark.stats)["query_id"] == "profiled"
+    gap_ns = (rec.epoch_unix_ns + query.t0_ns) - (start_unix
+                                                  + mark.start_ns)
+    assert abs(gap_ns) < 5e6, gap_ns
+    # the annotation encloses the span it carries
+    assert mark.duration_ns >= query.dur_ns
+
+
+# name, scope path, start_ns, duration_ns
+OPS = [
+    ("while.1", "jit(program)/agg#0/reduce/while", 0.0, 100.0),
+    ("fusion.2", "jit(program)/agg#0/reduce/while/body/add", 10.0, 30.0),
+    ("fusion.3", "jit(program)/agg#0/reduce/while/body/mul", 50.0, 20.0),
+    ("fusion.4 u32[8]<-u32[2],s32[8]",
+     "jit(program)/agg#0/broadcast_join#1/probe/jit(_take)/gather",
+     100.0, 50.0),
+    ("fusion.4 u32[8]<-u32[2],s32[8]",
+     "jit(program)/agg#0/broadcast_join#1/probe/jit(_take)/gather",
+     150.0, 30.0),
+    ("fusion.5", "jit(program)/agg#0/broadcast_join#1/build/sort",
+     180.0, 20.0),
+    ("fusion.6", "jit(program)/agg#0/broadcast_join#1/filter#2/and",
+     200.0, 10.0),
+    ("copy.7", "", 210.0, 10.0),
+    ("fusion.8", "jit(program)/epilogue/gather", 220.0, 30.0),
+]
+
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(p)/agg#0/broadcast_join#1/probe/jit(_take)/gather",
+     ("broadcast_join#1", "probe")),
+    ("jit(p)/agg#0/broadcast_join#1/filter#2/and", ("filter#2", "")),
+    ("jit(p)/agg#0/reduce/while/body/add", ("agg#0", "reduce")),
+    ("jit(p)/ipc_reader#15/broadcast/all_gather",
+     ("ipc_reader#15", "broadcast")),
+    ("jit(p)/agg#0/reduce_sum", ("agg#0", "")),
+    ("jit(p)/epilogue/gather", ("epilogue", "")),
+    ("jit(p)/jit(_take)/gather", (trace_cli.UNLABELLED, "")),
+    ("", (trace_cli.UNLABELLED, "")),
+])
+def test_label_of_a_scope_path(path, want):
+    assert trace_cli.label_of(path) == want
+
+
+def test_device_summary_on_hand_built_events():
+    assert trace_cli.self_times(OPS) == [50.0, 30.0, 20.0, 50.0, 30.0,
+                                         20.0, 10.0, 10.0, 30.0]
+    doc = trace_cli.device_summary(OPS)
+    assert doc["busy_s"] == pytest.approx(250e-9)
+    assert doc["labelled_s"] == pytest.approx(240e-9)
+    rows = {(r[0], r[1]): r for r in doc["rows"]}
+    assert set(rows) == {("agg#0", "reduce"), ("broadcast_join#1", "probe"),
+                         ("broadcast_join#1", "build"), ("filter#2", ""),
+                         ("epilogue", ""), (trace_cli.UNLABELLED, "")}
+    assert sum(r[3] for r in doc["rows"]) == pytest.approx(1.0)
+    # a while loop without its children; the children under their scope
+    assert rows[("agg#0", "reduce")][2:5] == [pytest.approx(100e-9),
+                                              pytest.approx(0.4), 3]
+    assert rows[("agg#0", "reduce")][5] == "while.1"
+    probe = rows[("broadcast_join#1", "probe")]
+    assert probe[2] == pytest.approx(80e-9) and probe[4] == 2
+    assert probe[5] == "fusion.4 u32[8]<-u32[2],s32[8]"
+    assert probe[6] == pytest.approx(80e-9)
+    assert rows[(trace_cli.UNLABELLED, "")][5] == "copy.7"
+    # most seconds first, by group and by operation
+    assert [r[2] for r in doc["rows"]] == \
+        sorted((r[2] for r in doc["rows"]), reverse=True)
+    assert doc["ops"][0][:3] == ["fusion.4 u32[8]<-u32[2],s32[8]",
+                                 "broadcast_join#1", "probe"]
+
+
+def _pb(*fields) -> bytes:
+    """One protobuf message from (field number, int | bytes | str)."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+    out = b""
+    for number, value in fields:
+        if isinstance(value, int):
+            out += varint(number << 3) + varint(value)
+        else:
+            value = value.encode() if isinstance(value, str) else value
+            out += varint(number << 3 | 2) + varint(len(value)) + value
+    return out
+
+
+def _xspace(ops) -> bytes:
+    """An XSpace as the v5e's profile holds one: a device plane whose
+    "XLA Ops" events carry times only, the scope path in the `tf_op` stat
+    of each event's metadata; and a host plane."""
+    names = sorted({(name, scope) for name, scope, _s, _d in ops})
+    ids = {key: i + 1 for i, key in enumerate(names)}
+    metadata = [
+        (4, _pb((1, ids[key]), (2, _pb(
+            (1, ids[key]), (2, f"%{key[0]} = u32[8]{{0}} fusion(u32[2]{{0}} "
+                               f"%a, s32[8]{{0}} %b), kind=kLoop"),
+            (5, _pb((1, 7), (5, key[1]))))))) for key in names]
+    events = [(4, _pb((1, ids[(name, scope)]), (2, int(start * 1000)),
+                      (3, int(dur * 1000))))
+              for name, scope, start, dur in ops]
+    device = _pb((2, "/device:TPU:0"),
+                 (3, _pb((1, 1), (2, "XLA Ops"), (3, 0), *events)),
+                 *metadata,
+                 (5, _pb((1, 7), (2, _pb((1, 7), (2, "tf_op"))))))
+    host = _pb((2, "/host:CPU"), (3, _pb((1, 2), (2, "main"), (3, 0))))
+    return _pb((1, device), (1, host))
+
+
+def test_device_subcommand_on_a_hand_built_profile(tmp_path, capsys):
+    assert trace_cli.main(["device", str(tmp_path)]) == 2
+    assert "no .xplane.pb" in capsys.readouterr().err
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(_xspace(
+        [(n.split()[0], scope, s, dur) for n, scope, s, dur in OPS]))
+    ops = trace_cli.read_device_ops(str(tmp_path))["/device:TPU:0"]
+    assert [(o[1], o[2], o[3]) for o in ops] == \
+        [(scope, s, dur) for _n, scope, s, dur in OPS]
+    assert ops[3][0] == "fusion.4 u32[8]<-u32[2],s32[8]"
+    assert trace_cli.main(["device", str(tmp_path), "--ops", "3"]) == 0
+    out = capsys.readouterr().out
+    assert "9 operations" in out and "96.00 % under an operator" in out
+    assert "broadcast_join#1/probe" in out and "unlabelled" in out
+
+
+def test_short_op_name():
+    hlo = ("%fusion.7 = u32[4096]{0:T(1024)} fusion(u32[8]{0} %a, "
+           "s32[4096]{0} %b), kind=kLoop, calls=%fused")
+    assert trace_cli.short_op_name(hlo) == \
+        "fusion.7 u32[4096]<-u32[8],s32[4096]"
+    assert trace_cli.short_op_name("%copy.1") == "copy.1"

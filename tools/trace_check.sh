@@ -5,7 +5,10 @@
 #      dumping Chrome-trace JSON (`python -m auron_tpu.trace run`
 #      validates the schema before writing)
 #   2. re-validate the dumped file through the standalone validator
-#   3. check the committed EXPLAIN ANALYZE goldens via the pytest hook
+#   3. run one query with tracing ON through the stage path (the
+#      default) and assert the leaf spans under task.execute, spmd.shard
+#      and spmd.gather exist, each with the span that caused it
+#   4. check the committed EXPLAIN ANALYZE goldens via the pytest hook
 #      (tests/test_observability.py; regen with AURON_REGEN_GOLDEN=1)
 #
 # The same checks run inside the suite (tests/test_observability.py::
@@ -24,6 +27,28 @@ JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m auron_tpu.trace run \
 
 JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m auron_tpu.trace validate \
     "$out_dir/q01.trace.json"
+
+# the compact gather (the accelerators' default) is what splits
+# spmd.gather into spmd.wait and spmd.fetch
+JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} AURON_TPU_AURON_SPMD_GATHER_COMPACT=on \
+    python -m auron_tpu.trace run --query q03 --sf 0.002 \
+    -o "$out_dir/q03.stage.trace.json" --analyze
+
+python - "$out_dir/q03.stage.trace.json" <<'PY'
+import json
+import sys
+
+events = [e for e in json.load(open(sys.argv[1]))["traceEvents"]
+          if e["ph"] == "X"]
+names = {e["name"] for e in events}
+leaves = {"scan.decode", "scan.to_device", "task.to_host", "spmd.tail",
+          "shard.pad", "shard.put", "spmd.wait", "spmd.fetch"}
+assert leaves <= names, f"stage-path trace lacks {sorted(leaves - names)}"
+ids = {e["args"]["id"] for e in events}
+assert all(e["args"]["parent"] in ids for e in events
+           if e["name"] in leaves), "a leaf span without its parent"
+print(f"stage-path trace: {len(events)} spans, every leaf under a parent")
+PY
 
 JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m pytest -q \
     -p no:cacheprovider \
