@@ -81,6 +81,10 @@ class SessionResult:
     # when auron.adaptive.enable made replanning act on them.
     aqe_decisions: List[dict] = field(default_factory=list)
     exchange_stats: List[dict] = field(default_factory=list)
+    # what the stage program reported of itself (parallel/stage.py::
+    # execute_plan_spmd's `stats`): `join_probes`, the probe each K=1
+    # join took, by operator label
+    stage_stats: Dict[str, object] = field(default_factory=dict)
 
     def to_pylist(self) -> List[dict]:
         return self.table.to_pylist()
@@ -98,7 +102,9 @@ class SessionResult:
             # the stage program's operators under the labels its device
             # time is filed under (python -m auron_tpu.trace device)
             from auron_tpu.parallel.stage import explain_stage
-            stage_plan = explain_stage(self.converted, self.ctx)
+            stage_plan = explain_stage(
+                self.converted, self.ctx,
+                probes=self.stage_stats.get("join_probes"))
         return _ea(self.metrics, query_id=self.query_id,
                    wall_s=self.wall_s, rows=self.table.num_rows,
                    spmd=self.spmd,
@@ -106,6 +112,15 @@ class SessionResult:
                    fallbacks=totals.get("num_fallbacks", 0),
                    aqe=self.aqe_decisions,
                    normalize=normalize, stage_plan=stage_plan)
+
+    def stage_totals(self) -> Dict[str, int]:
+        """The stage program's counters as query totals: `join_probes`
+        (K=1 joins run) and `join_probes_direct` (those that probed by
+        direct address on every device)."""
+        if not self.spmd:
+            return {}
+        from auron_tpu.parallel.stage import probe_counts
+        return probe_counts(self.stage_stats.get("join_probes") or {})
 
     def all_native(self) -> bool:
         """True when no foreign section remains (the
@@ -211,6 +226,9 @@ class AuronSession:
             # concurrent neighbor's retries and spills
             st = scope.stats.snapshot()
             trees = res.metrics if res is not None else []
+            totals = metric_totals(trees)
+            if res is not None:
+                totals.update(res.stage_totals())
             # the minimal lifecycle timeline of a direct execute (the
             # serving schedulers patch/record the full queued ->
             # admitted -> ... machine over this)
@@ -226,7 +244,7 @@ class AuronSession:
                 retries=st.get("retries", 0),
                 fallbacks=st.get("fallbacks", 0),
                 error=error, started_at=wall_start,
-                metric_totals=metric_totals(trees),
+                metric_totals=totals,
                 mem_peak=metric_max(trees, "mem_peak"),
                 mem_spills=st.get("mem_spills", 0),
                 mem_spill_bytes=st.get("mem_spill_bytes", 0),
@@ -289,11 +307,14 @@ class AuronSession:
                 precheck_plan(converted, ctx)
                 sources = {rid: self._source_table(src, ctx)
                            for rid, src in ctx.sources.items()}
+                stage_stats: Dict[str, object] = {}
                 with tracing.span("spmd.execute", cat="spmd"):
                     table = execute_plan_spmd(converted, ctx, mesh,
-                                              sources, axis=mesh_axis)
+                                              sources, axis=mesh_axis,
+                                              stats=stage_stats)
                 res = SessionResult(table=table, converted=converted,
-                                    tags=tags, ctx=ctx, spmd=True)
+                                    tags=tags, ctx=ctx, spmd=True,
+                                    stage_stats=stage_stats)
                 res._foreign_sections = sum(  # type: ignore[attr-defined]
                     1 for s in ctx.sources.values()
                     if s.node.children or

@@ -17,9 +17,17 @@ program over a `jax.sharding.Mesh`:
   every device (NativeBroadcastExchangeBase.collectNative analogue);
 - group aggregation: the same sort-based `_group_reduce_body` kernel the
   serial engine uses, traced inline;
-- broadcast/hash join: sorted-hash build + searchsorted probe, restricted
-  to probe-row-preserving shapes (single-match builds: the dim-table
-  pattern) — multi-match joins fall back to the serial engine.
+- broadcast/hash/sort-merge join: one lookup contract, (build row, found)
+  per probe row, computed one of two ways.  A single integer or date key
+  whose live build keys span less than the build side's capacity probes a
+  direct-address table (`table[key - min]`: one scatter to build, one
+  gather to probe); the program decides that from the keys it sees
+  (`lax.cond`, per device), with no option and no retry.  Everything else
+  — strings, decimals, composite keys, sparse ids — sorts the build
+  side's u64 key hashes and binary-searches them.  Duplicate build keys
+  under a pair-emitting join trip a retryable guard and the driver
+  re-traces with K-way pair expansion (double `searchsorted`); past the
+  factor the plan falls back to the serial engine.
 
 Anything the compiler cannot express raises `SpmdUnsupported`; callers
 (AuronSession.execute with a mesh) fall back to the per-partition serial
@@ -46,7 +54,7 @@ from auron_tpu.exprs.compiler import EvalCtx, device_capable, evaluate
 from auron_tpu.ir import plan as P
 from auron_tpu.ir.expr import Expr
 from auron_tpu.ir.node import Node
-from auron_tpu.ir.schema import DataType, Field, Schema
+from auron_tpu.ir.schema import DataType, Field, Schema, TypeId
 from auron_tpu.ops.sort_keys import stable_argsort
 from auron_tpu.parallel.exchange import (
     all_to_all_repartition, bounded_quota, broadcast_all_gather,
@@ -112,6 +120,31 @@ def _live_first_perm(live: Array) -> Array:
     return stable_argsort(jnp.logical_not(live))
 
 
+def _direct_addressable(pkeys, bkeys) -> bool:
+    """The static half of the direct-address probe's test: one key pair,
+    both device columns of one integer or date type (not decimal, string
+    or float — their device words are not the key's order)."""
+    if len(pkeys) != 1 or len(bkeys) != 1:
+        return False
+    pk, bk = pkeys[0], bkeys[0]
+    return all(isinstance(k, DeviceColumn) and
+               (k.dtype.is_integral or k.dtype.id == TypeId.DATE32)
+               for k in (pk, bk)) and \
+        pk.dtype.id == bk.dtype.id and pk.data.dtype == bk.data.dtype
+
+
+def _key_words(data: Array) -> Array:
+    """Integer key data at a width that has an unsigned twin on the
+    device (int8/int16 widen to int32)."""
+    return data if data.dtype.itemsize >= 4 else data.astype(jnp.int32)
+
+
+def _unsigned(x: Array) -> Array:
+    """The same bits as an unsigned integer of the same width."""
+    return lax.bitcast_convert_type(
+        x, jnp.uint64 if x.dtype.itemsize == 8 else jnp.uint32)
+
+
 def operator_labels(plan, conv_ctx) -> List[Tuple[int, Any, str]]:
     """(depth, node, "<kind>#<i>") for every operator of a stage plan,
     `<i>` its pre-order index, exchange and broadcast boundaries followed
@@ -165,10 +198,13 @@ def _peel_tail(plan, exchanges):
     return tail, shadow_sort, plan
 
 
-def explain_stage(plan, conv_ctx) -> str:
+def explain_stage(plan, conv_ctx,
+                  probes: Optional[Dict[str, str]] = None) -> str:
     """The stage path's EXPLAIN text: the driver-side tail, then every
     operator of the stage program under the label its device time is
-    filed under."""
+    filed under.  `probes` (execute_plan_spmd's `stats["join_probes"]`)
+    marks each K=1 join with the probe it took: `direct` or `search`."""
+    probes = probes or {}
     exchanges = getattr(conv_ctx, "exchanges", None) or {}
     broadcasts = getattr(conv_ctx, "broadcasts", None) or {}
     tail, _shadow, body = _peel_tail(plan, exchanges)
@@ -180,6 +216,8 @@ def explain_stage(plan, conv_ctx) -> str:
         elif isinstance(node, (P.BroadcastJoin, P.HashJoin,
                                P.SortMergeJoin)):
             detail = f" type={node.join_type}"
+            if label in probes:
+                detail += f" probe={probes[label]}"
         elif isinstance(node, P.IpcReader):
             if node.resource_id in exchanges:
                 detail = " exchange:" + \
@@ -237,6 +275,10 @@ class _StageTracer:
         # compaction disabled — an INDEPENDENT retry dimension so a
         # genuinely fanning-out join doesn't also lose the agg shrink
         self.join_guards: List[Any] = []
+        # one entry per K=1 join, in trace order: (operator label, the
+        # number of devices that took the direct-address probe — a device
+        # scalar — or None where the join traced the search alone)
+        self.probes: List[Tuple[str, Any]] = []
         # join pair-expansion factor (1 = single-candidate probe)
         self.match_factor = max(1, int(match_factor))
         # post-agg static capacity (rows/device); 0 keeps input capacity
@@ -604,7 +646,8 @@ class _StageTracer:
         # those types are precheck-rejected for broadcast joins
         return self._join(n.left, n.right, n.on, n.join_type,
                           build_side=n.broadcast_side,
-                          existence_name=n.existence_output_name)
+                          existence_name=n.existence_output_name,
+                          label=self.labels.get(id(n), n.kind))
 
     def _do_hash_join(self, n: P.HashJoin) -> DeviceTable:
         # colocation vetted by precheck_plan: a shuffled hash join is
@@ -613,16 +656,17 @@ class _StageTracer:
         return self._join(n.left, n.right, n.on, n.join_type,
                           build_side=n.build_side,
                           existence_name=n.existence_output_name,
-                          colocated=True)
+                          colocated=True,
+                          label=self.labels.get(id(n), n.kind))
 
     def _do_broadcast_join_build_hash_map(self, n) -> DeviceTable:
         return self.eval_node(n.child)
 
     def _do_sort_merge_join(self, n: P.SortMergeJoin) -> DeviceTable:
         # SMJ in SPMD: both sides arrive hash-exchanged on their join
-        # keys, so equal keys are COLOCATED and the per-device
-        # sorted-hash probe kernel applies (the mid-plan sorts under an
-        # SMJ are no-ops here — the kernel sorts hashes itself).
+        # keys, so equal keys are COLOCATED and the per-device probe
+        # applies (the mid-plan sorts under an SMJ are no-ops here —
+        # neither probe needs its input ordered).
         # Duplicate build keys retry with K-way pair expansion; key runs
         # wider than the factor fall back to the streaming serial SMJ.
         # colocation was vetted by precheck_plan (the one authoritative
@@ -630,18 +674,15 @@ class _StageTracer:
         return self._join(n.left, n.right, n.on, n.join_type,
                           build_side="right",
                           existence_name=n.existence_output_name,
-                          colocated=True)
+                          colocated=True,
+                          label=self.labels.get(id(n), n.kind))
 
     _JOIN_TYPES = ("inner", "left", "left_semi", "left_anti", "existence")
     _JOIN_TYPES_COLOCATED = _JOIN_TYPES + ("full", "right")
 
     def _join(self, left_ir, right_ir, on, join_type: str,
               build_side: str, existence_name: str = "exists",
-              colocated: bool = False) -> DeviceTable:
-        from auron_tpu.ops.joins.exec import join_output_schema
-        from auron_tpu.ops.joins.kernel import (
-            _NULL_BUILD, _NULL_PROBE, join_key_hash,
-        )
+              colocated: bool = False, label: str = "") -> DeviceTable:
         allowed = self._JOIN_TYPES_COLOCATED if colocated \
             else self._JOIN_TYPES
         if join_type not in allowed:
@@ -650,36 +691,61 @@ class _StageTracer:
             raise SpmdUnsupported("SPMD join requires build_side=right")
         probe = self.eval_node(left_ir)
         build = self.eval_node(right_ir)
-        # scopes only: the operations are traced in the order they always
-        # were, so the program's HLO is the parent's but for its metadata
         with jax.named_scope("probe"):
             pkeys = self._eval_exprs(on.left_keys, probe)
         with jax.named_scope("build"):
             bkeys = self._eval_exprs(on.right_keys, build)
-            bh, bvalid = join_key_hash(bkeys, build.capacity)
-            bh = jnp.where(jnp.logical_and(build.live, bvalid), bh,
-                           _NULL_BUILD)
-            from auron_tpu.ops.strategy import sort_strategy
-            if sort_strategy(build.capacity) == "radix":
-                from auron_tpu.ops.radix_sort import stable_argsort_u64
-                order = stable_argsort_u64(bh)
-            else:
-                order = stable_argsort(bh)
-            sorted_bh = jnp.take(bh, order)
+        semi_like = join_type in ("left_semi", "left_anti", "existence")
+        K = 1 if semi_like else self.match_factor
+        if K <= 1 and _direct_addressable(pkeys, bkeys):
+            # one integer key: the program looks at the build keys and
+            # takes the direct-address probe where their range fits
+            bidx, ok = self._lookup_adaptive(probe, build, pkeys[0],
+                                             bkeys[0], semi_like, label)
+            with jax.named_scope("probe"):
+                return self._join_emit(probe, build, bidx, ok, join_type,
+                                       existence_name)
+        # everything else traces the sorted-hash search, operation for
+        # operation as before there was a choice
+        with jax.named_scope("build"):
+            order, sorted_bh = self._sorted_build_hashes(build, bkeys)
         with jax.named_scope("probe"):
-            ph, pvalid = join_key_hash(pkeys, probe.capacity)
-            ph = jnp.where(jnp.logical_and(probe.live, pvalid), ph,
-                           _NULL_PROBE)
-            semi_like = join_type in ("left_semi", "left_anti",
-                                      "existence")
-            K = 1 if semi_like else self.match_factor
-            if K <= 1:
-                return self._join_single(probe, build, pkeys, bkeys,
-                                         order, sorted_bh, ph, join_type,
-                                         existence_name)
-            return self._join_expanded(probe, build, pkeys, bkeys, order,
-                                       sorted_bh, ph, join_type,
-                                       existence_name, K)
+            ph = self._probe_hashes(probe, pkeys)
+            if K > 1:
+                return self._join_expanded(probe, build, pkeys, bkeys,
+                                           order, sorted_bh, ph, join_type,
+                                           existence_name, K)
+            self.probes.append((label, None))
+            self._trip_guard(
+                self._search_trip(bkeys, order, sorted_bh, semi_like),
+                semi_like)
+            bidx, ok = self._search_probe(build, pkeys, bkeys, order,
+                                          sorted_bh, ph)
+            return self._join_emit(probe, build, bidx, ok, join_type,
+                                   existence_name)
+
+    @staticmethod
+    def _sorted_build_hashes(build, bkeys):
+        """(order, sorted_bh): the build side as a sorted u64 hash array,
+        dead and null-key rows under a sentinel at its end."""
+        from auron_tpu.ops.joins.kernel import _NULL_BUILD, join_key_hash
+        from auron_tpu.ops.strategy import sort_strategy
+        bh, bvalid = join_key_hash(bkeys, build.capacity)
+        bh = jnp.where(jnp.logical_and(build.live, bvalid), bh,
+                       _NULL_BUILD)
+        if sort_strategy(build.capacity) == "radix":
+            from auron_tpu.ops.radix_sort import stable_argsort_u64
+            order = stable_argsort_u64(bh)
+        else:
+            order = stable_argsort(bh)
+        return order, jnp.take(bh, order)
+
+    @staticmethod
+    def _probe_hashes(probe, pkeys):
+        from auron_tpu.ops.joins.kernel import _NULL_PROBE, join_key_hash
+        ph, pvalid = join_key_hash(pkeys, probe.capacity)
+        return jnp.where(jnp.logical_and(probe.live, pvalid), ph,
+                         _NULL_PROBE)
 
     @staticmethod
     def _cols_eq(a_cols, b_cols, ok):
@@ -718,41 +784,121 @@ class _StageTracer:
         t2 = DeviceTable(schema, null_probe + list(build.cols), live2)
         return self._concat_tables(schema, [t1, t2])
 
-    def _join_single(self, probe, build, pkeys, bkeys, order, sorted_bh,
-                     ph, join_type, existence_name):
-        """Single-candidate probe (match_factor=1): duplicate build keys
-        would need pair expansion, so a runtime guard detects them
-        (adjacent equal non-sentinel hashes after the sort).  For
-        pair-emitting join types the trip is RETRYABLE (the driver
-        re-traces with the expansion factor).  Semi/anti/existence are
-        probe-preserving, so TRUE duplicate keys are harmless — the
-        leftmost candidate of an equal-hash run carries the same key —
-        and only a hash COLLISION (adjacent equal hashes whose exact
-        keys differ) trips their (hard) guard.  This is what lets the
-        TPC-DS semi/anti families (customer EXISTS over fact tables:
-        massively duplicate build keys) ride the mesh at K=1."""
-        from auron_tpu.ops.joins.exec import join_output_schema
+    # -- the K=1 lookup: one contract, (bidx, ok), two ways to compute it --
+    #
+    # Single-candidate probe (match_factor=1): every probe row gets at
+    # most one build row.  Duplicate build keys would need pair expansion,
+    # so a runtime guard detects them.  For pair-emitting join types the
+    # trip is RETRYABLE (the driver re-traces with the expansion factor).
+    # Semi/anti/existence are probe-preserving, so TRUE duplicate keys are
+    # harmless — any candidate of an equal-key run carries the same key.
+    # This is what lets the TPC-DS semi/anti families (customer EXISTS
+    # over fact tables: massively duplicate build keys) ride the mesh at
+    # K=1.
+
+    def _trip_guard(self, trip, semi_like: bool) -> None:
+        """File one join's local trip: hard under a semi-like join (a
+        hash collision), retryable under a pair-emitting one (a
+        duplicate build key)."""
+        tripped = lax.psum(trip.astype(jnp.int32), self.axis) > 0
+        (self.guards if semi_like else self.retry_guards).append(tripped)
+
+    def _search_trip(self, bkeys, order, sorted_bh, semi_like: bool):
+        """The sorted-hash side's trip, from adjacent equal non-sentinel
+        hashes after the sort: any such pair under a pair-emitting join;
+        under a semi-like join only a hash COLLISION (equal hashes whose
+        exact keys differ), since the leftmost candidate of an equal-hash
+        run must carry the probed key."""
         from auron_tpu.ops.joins.kernel import _NULL_BUILD
         adj = jnp.logical_and(sorted_bh[1:] == sorted_bh[:-1],
                               sorted_bh[1:] != _NULL_BUILD)
-        if join_type in ("left_semi", "left_anti", "existence"):
-            keys_eq = self._cols_eq(
-                [bk.gather(order[:-1], adj) for bk in bkeys],
-                [bk.gather(order[1:], adj) for bk in bkeys],
-                jnp.ones(adj.shape, bool))
-            collision = jnp.any(jnp.logical_and(
-                adj, jnp.logical_not(keys_eq)))
-            self.guards.append(
-                lax.psum(collision.astype(jnp.int32), self.axis) > 0)
-        else:
-            dup = jnp.any(adj)
-            self.retry_guards.append(
-                lax.psum(dup.astype(jnp.int32), self.axis) > 0)
+        if not semi_like:
+            return jnp.any(adj)
+        keys_eq = self._cols_eq(
+            [bk.gather(order[:-1], adj) for bk in bkeys],
+            [bk.gather(order[1:], adj) for bk in bkeys],
+            jnp.ones(adj.shape, bool))
+        return jnp.any(jnp.logical_and(adj, jnp.logical_not(keys_eq)))
+
+    def _search_probe(self, build, pkeys, bkeys, order, sorted_bh, ph):
+        """Binary search of the probe hashes in the sorted build hashes:
+        log2(slots) + 1 dependent gathers of the probe's capacity, then
+        the exact-key filter for hash collisions."""
         pos = jnp.clip(jnp.searchsorted(sorted_bh, ph), 0,
                        build.capacity - 1)
         hit = jnp.take(sorted_bh, pos) == ph
         bidx = jnp.take(order, pos)
-        ok = self._exact_eq(pkeys, bkeys, bidx, hit)
+        return bidx, self._exact_eq(pkeys, bkeys, bidx, hit)
+
+    def _lookup_adaptive(self, probe, build, pk, bk, semi_like: bool,
+                         label: str):
+        """(bidx, ok) for one integer key pair, the way chosen INSIDE the
+        program from the build keys it is looking at: where the live,
+        non-null keys span less than the build side's own capacity
+        (surrogate keys of a dimension table do), the row's position in a
+        table indexed by `key - min` IS the lookup — one gather of the
+        probe's capacity, exact, no hash, no sort, no collision filter
+        (Spark's LongHashedRelation "dense mode").  Otherwise the
+        sorted-hash search.  `lax.cond`: only the chosen side executes,
+        each device decides for its own build shard, and no collective
+        sits inside a branch."""
+        cap = build.capacity
+        pdata, bdata = _key_words(pk.data), _key_words(bk.data)
+        with jax.named_scope("build"):
+            bvalid = jnp.logical_and(build.live, bk.validity)
+            info = jnp.iinfo(bdata.dtype)
+            kmin = jnp.min(jnp.where(bvalid, bdata, info.max))
+            kmax = jnp.max(jnp.where(bvalid, bdata, info.min))
+            # an unsigned wrap-around difference is exact for any pair of
+            # signed keys: no int64 extreme overflows it
+            dense = jnp.logical_and(
+                jnp.any(bvalid),
+                _unsigned(kmax) - _unsigned(kmin) < cap)
+
+        def direct():
+            with jax.named_scope("build"):
+                # dead and null-key rows scatter out of range and drop
+                slot = jnp.where(bvalid, _unsigned(bdata) - _unsigned(kmin),
+                                 cap).astype(jnp.int32)
+                table = jnp.full(cap, -1, jnp.int32).at[slot].set(
+                    jnp.arange(cap, dtype=jnp.int32), mode="drop")
+                # a duplicate key lost its slot to another row
+                trip = jnp.bool_(False) if semi_like else \
+                    jnp.sum((table >= 0).astype(jnp.int32)) != \
+                    jnp.sum(bvalid.astype(jnp.int32))
+            with jax.named_scope("probe"):
+                off = _unsigned(pdata) - _unsigned(kmin)
+                in_range = off < cap
+                bidx = table.at[
+                    jnp.where(in_range, off, 0).astype(jnp.int32)
+                ].get(mode="promise_in_bounds")
+                ok = jnp.logical_and(
+                    jnp.logical_and(probe.live, pk.validity),
+                    jnp.logical_and(in_range, bidx >= 0))
+                return jnp.maximum(bidx, 0), ok, trip
+
+        def search():
+            with jax.named_scope("build"):
+                order, sorted_bh = self._sorted_build_hashes(build, [bk])
+            with jax.named_scope("probe"):
+                ph = self._probe_hashes(probe, [pk])
+                trip = self._search_trip([bk], order, sorted_bh, semi_like)
+                bidx, ok = self._search_probe(build, [pk], [bk], order,
+                                              sorted_bh, ph)
+                return bidx, ok, trip
+
+        bidx, ok, trip = lax.cond(dense, direct, search)
+        self._trip_guard(trip, semi_like)
+        # how many devices took the direct side, for the driver's counter
+        self.probes.append(
+            (label, lax.psum(dense.astype(jnp.int32), self.axis)))
+        return bidx, ok
+
+    def _join_emit(self, probe, build, bidx, ok, join_type,
+                   existence_name):
+        """The join's output from the lookup: `ok` marks the probe rows
+        that found their key, `bidx` the build row each found."""
+        from auron_tpu.ops.joins.exec import join_output_schema
         schema = join_output_schema(probe.schema, build.schema, join_type,
                                     existence_name)
         if join_type in ("left_semi", "left_anti"):
@@ -1335,12 +1481,16 @@ def clear_source_caches() -> None:
 
 
 def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
-                      source_tables: Dict[str, Any], axis: str = "parts"):
+                      source_tables: Dict[str, Any], axis: str = "parts",
+                      stats: Optional[Dict[str, Any]] = None):
     """Compile + run `plan` as one shard_map program over `mesh`.
 
     source_tables: rid -> pyarrow.Table for every FFI source the plan
     references (the C2N boundary inputs).  Returns a pyarrow.Table.
     Raises SpmdUnsupported when the plan shape cannot be expressed.
+    `stats`, when given, receives what the program reported of itself:
+    `join_probes`, {operator label: "direct" | "search" | "direct k/n"}
+    for every K=1 join of the attempt that gave the result.
 
     A tripped join guard (duplicate build keys past the current match
     factor) retries ONCE with auron.spmd.join.match.factor pair
@@ -1395,7 +1545,8 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                           source_tables, axis,
                                           match_factor=match,
                                           agg_cap_hint=cap_eff,
-                                          join_compact=join_compact)
+                                          join_compact=join_compact,
+                                          stats=stats)
             if match > 1:
                 _MATCH_FACTOR_HINT[hint_key] = match
             if cap_eff != cap_hint:
@@ -1577,11 +1728,34 @@ def _note_gather(counts_np, live_np, cols_np) -> Dict[str, int]:
     return GATHER_STATS
 
 
+def _probe_marks(probe_box, direct_np, n_dev: int) -> Dict[str, str]:
+    """{operator label: "direct" | "search" | "direct k/n"} for the K=1
+    joins of one run: `direct_np` holds, per join traced with a choice,
+    how many of the `n_dev` devices took the direct-address probe."""
+    counts = iter(np.asarray(direct_np).tolist()
+                  if direct_np is not None else ())
+    marks = {}
+    for label, chosen in probe_box:
+        k = next(counts) if chosen else 0
+        marks[label] = "direct" if k == n_dev else \
+            "search" if k == 0 else f"direct {k}/{n_dev}"
+    return marks
+
+
+def probe_counts(probes: Dict[str, str]) -> Dict[str, int]:
+    """The counter's two numbers: K=1 joins run, and those of them in
+    which every device probed by direct address."""
+    return {"join_probes": len(probes),
+            "join_probes_direct": sum(m == "direct"
+                                      for m in probes.values())}
+
+
 def _execute_plan_spmd_once(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                             source_tables: Dict[str, Any], axis,
                             match_factor: int,
                             agg_cap_hint: Optional[int] = None,
-                            join_compact: bool = True):
+                            join_compact: bool = True,
+                            stats: Optional[Dict[str, Any]] = None):
     # one `spmd.launch` span per stage attempt, with the host-visible
     # internal phases (`spmd.ingest` scan IO, `spmd.shard` pad+transfer,
     # `spmd.compile`/`spmd.run` program execution, `spmd.gather` result
@@ -1596,14 +1770,16 @@ def _execute_plan_spmd_once(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         with jitcheck.transfer_guard("spmd.execute"):
             return _execute_plan_spmd_once_impl(
                 plan, conv_ctx, mesh, source_tables, axis, match_factor,
-                agg_cap_hint=agg_cap_hint, join_compact=join_compact)
+                agg_cap_hint=agg_cap_hint, join_compact=join_compact,
+                stats=stats)
 
 
 def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                  source_tables: Dict[str, Any], axis,
                                  match_factor: int,
                                  agg_cap_hint: Optional[int] = None,
-                                 join_compact: bool = True):
+                                 join_compact: bool = True,
+                                 stats: Optional[Dict[str, Any]] = None):
     import dataclasses
 
     import pyarrow as pa
@@ -1729,6 +1905,9 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
 
     if cached is None:
         schema_box: List[Schema] = []
+        # (operator label, traced with a choice) per K=1 join, filled at
+        # trace time like the schema
+        probe_box: List[Tuple[str, bool]] = []
         labels = {id(node): label
                   for _depth, node, label in operator_labels(plan, conv_ctx)}
 
@@ -1747,6 +1926,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             out = tracer.eval_node(plan)
             if not schema_box:
                 schema_box.append(out.schema)
+                probe_box.extend((label, flag is not None)
+                                 for label, flag in tracer.probes)
             with jax.named_scope("epilogue"):
                 guards = jnp.stack(tracer.guards) if tracer.guards else \
                     jnp.zeros(0, bool)
@@ -1756,6 +1937,12 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     if tracer.shrink_guards else jnp.zeros(0, bool)
                 join_guards = jnp.stack(tracer.join_guards) \
                     if tracer.join_guards else jnp.zeros(0, bool)
+                # devices on the direct side, per join traced with a
+                # choice; a program without one has no such output and
+                # is the program it always was
+                direct = [flag for _label, flag in tracer.probes
+                          if flag is not None]
+                probe_direct = jnp.stack(direct) if direct else None
                 cols, live = out.cols, out.live
                 count = jnp.sum(live.astype(jnp.int32))[None]
                 if compact_gather:
@@ -1768,16 +1955,16 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     cols = [c.gather(perm, ok) for c in cols]
                     live = ok
             return (cols, live, count, guards, retry_guards,
-                    shrink_guards, join_guards)
+                    shrink_guards, join_guards, probe_direct)
 
         shard = jitcheck.site("spmd.stage").jit(jax.shard_map(
             program, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: PS(axis), host_inputs),),
             out_specs=(PS(axis), PS(axis), PS(axis), PS(), PS(), PS(),
-                       PS()),
+                       PS(), PS()),
             check_vma=False))
     else:
-        shard, schema_box = cached
+        shard, schema_box, probe_box = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
     # compiles the whole stage program, so the span is the compile span
@@ -1788,9 +1975,9 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             cat="spmd", devices=n_dev,
             first_launch_included=cached is None):
         (out_cols, out_live, counts, guards, retry_guards, shrink_guards,
-         join_guards) = shard(host_inputs)
+         join_guards, probe_direct) = shard(host_inputs)
     if cached is None:
-        _PROGRAM_CACHE[cache_key] = (shard, schema_box)
+        _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box)
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
@@ -1803,11 +1990,13 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             # output fetch at all, and a clean run fetches only the
             # compacted slice below.  `spmd.wait` is the host's wait for
             # the stage program (`spmd.run` was its enqueue).
-            with tracing.span("spmd.wait", cat="spmd"):
-                (counts_np, guards_np, retry_np, shrink_np,
-                 join_np) = host_sync(
+            with tracing.span("spmd.wait", cat="spmd") as sp:
+                (counts_np, guards_np, retry_np, shrink_np, join_np,
+                 direct_np) = host_sync(
                     (counts, guards, retry_guards, shrink_guards,
-                     join_guards))
+                     join_guards, probe_direct))
+                probes = _probe_marks(probe_box, direct_np, n_dev)
+                sp.set_args(**probe_counts(probes))
         else:
             # single batched fetch (CPU: transfers are memcpy-cheap, two
             # round trips would only add dispatch latency): the wait for
@@ -1815,11 +2004,13 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             # this path records no `spmd.fetch`
             with tracing.span("spmd.wait", cat="spmd") as sp:
                 (out_live_np, out_cols_np, counts_np, guards_np, retry_np,
-                 shrink_np, join_np) = host_sync(
+                 shrink_np, join_np, direct_np) = host_sync(
                     (out_live, out_cols, counts, guards, retry_guards,
-                     shrink_guards, join_guards))
+                     shrink_guards, join_guards, probe_direct))
+                probes = _probe_marks(probe_box, direct_np, n_dev)
                 sp.set_args(**_note_gather(counts_np, out_live_np,
-                                           out_cols_np))
+                                           out_cols_np),
+                            **probe_counts(probes))
         if np.any(np.asarray(guards_np)):
             raise SpmdGuardTripped(
                 "runtime guard tripped (exchange quota overflow, or "
@@ -1837,6 +2028,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             raise SpmdGuardTripped(
                 "duplicate-key build side at match factor 1: result "
                 "discarded", retryable=True)
+        if stats is not None:
+            stats["join_probes"] = probes
         if compact_gather:
             # phase 2: slice each shard to the smallest capacity bucket
             # that holds its rows (one tiny cached program), then fetch

@@ -147,6 +147,9 @@ _OP_LABEL = re.compile(r"^[a-z_]+#[0-9]+$")
 # label in a scope path is a jit or primitive name
 _SUB_SCOPES = frozenset({"build", "probe", "exchange", "broadcast",
                          "group", "reduce", "compact"})
+# what jax puts between a label and a sub-scope opened inside a branch of
+# a `lax.cond` (the join's choice of probe)
+_COND_PARTS = re.compile(r"^(cond|branch_[0-9]+_fun)$")
 
 
 def label_of(scope_path: str) -> Tuple[str, str]:
@@ -154,7 +157,7 @@ def label_of(scope_path: str) -> Tuple[str, str]:
     `jit(program)/agg#0/broadcast_join#2/probe/jit(_take)/gather`: the
     innermost operator label and the sub-scope right under it; the
     stage program's `epilogue`; else `unlabelled`."""
-    parts = scope_path.split("/")
+    parts = [p for p in scope_path.split("/") if not _COND_PARTS.match(p)]
     for i in range(len(parts) - 1, -1, -1):
         if _OP_LABEL.match(parts[i]):
             sub = parts[i + 1] if i + 1 < len(parts) and \

@@ -1145,3 +1145,246 @@ def test_spmd_exchange_quota_skew_sweep():
     # anything (hot-key shapes overflow, long tails fit)
     assert swept_both["overflow"] >= 1 and swept_both["fits"] >= 2, \
         swept_both
+
+
+# ---------------------------------------------------------------------------
+# the K=1 join lookup: a direct-address probe where one integer key's range
+# fits the build side, chosen inside the program; the sorted-hash search
+# everywhere else.  Every case: the serial engine's rows AND the counter.
+# ---------------------------------------------------------------------------
+
+I64_MIN, I64_MAX = np.iinfo(np.int64).min, np.iinfo(np.int64).max
+
+
+def _probe_tables(case):
+    """(fact, dim, left keys, right keys) of one case: `fk` probes `dk`."""
+    rng = np.random.default_rng(27)
+    n = 1200
+
+    def fact_of(keys, mask=None):
+        return pa.table({"fk": pa.array(keys, mask=mask),
+                         "amount": rng.normal(10, 5, len(keys))})
+
+    def dim_of(keys, mask=None):
+        return pa.table({"dk": pa.array(keys, mask=mask),
+                         "w": np.arange(len(keys), dtype=np.float64)})
+
+    on = (("fk",), ("dk",))
+    if case in ("int64", "filtered-build", "dead-probe-rows"):
+        return (fact_of(rng.integers(0, 120, n).astype(np.int64)),
+                dim_of(np.arange(100, dtype=np.int64)), *on)
+    if case in ("int32", "int16"):
+        t = np.dtype(case)
+        return (fact_of(rng.integers(0, 120, n).astype(t)),
+                dim_of(np.arange(100).astype(t)), *on)
+    if case == "date":
+        days = pa.array(rng.integers(10957, 11100, n).astype(np.int32),
+                        type=pa.int32()).cast(pa.date32())
+        dim_days = pa.array(np.arange(10957, 11057, dtype=np.int32),
+                            type=pa.int32()).cast(pa.date32())
+        return (pa.table({"fk": days, "amount": rng.normal(10, 5, n)}),
+                pa.table({"dk": dim_days,
+                          "w": np.arange(100, dtype=np.float64)}), *on)
+    if case == "negative":
+        return (fact_of(rng.integers(-80, 30, n).astype(np.int64)),
+                dim_of(np.arange(-60, 14, dtype=np.int64)), *on)
+    if case in ("int64-top", "int64-bottom", "int64-both-ends"):
+        ends = np.array([I64_MIN, I64_MIN + 1, I64_MIN + 5, -1, 0, 1,
+                         I64_MAX - 5, I64_MAX - 1, I64_MAX], dtype=np.int64)
+        dim_keys = {"int64-top": np.arange(I64_MAX - 7, I64_MAX,
+                                           dtype=np.int64),
+                    "int64-bottom": np.arange(I64_MIN, I64_MIN + 7,
+                                              dtype=np.int64),
+                    "int64-both-ends": np.array([I64_MIN, I64_MAX],
+                                                dtype=np.int64)}[case]
+        return (fact_of(np.concatenate([rng.choice(ends, n), dim_keys])),
+                dim_of(dim_keys), *on)
+    if case == "null-keys":
+        fk = rng.integers(0, 120, n).astype(np.int64)
+        dk = np.arange(100, dtype=np.int64)
+        return (fact_of(fk, mask=rng.random(n) < 0.1),
+                dim_of(dk, mask=(dk % 7 == 3)), *on)
+    if case == "empty-build":
+        return (fact_of(rng.integers(0, 120, n).astype(np.int64)),
+                dim_of(np.arange(0, dtype=np.int64)), *on)
+    if case == "sparse":
+        return (fact_of(rng.integers(0, 120, n).astype(np.int64) * 1000),
+                dim_of(np.arange(100, dtype=np.int64) * 1000), *on)
+    if case == "string":
+        return (fact_of([f"k{i}" for i in rng.integers(0, 120, n)]),
+                dim_of([f"k{i}" for i in range(100)]), *on)
+    if case == "two-keys":
+        fk = rng.integers(0, 120, n).astype(np.int64)
+        dk = np.arange(100, dtype=np.int64)
+        return (pa.table({"fk": fk, "fk2": fk % 5,
+                          "amount": rng.normal(10, 5, n)}),
+                pa.table({"dk": dk, "dk2": dk % 5,
+                          "w": dk.astype(np.float64)}),
+                ("fk", "fk2"), ("dk", "dk2"))
+    if case == "duplicates":
+        return (fact_of(rng.integers(0, 60, n).astype(np.int64)),
+                dim_of(np.repeat(np.arange(40, dtype=np.int64), 2)), *on)
+    raise AssertionError(case)
+
+
+def _probe_join(case, join_type, fact, dim, left_keys, right_keys,
+                colocated=False):
+    """(stage plan, ctx, serial plan): a broadcast join, or a hash join
+    of two sides hash-exchanged on the join keys."""
+    ctx = _Ctx()
+    fsrc = P.FFIReader(schema=from_arrow_schema(fact.schema),
+                       resource_id="fact")
+    dsrc = P.FFIReader(schema=from_arrow_schema(dim.schema),
+                       resource_id="dim")
+    probe_side, build_side = fsrc, dsrc
+    if case == "filtered-build":
+        build_side = P.Filter(child=dsrc, predicates=(E.BinaryExpr(
+            left=col("w"), op="<", right=lit(77.0)),
+            E.BinaryExpr(left=col("w"), op=">", right=lit(5.0)),))
+    if case == "dead-probe-rows":
+        probe_side = P.Filter(child=fsrc, predicates=(E.BinaryExpr(
+            left=col("amount"), op=">", right=lit(9.0)),))
+    on = JoinOn(left_keys=tuple(col(k) for k in left_keys),
+                right_keys=tuple(col(k) for k in right_keys))
+    if colocated:
+        for rid, child, keys in (("exl", probe_side, left_keys),
+                                 ("exr", build_side, right_keys)):
+            ctx.exchanges[rid] = ShuffleJob(
+                rid=rid, child=child, schema=None,
+                partitioning=P.Partitioning(
+                    mode="hash", num_partitions=8,
+                    expressions=tuple(col(k) for k in keys)))
+        stage = P.HashJoin(
+            left=P.IpcReader(schema=None, resource_id="exl"),
+            right=P.IpcReader(schema=None, resource_id="exr"),
+            on=on, join_type=join_type, build_side="right")
+        serial = P.HashJoin(left=probe_side, right=build_side, on=on,
+                            join_type=join_type, build_side="right")
+        return stage, ctx, serial
+    ctx.broadcasts["bc"] = BroadcastJob(rid="bc", child=build_side,
+                                        schema=None)
+    stage = P.BroadcastJoin(
+        left=probe_side, right=P.IpcReader(schema=None, resource_id="bc"),
+        on=on, join_type=join_type, broadcast_side="right")
+    serial = P.BroadcastJoin(left=probe_side, right=build_side, on=on,
+                             join_type=join_type, broadcast_side="right")
+    return stage, ctx, serial
+
+
+def _run_probe_case(case, join_type, want, retries=0, colocated=False,
+                    tables=None):
+    from auron_tpu.runtime import retry
+    fact, dim, lk, rk = tables or _probe_tables(case)
+    stage, ctx, serial = _probe_join(case, join_type, fact, dim, lk, rk,
+                                     colocated)
+    srcs = {"fact": fact, "dim": dim}
+    stats = {}
+    before = retry.stats_snapshot()["retries"]
+    got = execute_plan_spmd(stage, ctx, data_mesh(8), srcs, stats=stats)
+    assert retry.stats_snapshot()["retries"] - before == retries
+    assert _canon(got.to_pylist()) == _canon(_serial_reference(serial, srcs))
+    # one K=1 join, and the probe it took; none once pair expansion runs
+    assert list(stats["join_probes"].values()) == ([want] if want else [])
+    return got
+
+
+@pytest.mark.parametrize("case,join_type,want", [
+    ("int64", "inner", "direct"),
+    ("int32", "left", "direct"),
+    ("int16", "inner", "direct"),
+    ("date", "inner", "direct"),
+    ("filtered-build", "inner", "direct"),
+    ("negative", "left", "direct"),
+    ("int64-top", "inner", "direct"),
+    ("int64-bottom", "left", "direct"),
+    ("int64-both-ends", "inner", "search"),   # a range of 2**64 - 1
+    ("null-keys", "left", "direct"),
+    ("null-keys", "left_anti", "direct"),
+    ("dead-probe-rows", "inner", "direct"),
+    ("empty-build", "left", "search"),        # no live key to address by
+    ("sparse", "inner", "search"),            # range 99,001 >= capacity
+    ("string", "inner", "search"),
+    ("two-keys", "inner", "search"),
+    ("duplicates", "left_semi", "direct"),
+    ("duplicates", "left_anti", "direct"),
+    ("duplicates", "existence", "direct"),
+])
+def test_join_probe_is_chosen_from_the_build_keys(case, join_type, want):
+    got = _run_probe_case(case, join_type, want)
+    assert got.num_rows > 0
+
+
+@pytest.mark.parametrize("join_type", ["inner", "left"])
+def test_direct_probe_keeps_the_duplicate_key_retry(join_type):
+    """A duplicate build key on the direct side raises the same retryable
+    guard as on the search side: one retry, then K-way pair expansion
+    (which has no K=1 probe to count)."""
+    from auron_tpu.parallel import stage as S
+    S._MATCH_FACTOR_HINT.clear()
+    _run_probe_case("duplicates", join_type, None, retries=1)
+    assert list(S._MATCH_FACTOR_HINT.values()) == [4]
+
+
+@pytest.mark.parametrize("join_type", ["left", "full", "right"])
+def test_direct_probe_under_a_colocated_hash_join(join_type):
+    """Each device probes its own build shard: 100 dense keys spread by
+    hash over 8 devices still span less than a shard's capacity."""
+    _run_probe_case("int64", join_type, "direct", colocated=True)
+
+
+def test_devices_of_a_mesh_choose_for_their_own_shard():
+    """One far key makes one device's build shard sparse: that device
+    searches, the seven others address directly, the answer is one."""
+    from auron_tpu.columnar.batch import DeviceColumn
+    from auron_tpu.exprs import hashing as H
+    import jax.numpy as jnp
+    far = 1 << 40
+    keys = np.concatenate([np.arange(100, dtype=np.int64),
+                           np.arange(far, far + 64, dtype=np.int64)])
+    pid = np.asarray(H.pmod(H.hash_columns(
+        [DeviceColumn(I64, jnp.asarray(keys), jnp.ones(len(keys), bool))],
+        seed=42), 8))
+    lonely = next(k for k, p in zip(keys[100:], pid[100:]) if p == 3)
+    dk = np.append(np.arange(100, dtype=np.int64), lonely)
+    assert set(pid[:100]) == set(range(8))    # every shard holds dense keys
+    rng = np.random.default_rng(5)
+    fact = pa.table({"fk": rng.choice(np.append(dk, [100, 101, far]), 1200),
+                     "amount": rng.normal(10, 5, 1200)})
+    dim = pa.table({"dk": dk, "w": np.arange(len(dk), dtype=np.float64)})
+    _run_probe_case("mixed", "full", "direct 7/8", colocated=True,
+                    tables=(fact, dim, ("fk",), ("dk",)))
+
+
+def _lowered_join_text(case, join_type="inner"):
+    """The lowered stage program of one case's broadcast join."""
+    from stage_spy import spied_program
+    fact, dim, lk, rk = _probe_tables(case)
+    stage, ctx, _serial = _probe_join(case, join_type, fact, dim, lk, rk)
+    program, inputs = spied_program(stage, ctx, data_mesh(8),
+                                    {"fact": fact, "dim": dim})
+    return program.lower(inputs).as_text()
+
+
+# sha256 of the same join's lowered text at commit e340063, the parent of
+# the PR that brought the choice (its `execute_plan_spmd`, this jax).  A
+# change that is meant to move these programs takes new digests from the
+# tree before it, the way these were taken.
+_SEARCH_ONLY_PROGRAM = {
+    "string":
+        "e8dc0694560254a03149d23163528d4d9fad39cecfc4302d5a12fa08005f5751",
+    "two-keys":
+        "f4882f76aec2277db5882ac97e54afc670ce81ac077870b396f515496a51dcad",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SEARCH_ONLY_PROGRAM))
+def test_a_join_that_does_not_qualify_traces_no_choice(case):
+    """A string key, a composite key: no conditional, no flag output —
+    the program such a join lowered to before there was a choice, byte
+    for byte."""
+    import hashlib
+    text = _lowered_join_text(case)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        _SEARCH_ONLY_PROGRAM[case]
+    assert "stablehlo.case" in _lowered_join_text("int64")

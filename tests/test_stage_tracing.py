@@ -24,6 +24,7 @@ from auron_tpu.ir.schema import DataType, Field, Schema
 from auron_tpu.parallel import stage as S
 from auron_tpu.parallel.mesh import data_mesh
 from auron_tpu.runtime import tracing
+from stage_spy import spied_program
 
 I64 = DataType.int64()
 F64 = DataType.float64()
@@ -113,18 +114,18 @@ def test_operator_scopes_in_the_lowered_stage_program(files):
     before = set(S._PROGRAM_CACHE)
     got1 = S.execute_plan_spmd(p1, c1, mesh, {})
     [key] = set(S._PROGRAM_CACHE) - before
-    shard, schema_box = S._PROGRAM_CACHE[key]
+    shard, *boxes = S._PROGRAM_CACHE[key]
     calls = []
 
     def spy(inputs):
         calls.append(inputs)
         return shard(inputs)
 
-    S._PROGRAM_CACHE[key] = (spy, schema_box)
+    S._PROGRAM_CACHE[key] = (spy, *boxes)
     try:
         got2 = S.execute_plan_spmd(p2, c2, mesh, {})
     finally:
-        S._PROGRAM_CACHE[key] = (shard, schema_box)
+        S._PROGRAM_CACHE[key] = (shard, *boxes)
     # the second conversion ran the first one's program
     assert len(calls) == 1 and set(S._PROGRAM_CACHE) - before == {key}
     assert got1.to_pylist() == got2.to_pylist()
@@ -279,6 +280,98 @@ def test_program_spans_on_the_profilers_clock(files, tmp_path):
     assert mark.duration_ns >= query.dur_ns
 
 
+def _eqns(jaxpr, inside=()):
+    """Every equation of a jaxpr and of the jaxprs its equations hold,
+    with the chain of (primitive name, branch index) it lies under."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for name, value in eqn.params.items():
+            subs = value if isinstance(value, (tuple, list)) else (value,)
+            for i, sub in enumerate(subs):
+                sub = getattr(sub, "jaxpr", sub)     # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(
+                        sub, inside + ((eqn.primitive.name, i),))
+
+
+def test_one_conditional_a_join_and_no_loop_on_its_direct_side(files):
+    """A query-7-shaped plan: four chained broadcast joins, each on one
+    integer key of a small table.  Each join lowers to one conditional;
+    its direct side (branch 1) is a scatter and gathers, no while loop;
+    the search side (branch 0) keeps `searchsorted`'s."""
+    ctx = _Ctx()
+    node = P.ParquetScan(
+        schema=Schema((Field("k1", I64), Field("k2", I64),
+                       Field("amount", F64))),
+        file_groups=tuple(FileGroup(paths=(p,)) for p in files["fact"]))
+    for i, (name, key) in enumerate((("d1", "k1"), ("d2", "k2"),
+                                     ("d1", "k1"), ("d2", "k2"))):
+        dim = P.RenameColumns(
+            child=P.ParquetScan(
+                schema=Schema((Field(f"{name}_key", I64),
+                               Field(f"{name}_grp", I64))),
+                file_groups=(FileGroup(paths=(files[name],)),)),
+            names=(f"key{i}", f"grp{i}"))
+        ctx.broadcasts[f"bc{i}"] = BroadcastJob(rid=f"bc{i}", child=dim,
+                                                schema=None)
+        node = P.BroadcastJoin(
+            left=node, right=P.IpcReader(schema=None, resource_id=f"bc{i}"),
+            on=JoinOn(left_keys=(col(key),), right_keys=(col(f"key{i}"),)),
+            join_type="inner", broadcast_side="right")
+    program, inputs = spied_program(node, ctx, data_mesh(1), {})
+    found = list(_eqns(jax.make_jaxpr(program)(inputs).jaxpr))
+    conds = [eqn for eqn, _inside in found if eqn.primitive.name == "cond"]
+    assert len(conds) == 4
+    assert all(len(eqn.params["branches"]) == 2 for eqn in conds)
+    loops = [inside for eqn, inside in found
+             if eqn.primitive.name in ("while", "scan")]
+    sides = [dict(inside)["cond"] for inside in loops
+             if "cond" in dict(inside)]
+    assert sides and set(sides) == {0}, sides
+    # and a scatter and a gather on the direct side of each
+    for wanted in ("scatter", "gather"):
+        assert sum(eqn.primitive.name == wanted and ("cond", 1) in inside
+                   for eqn, inside in found) >= 4, wanted
+    assert program.lower(inputs).as_text().count("stablehlo.case") == 4
+
+
+def test_join_probe_counter_in_the_record_the_span_and_explain(tmp_path):
+    """`join_probes` / `join_probes_direct`: in the query record's totals,
+    on `spmd.wait`'s args, and as `probe=direct` / `probe=search` on each
+    join's line of EXPLAIN ANALYZE."""
+    from auron_tpu.frontend.session import AuronSession
+    from auron_tpu.it import queries
+    from auron_tpu.it.datagen import generate
+    from auron_tpu.it.oracle import PyArrowEngine
+    catalog = generate(str(tmp_path / "tpcds"), sf=0.002)
+    session = AuronSession(foreign_engine=PyArrowEngine())
+    with conf.scoped({"auron.trace.enable": True}):
+        res = session.execute(queries.build("q03", catalog))
+    assert res.spmd
+    marks = res.stage_stats["join_probes"]
+    text = res.explain_analyze()
+    joins = [line.split() for line in text.splitlines()
+             if "_join#" in line and "build_hash_map" not in line]
+    assert joins and len(joins) == len(marks)
+    for label, _type, probe in joins:
+        assert probe == f"probe={marks[label]}"
+        assert marks[label] in ("direct", "search")
+    n_direct = sum(m == "direct" for m in marks.values())
+    assert n_direct >= 1              # q03 joins date_dim on d_date_sk
+    rec = tracing.find_query(res.query_id)
+    assert rec.metric_totals["join_probes"] == len(marks)
+    assert rec.metric_totals["join_probes_direct"] == n_direct
+    [wait] = [s for s in res.trace.snapshot() if s.name == "spmd.wait"]
+    assert wait.args["join_probes"] == len(marks)
+    assert wait.args["join_probes_direct"] == n_direct
+    # the serial path reports no stage counter
+    with conf.scoped({"auron.spmd.singleDevice.enable": False}):
+        serial = session.execute(queries.build("q03", catalog))
+    assert not serial.spmd and serial.stage_totals() == {}
+    assert "join_probes" not in \
+        tracing.find_query(serial.query_id).metric_totals
+
+
 # name, scope path, start_ns, duration_ns
 OPS = [
     ("while.1", "jit(program)/agg#0/reduce/while", 0.0, 100.0),
@@ -308,6 +401,11 @@ OPS = [
      ("ipc_reader#15", "broadcast")),
     ("jit(p)/agg#0/reduce_sum", ("agg#0", "")),
     ("jit(p)/epilogue/gather", ("epilogue", "")),
+    ("jit(p)/agg#0/broadcast_join#1/cond/branch_1_fun/build/scatter",
+     ("broadcast_join#1", "build")),
+    ("jit(p)/broadcast_join#1/cond/branch_0_fun/probe/while/body/gather",
+     ("broadcast_join#1", "probe")),
+    ("jit(p)/broadcast_join#1/cond", ("broadcast_join#1", "")),
     ("jit(p)/jit(_take)/gather", (trace_cli.UNLABELLED, "")),
     ("", (trace_cli.UNLABELLED, "")),
 ])
