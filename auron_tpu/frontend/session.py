@@ -83,7 +83,8 @@ class SessionResult:
     exchange_stats: List[dict] = field(default_factory=list)
     # what the stage program reported of itself (parallel/stage.py::
     # execute_plan_spmd's `stats`): `join_probes`, the probe each K=1
-    # join took, by operator label
+    # join took, by operator label; over more than one device also
+    # `exchanges`, `broadcasts` and `sources`, what crossed devices
     stage_stats: Dict[str, object] = field(default_factory=dict)
 
     def to_pylist(self) -> List[dict]:
@@ -102,9 +103,8 @@ class SessionResult:
             # the stage program's operators under the labels its device
             # time is filed under (python -m auron_tpu.trace device)
             from auron_tpu.parallel.stage import explain_stage
-            stage_plan = explain_stage(
-                self.converted, self.ctx,
-                probes=self.stage_stats.get("join_probes"))
+            stage_plan = explain_stage(self.converted, self.ctx,
+                                       stats=self.stage_stats)
         return _ea(self.metrics, query_id=self.query_id,
                    wall_s=self.wall_s, rows=self.table.num_rows,
                    spmd=self.spmd,
@@ -116,11 +116,14 @@ class SessionResult:
     def stage_totals(self) -> Dict[str, int]:
         """The stage program's counters as query totals: `join_probes`
         (K=1 joins run) and `join_probes_direct` (those that probed by
-        direct address on every device)."""
+        direct address on every device); over more than one device also
+        `exchange_rows`, `exchange_rows_moved`, `exchange_buffer_bytes`,
+        `exchange_fill_pct_max`, `broadcast_rows`, `broadcast_slots` and
+        `broadcast_buffer_bytes` (stage.py::crossing_totals)."""
         if not self.spmd:
             return {}
-        from auron_tpu.parallel.stage import probe_counts
-        return probe_counts(self.stage_stats.get("join_probes") or {})
+        from auron_tpu.parallel.stage import stage_totals
+        return stage_totals(self.stage_stats)
 
     def all_native(self) -> bool:
         """True when no foreign section remains (the

@@ -86,29 +86,41 @@ def _scatter_to_send(data, dest, valid, n_dev: int, quota: int):
     return send, send_valid, overflow
 
 
+def destination_counts(dest, valid, n_dev: int):
+    """int32 [N]: how many valid rows of this device go to each destination
+    — the rows of its send buffer's blocks before the quota cuts them."""
+    to = dest[None, :] == jnp.arange(n_dev, dtype=dest.dtype)[:, None]
+    return jnp.sum(jnp.logical_and(to, valid[None, :]), axis=1,
+                   dtype=jnp.int32)
+
+
 def all_to_all_repartition(arrays: List[Any], dest, valid, axis: str,
                            n_dev: int, quota: int
-                           ) -> Tuple[List[Any], Any]:
+                           ) -> Tuple[List[Any], Any, Any]:
     """Repartition rows of `arrays` (each [C, ...]) by `dest` device ids.
 
     Returns (received_arrays each [N*Q, ...], received_valid [N*Q],
     overflow bool scalar — LOCAL to this device; psum/any-reduce it).
-    Must run inside shard_map with named axis `axis`.
+    Must run inside shard_map with named axis `axis`.  The send buffers'
+    construction traces under the scope `scatter`, the transfer under
+    `all_to_all`.
     """
     outs = []
     recv_valid = None
     overflow = None
     for a in arrays:
-        send, send_valid, ovf = _scatter_to_send(a, dest, valid, n_dev,
-                                                 quota)
-        recv = lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
-                              tiled=False)
-        outs.append(recv.reshape((n_dev * quota,) + a.shape[1:]))
-        if recv_valid is None:
-            overflow = ovf
-            rv = lax.all_to_all(send_valid, axis, split_axis=0,
-                                concat_axis=0, tiled=False)
-            recv_valid = rv.reshape(n_dev * quota)
+        with jax.named_scope("scatter"):
+            send, send_valid, ovf = _scatter_to_send(a, dest, valid, n_dev,
+                                                     quota)
+        with jax.named_scope("all_to_all"):
+            recv = lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
+                                  tiled=False)
+            outs.append(recv.reshape((n_dev * quota,) + a.shape[1:]))
+            if recv_valid is None:
+                overflow = ovf
+                rv = lax.all_to_all(send_valid, axis, split_axis=0,
+                                    concat_axis=0, tiled=False)
+                recv_valid = rv.reshape(n_dev * quota)
     if overflow is None:
         overflow = jnp.asarray(False)
     return outs, recv_valid, overflow

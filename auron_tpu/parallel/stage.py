@@ -58,7 +58,7 @@ from auron_tpu.ir.schema import DataType, Field, Schema, TypeId
 from auron_tpu.ops.sort_keys import stable_argsort
 from auron_tpu.parallel.exchange import (
     all_to_all_repartition, bounded_quota, broadcast_all_gather,
-    hierarchical_repartition,
+    destination_counts, hierarchical_repartition,
 )
 from auron_tpu.runtime import jitcheck
 
@@ -199,12 +199,16 @@ def _peel_tail(plan, exchanges):
 
 
 def explain_stage(plan, conv_ctx,
-                  probes: Optional[Dict[str, str]] = None) -> str:
+                  stats: Optional[Dict[str, Any]] = None) -> str:
     """The stage path's EXPLAIN text: the driver-side tail, then every
     operator of the stage program under the label its device time is
-    filed under.  `probes` (execute_plan_spmd's `stats["join_probes"]`)
-    marks each K=1 join with the probe it took: `direct` or `search`."""
-    probes = probes or {}
+    filed under.  `stats` (execute_plan_spmd's) marks each K=1 join with
+    the probe it took, `direct` or `search`, and each boundary that
+    crossed devices with what it moved."""
+    stats = stats or {}
+    probes = stats.get("join_probes") or {}
+    crossed = {**(stats.get("exchanges") or {}),
+               **(stats.get("broadcasts") or {})}
     exchanges = getattr(conv_ctx, "exchanges", None) or {}
     broadcasts = getattr(conv_ctx, "broadcasts", None) or {}
     tail, _shadow, body = _peel_tail(plan, exchanges)
@@ -224,6 +228,16 @@ def explain_stage(plan, conv_ctx,
                     exchanges[node.resource_id].partitioning.mode
             elif node.resource_id in broadcasts:
                 detail = " broadcast"
+            c = crossed.get(label)
+            if c is not None and "quota" in c:
+                detail += (
+                    f" rows={c['rows']} moved={c['rows_moved']}"
+                    f" recv_max={c['rows_recv_max']} quota={c['quota']}"
+                    f" fill={c['fill_pct']:.2f}%"
+                    f" bytes={c['moved_bytes']}/{c['buffer_bytes']}")
+            elif c is not None:
+                detail += (f" rows={c['rows']} slots={c['slots']}"
+                           f" bytes={c['buffer_bytes']}")
         lines.append(f"{'  ' * depth}{label}{detail}")
     return "\n".join(lines)
 
@@ -231,6 +245,13 @@ def explain_stage(plan, conv_ctx,
 # ---------------------------------------------------------------------------
 # plan walk (traced inside shard_map)
 # ---------------------------------------------------------------------------
+
+def _row_bytes(flat) -> int:
+    """Bytes one row takes in a collective's buffers: a slot of every
+    array, and its bit of the live mask (a byte)."""
+    return 1 + sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for a in flat)
+
 
 class _StageTracer:
     def __init__(self, conv_ctx, bindings: Dict[str, DeviceTable],
@@ -279,6 +300,11 @@ class _StageTracer:
         # number of devices that took the direct-address probe — a device
         # scalar — or None where the join traced the search alone)
         self.probes: List[Tuple[str, Any]] = []
+        # one entry per exchange or broadcast boundary that crossed
+        # devices, in trace order: (what is known of it at trace time,
+        # its counts — a replicated int64 device vector); a one-device
+        # program has none (_count_exchange, _count_broadcast)
+        self.crossings: List[Tuple[Dict[str, Any], Any]] = []
         # join pair-expansion factor (1 = single-candidate probe)
         self.match_factor = max(1, int(match_factor))
         # post-agg static capacity (rows/device); 0 keeps input capacity
@@ -351,21 +377,23 @@ class _StageTracer:
         # an IpcReader is how the converted plan references an exchange or
         # broadcast boundary; inline it as a collective
         rid = n.resource_id
+        label = self.labels.get(id(n), n.kind)
         if rid in self.exchanges:
             job = self.exchanges[rid]
             child = self.eval_node(_require_native(job.child))
             with jax.named_scope("exchange"):
-                return self._exchange(child, job.partitioning)
+                return self._exchange(child, job.partitioning, label)
         if rid in self.broadcasts:
             job = self.broadcasts[rid]
             child = self.eval_node(_require_native(job.child))
             with jax.named_scope("broadcast"):
-                return self._broadcast(child)
+                return self._broadcast(child, label)
         return self._binding(rid, n.schema)
 
     # exchanges --------------------------------------------------------------
 
-    def _exchange(self, t: DeviceTable, part: P.Partitioning) -> DeviceTable:
+    def _exchange(self, t: DeviceTable, part: P.Partitioning,
+                  label: str) -> DeviceTable:
         n_dev = self.n_dev
         if n_dev == 1:
             # single-device axis: every row already lives on its
@@ -402,6 +430,7 @@ class _StageTracer:
         else:
             raise SpmdUnsupported(f"partitioning mode {part.mode!r}")
         flat, treedef = jax.tree.flatten(t.cols)
+        row_bytes = _row_bytes(flat)
         # bounded quota for spreading modes (hash/rr): received buffers
         # stay O(global/n_dev * margin); a single-partition exchange
         # legitimately funnels everything to one device, so it keeps the
@@ -430,6 +459,11 @@ class _StageTracer:
                 quota=q1, bound_stage2=not funnel)
             any_ovf = lax.psum(
                 lax.psum(ovf.astype(jnp.int32), a_ici), a_dcn) > 0
+            # the first stage's blocks go to the n_ici local chips, each
+            # row with its int32 route; the second sends what it received
+            quota, blocks = q1, n_ici
+            buffer_bytes = (row_bytes + 4) * n_ici * q1 \
+                + row_bytes * live.shape[0]
         else:
             quota = t.capacity if funnel \
                 else bounded_quota(t.capacity, min(n_dev, spread))
@@ -437,11 +471,39 @@ class _StageTracer:
                                                      self.axis, n_dev,
                                                      quota=quota)
             any_ovf = lax.psum(ovf.astype(jnp.int32), self.axis) > 0
+            blocks, buffer_bytes = n_dev, row_bytes * n_dev * quota
         self.guards.append(any_ovf)
+        with jax.named_scope("count"):
+            self._count_exchange(
+                {"label": label, "kind": "exchange", "mode": part.mode,
+                 "quota": quota, "row_bytes": row_bytes,
+                 "buffer_bytes": buffer_bytes}, pid, t.live, live, blocks)
         cols = jax.tree.unflatten(treedef, outs)
         return DeviceTable(t.schema, cols, live)
 
-    def _broadcast(self, t: DeviceTable) -> DeviceTable:
+    def _count_exchange(self, what: Dict[str, Any], pid, live_in, live_out,
+                        blocks: int) -> None:
+        """What an exchange moved, counted from its routing and from what
+        arrived, not taken from the kernel: live rows in, those bound for
+        another device, the most any device received, and the fullest
+        (source, destination) block of the first all_to_all before its
+        quota cut it (`blocks` destinations a device; past the quota the
+        overflow guard has tripped).  Replicated: one psum, one pmax."""
+        sent = destination_counts(pid, live_in, self.n_dev)
+        rows_in = jnp.sum(sent, dtype=jnp.int32)
+        stayed = jnp.take(sent, self._axis_index())
+        fullest = jnp.max(jnp.sum(sent.reshape(-1, blocks), axis=0,
+                                  dtype=jnp.int32))
+        sums = lax.psum(jnp.stack([rows_in, rows_in - stayed])
+                        .astype(jnp.int64), self.axis)
+        # a device's counts fit 32 bits, and the TPU lowers no 64-bit
+        # all-reduce but the sum
+        tops = lax.pmax(jnp.stack([
+            jnp.sum(live_out, dtype=jnp.int32), fullest]), self.axis)
+        self.crossings.append(
+            (what, jnp.concatenate([sums, tops.astype(jnp.int64)])))
+
+    def _broadcast(self, t: DeviceTable, label: str) -> DeviceTable:
         flat, treedef = jax.tree.flatten(t.cols)
         if isinstance(self.axis, tuple):
             live = t.live
@@ -449,6 +511,15 @@ class _StageTracer:
                 flat, live = broadcast_all_gather(flat, live, ax)
         else:
             flat, live = broadcast_all_gather(flat, t.live, self.axis)
+        if self.n_dev > 1:
+            # every device holds the same gathered rows: its own count
+            # is the replicated one
+            with jax.named_scope("count"):
+                self.crossings.append((
+                    {"label": label, "kind": "broadcast",
+                     "slots": live.shape[0],
+                     "buffer_bytes": _row_bytes(flat) * live.shape[0]},
+                    jnp.sum(live, dtype=jnp.int64)[None]))
         cols = jax.tree.unflatten(treedef, flat)
         return DeviceTable(t.schema, cols, live)
 
@@ -1237,6 +1308,13 @@ from auron_tpu.ir.node import tree_has_kind as _tree_has  # noqa: E402
 # host driver: shard inputs, run the program, gather + compact
 # ---------------------------------------------------------------------------
 
+def _rows_per_device(n: int, n_dev: int) -> List[int]:
+    """How `_shard_table` deals n rows in file order: ceil(n / n_dev) a
+    device until they run out."""
+    per_dev = -(-max(n, 1) // n_dev)
+    return [min(max(n - d * per_dev, 0), per_dev) for d in range(n_dev)]
+
+
 def _shard_table(table, mesh: Mesh, axis: str) -> Tuple[Schema, List[Any],
                                                         Array, int]:
     """Split an arrow table row-wise across the mesh: returns flat arrays
@@ -1288,8 +1366,7 @@ def _shard_table(table, mesh: Mesh, axis: str) -> Tuple[Schema, List[Any],
                 jnp.asarray(np.concatenate(
                     [np.asarray(p.validity) for p in parts])), bits))
     live = np.zeros(n_dev * cap, bool)
-    for d in range(n_dev):
-        got = min(max(n - d * per_dev, 0), per_dev)
+    for d, got in enumerate(_rows_per_device(n, n_dev)):
         live[d * cap: d * cap + got] = True
     return schema, cols, jnp.asarray(live), cap
 
@@ -1488,9 +1565,13 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     source_tables: rid -> pyarrow.Table for every FFI source the plan
     references (the C2N boundary inputs).  Returns a pyarrow.Table.
     Raises SpmdUnsupported when the plan shape cannot be expressed.
-    `stats`, when given, receives what the program reported of itself:
-    `join_probes`, {operator label: "direct" | "search" | "direct k/n"}
-    for every K=1 join of the attempt that gave the result.
+    `stats`, when given, receives what the last attempt's program
+    reported of itself (the attempt that gave the result, or the one whose
+    guard tripped last): `join_probes`, {operator label: "direct" |
+    "search" | "direct k/n"} for every K=1 join; over more than one
+    device also `exchanges` and `broadcasts`, {operator label: counts} for
+    every boundary (`_crossing_stats`), and `sources`, {canonical rid:
+    rows, cap, rows on the fullest and the emptiest device}.
 
     A tripped join guard (duplicate build keys past the current match
     factor) retries ONCE with auron.spmd.join.match.factor pair
@@ -1750,6 +1831,62 @@ def probe_counts(probes: Dict[str, str]) -> Dict[str, int]:
                                       for m in probes.values())}
 
 
+def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
+    """{"exchanges": {label: ..}, "broadcasts": {label: ..}} of one run,
+    from what the tracer knew of each boundary (`cross_box`) and the
+    program's counts.  An exchange: `rows` in, `rows_moved` to another
+    device and their `moved_bytes`, `rows_recv_max` on the fullest device,
+    `block_rows_max` of the fullest (source, destination) block against
+    its `quota` as `fill_pct` (past 100 the overflow guard tripped), and
+    the `buffer_bytes` a device's all_to_all carries whatever the rows.  A
+    broadcast: live `rows` in `slots` gathered to every device, and the
+    gathered `buffer_bytes`.  Empty on one device."""
+    out: Dict[str, Dict[str, dict]] = {}
+    counts = iter(np.asarray(cross_np).tolist()
+                  if cross_np is not None else ())
+    for what in cross_box:
+        what = dict(what)
+        of_kind = out.setdefault(what.pop("kind") + "s", {})
+        if "quota" in what:
+            rows, moved, recv, block = (next(counts) for _ in range(4))
+            what.update(
+                rows=rows, rows_moved=moved, rows_recv_max=recv,
+                block_rows_max=block,
+                fill_pct=100.0 * block / what["quota"],
+                moved_bytes=moved * what.pop("row_bytes"))
+        else:
+            what["rows"] = next(counts)
+        of_kind[what.pop("label")] = what
+    return out
+
+
+def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
+    """execute_plan_spmd's `stats` as query totals: the probe counter's
+    two numbers and the boundaries' counts."""
+    return {**probe_counts(stats.get("join_probes") or {}),
+            **crossing_totals(stats)}
+
+
+def crossing_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
+    """The boundaries' counts as query totals; none where nothing crossed
+    devices."""
+    ex = list((stats.get("exchanges") or {}).values())
+    bc = list((stats.get("broadcasts") or {}).values())
+    out: Dict[str, Any] = {}
+    if ex:
+        out.update(
+            exchange_rows=sum(e["rows"] for e in ex),
+            exchange_rows_moved=sum(e["rows_moved"] for e in ex),
+            exchange_buffer_bytes=sum(e["buffer_bytes"] for e in ex),
+            exchange_fill_pct_max=max(e["fill_pct"] for e in ex))
+    if bc:
+        out.update(
+            broadcast_rows=sum(b["rows"] for b in bc),
+            broadcast_slots=sum(b["slots"] for b in bc),
+            broadcast_buffer_bytes=sum(b["buffer_bytes"] for b in bc))
+    return out
+
+
 def _execute_plan_spmd_once(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                             source_tables: Dict[str, Any], axis,
                             match_factor: int,
@@ -1831,9 +1968,13 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                  _string_cfg_fingerprint())
     host_inputs = {}
     schemas = {}
+    # rows of every source on each device: what a device is given to scan
+    device_rows = np.zeros(n_dev, np.int64)
     with tracing.span("spmd.shard", cat="spmd",
-                      sources=len(source_tables)):
+                      sources=len(source_tables)) as shard_span:
         for rid, table in source_tables.items():
+            dealt = _rows_per_device(table.num_rows, n_dev)
+            device_rows += dealt
             e = _DEVICE_SHARDS.get(table, shard_key)
             if e is None:
                 with tracing.span("shard.pad", cat="spmd") as sp:
@@ -1842,6 +1983,9 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     if sp.armed:
                         sp.set_args(rows=table.num_rows, cap=cap,
                                     bytes=table.nbytes)
+                        if n_dev > 1:
+                            sp.set_args(rows_max=max(dealt),
+                                        rows_min=min(dealt))
                 with tracing.span("shard.put", cat="spmd") as sp:
                     e = {"schema": schema,
                          "cols": jax.tree.map(
@@ -1856,6 +2000,14 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 _DEVICE_SHARDS.put(table, e, shard_key)
             host_inputs[rid] = (e["cols"], e["live"])
             schemas[rid] = e["schema"]
+            if n_dev > 1 and stats is not None:
+                stats.setdefault("sources", {})[rid] = {
+                    "rows": table.num_rows,
+                    "cap": e["live"].shape[0] // n_dev,
+                    "rows_max": max(dealt), "rows_min": min(dealt)}
+        if n_dev > 1 and shard_span.armed:
+            shard_span.set_args(device_rows_max=int(device_rows.max()),
+                                device_rows_min=int(device_rows.min()))
     # program cache: repeat executions of the SAME converted plan over the
     # same input shapes reuse the compiled shard_map program (a fresh
     # jax.jit closure per call would re-trace+re-compile every time)
@@ -1908,6 +2060,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         # (operator label, traced with a choice) per K=1 join, filled at
         # trace time like the schema
         probe_box: List[Tuple[str, bool]] = []
+        # what the tracer knew of each boundary that crossed devices
+        cross_box: List[Dict[str, Any]] = []
         labels = {id(node): label
                   for _depth, node, label in operator_labels(plan, conv_ctx)}
 
@@ -1928,6 +2082,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 schema_box.append(out.schema)
                 probe_box.extend((label, flag is not None)
                                  for label, flag in tracer.probes)
+                cross_box.extend(what for what, _n in tracer.crossings)
             with jax.named_scope("epilogue"):
                 guards = jnp.stack(tracer.guards) if tracer.guards else \
                     jnp.zeros(0, bool)
@@ -1943,6 +2098,10 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 direct = [flag for _label, flag in tracer.probes
                           if flag is not None]
                 probe_direct = jnp.stack(direct) if direct else None
+                # the boundaries' counts; none on one device, likewise
+                crossed = jnp.concatenate(
+                    [n for _what, n in tracer.crossings]) \
+                    if tracer.crossings else None
                 cols, live = out.cols, out.live
                 count = jnp.sum(live.astype(jnp.int32))[None]
                 if compact_gather:
@@ -1955,16 +2114,16 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     cols = [c.gather(perm, ok) for c in cols]
                     live = ok
             return (cols, live, count, guards, retry_guards,
-                    shrink_guards, join_guards, probe_direct)
+                    shrink_guards, join_guards, probe_direct, crossed)
 
         shard = jitcheck.site("spmd.stage").jit(jax.shard_map(
             program, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: PS(axis), host_inputs),),
             out_specs=(PS(axis), PS(axis), PS(axis), PS(), PS(), PS(),
-                       PS(), PS()),
+                       PS(), PS(), PS()),
             check_vma=False))
     else:
-        shard, schema_box, probe_box = cached
+        shard, schema_box, probe_box, cross_box = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
     # compiles the whole stage program, so the span is the compile span
@@ -1975,9 +2134,10 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             cat="spmd", devices=n_dev,
             first_launch_included=cached is None):
         (out_cols, out_live, counts, guards, retry_guards, shrink_guards,
-         join_guards, probe_direct) = shard(host_inputs)
+         join_guards, probe_direct, crossed) = shard(host_inputs)
     if cached is None:
-        _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box)
+        _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box,
+                                     cross_box)
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
@@ -1992,11 +2152,13 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             # the stage program (`spmd.run` was its enqueue).
             with tracing.span("spmd.wait", cat="spmd") as sp:
                 (counts_np, guards_np, retry_np, shrink_np, join_np,
-                 direct_np) = host_sync(
+                 direct_np, crossed_np) = host_sync(
                     (counts, guards, retry_guards, shrink_guards,
-                     join_guards, probe_direct))
-                probes = _probe_marks(probe_box, direct_np, n_dev)
-                sp.set_args(**probe_counts(probes))
+                     join_guards, probe_direct, crossed))
+                reported = {
+                    "join_probes": _probe_marks(probe_box, direct_np, n_dev),
+                    **_crossing_stats(cross_box, crossed_np)}
+                sp.set_args(**stage_totals(reported))
         else:
             # single batched fetch (CPU: transfers are memcpy-cheap, two
             # round trips would only add dispatch latency): the wait for
@@ -2004,13 +2166,19 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             # this path records no `spmd.fetch`
             with tracing.span("spmd.wait", cat="spmd") as sp:
                 (out_live_np, out_cols_np, counts_np, guards_np, retry_np,
-                 shrink_np, join_np, direct_np) = host_sync(
+                 shrink_np, join_np, direct_np, crossed_np) = host_sync(
                     (out_live, out_cols, counts, guards, retry_guards,
-                     shrink_guards, join_guards, probe_direct))
-                probes = _probe_marks(probe_box, direct_np, n_dev)
+                     shrink_guards, join_guards, probe_direct, crossed))
+                reported = {
+                    "join_probes": _probe_marks(probe_box, direct_np, n_dev),
+                    **_crossing_stats(cross_box, crossed_np)}
                 sp.set_args(**_note_gather(counts_np, out_live_np,
                                            out_cols_np),
-                            **probe_counts(probes))
+                            **stage_totals(reported))
+        if stats is not None:
+            # before the guards: a tripped exchange guard's fill is what
+            # says why
+            stats.update(reported)
         if np.any(np.asarray(guards_np)):
             raise SpmdGuardTripped(
                 "runtime guard tripped (exchange quota overflow, or "
@@ -2028,8 +2196,6 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             raise SpmdGuardTripped(
                 "duplicate-key build side at match factor 1: result "
                 "discarded", retryable=True)
-        if stats is not None:
-            stats["join_probes"] = probes
         if compact_gather:
             # phase 2: slice each shard to the smallest capacity bucket
             # that holds its rows (one tiny cached program), then fetch

@@ -147,6 +147,10 @@ _OP_LABEL = re.compile(r"^[a-z_]+#[0-9]+$")
 # label in a scope path is a jit or primitive name
 _SUB_SCOPES = frozenset({"build", "probe", "exchange", "broadcast",
                          "group", "reduce", "compact"})
+# the scopes beneath a boundary's own (parallel/exchange.py, stage.py's
+# counts): `exchange/scatter` fills the send buffers, `exchange/all_to_all`
+# moves them
+_BOUNDARY_SCOPES = frozenset({"scatter", "all_to_all", "count"})
 # what jax puts between a label and a sub-scope opened inside a branch of
 # a `lax.cond` (the join's choice of probe)
 _COND_PARTS = re.compile(r"^(cond|branch_[0-9]+_fun)$")
@@ -155,14 +159,19 @@ _COND_PARTS = re.compile(r"^(cond|branch_[0-9]+_fun)$")
 def label_of(scope_path: str) -> Tuple[str, str]:
     """(operator label, sub-scope) of a scope path such as
     `jit(program)/agg#0/broadcast_join#2/probe/jit(_take)/gather`: the
-    innermost operator label and the sub-scope right under it; the
-    stage program's `epilogue`; else `unlabelled`."""
+    innermost operator label and the sub-scope right under it (two deep
+    beneath a boundary: `exchange/scatter`); the stage program's
+    `epilogue`; else `unlabelled`."""
     parts = [p for p in scope_path.split("/") if not _COND_PARTS.match(p)]
     for i in range(len(parts) - 1, -1, -1):
         if _OP_LABEL.match(parts[i]):
-            sub = parts[i + 1] if i + 1 < len(parts) and \
-                parts[i + 1] in _SUB_SCOPES else ""
-            return parts[i], sub
+            below = parts[i + 1:i + 3]
+            if not below or below[0] not in _SUB_SCOPES:
+                return parts[i], ""
+            if below[0] in ("exchange", "broadcast") and \
+                    below[-1] in _BOUNDARY_SCOPES:
+                return parts[i], "/".join(below)
+            return parts[i], below[0]
     if "epilogue" in parts:
         return "epilogue", ""
     return UNLABELLED, ""
@@ -346,22 +355,37 @@ def _cmd_device(args: argparse.Namespace) -> int:
         print("trace: the profile holds no device plane with an "
               "'XLA Ops' line (a CPU profile has none)", file=sys.stderr)
         return 2
+    busy_of = {}
     for plane, ops in sorted(planes.items()):
         doc = device_summary(ops)
-        busy = doc["busy_s"]
+        busy = busy_of[plane] = doc["busy_s"]
         print(f"{plane}: {len(ops)} operations, busy {busy:.6f} s, "
               f"{100 * doc['labelled_s'] / busy if busy else 0:.2f} % "
               f"under an operator label")
-        print(f"{'label':28} {'scope':10} {'seconds':>11} {'share':>7} "
+        print(f"{'label':28} {'scope':19} {'seconds':>11} {'share':>7} "
               f"{'ops':>6}  longest operation (its seconds)")
-        for label, sub, sec, share, n, name, name_s in \
-                doc["rows"][:args.top]:
-            print(f"{label[:28]:28} {sub:10} {sec:11.6f} "
+
+        def show(row):
+            label, sub, sec, share, n, name, name_s = row
+            print(f"{label[:28]:28} {sub:19} {sec:11.6f} "
                   f"{100 * share:6.2f}% {n:6d}  {name} ({name_s:.6f})")
+        for row in doc["rows"][:args.top]:
+            show(row)
+        # what crosses devices, however little of the time it takes
+        for row in doc["rows"][args.top:]:
+            if row[1].split("/")[0] in ("exchange", "broadcast"):
+                show(row)
         print(f"the {args.ops} operations with most self time:")
         for name, label, sub, sec, n in doc["ops"][:args.ops]:
             where = f"{label}/{sub}" if sub else label
             print(f"  {sec:11.6f} s  x{n:<4d} {where:32} {name}")
+    most = max(busy_of, key=busy_of.get)
+    least = min(busy_of, key=busy_of.get)
+    if len(busy_of) > 1 and busy_of[most]:
+        print(f"{len(busy_of)} device planes: busiest {most} "
+              f"{busy_of[most]:.6f} s, least busy {least} "
+              f"{busy_of[least]:.6f} s, "
+              f"{100 * (1 - busy_of[least] / busy_of[most]):.2f} % less")
     return 0
 
 

@@ -149,6 +149,10 @@ def test_operator_scopes_in_the_lowered_stage_program(files):
             assert f"{label}/group/" in text and f"{label}/reduce/" in text
     # the collectives lie under their boundary's label
     assert "/exchange/" in text and "/broadcast/" in text
+    # an exchange's send buffers, its transfer and its counts apart
+    for sub in ("scatter", "all_to_all", "count"):
+        assert f"/exchange/{sub}/" in text, sub
+    assert "/broadcast/count/" in text
     assert '"epilogue/' in text
     # nesting reads back to the tree: a child's scope inside its parent's
     join_labels = [lb for lb in labels if lb.startswith("broadcast_join#")]
@@ -399,6 +403,14 @@ OPS = [
     ("jit(p)/agg#0/reduce/while/body/add", ("agg#0", "reduce")),
     ("jit(p)/ipc_reader#15/broadcast/all_gather",
      ("ipc_reader#15", "broadcast")),
+    ("jit(p)/ipc_reader#15/broadcast/count/reduce_sum",
+     ("ipc_reader#15", "broadcast/count")),
+    ("jit(p)/agg#1/ipc_reader#2/exchange/scatter/sort",
+     ("ipc_reader#2", "exchange/scatter")),
+    ("jit(p)/agg#1/ipc_reader#2/exchange/all_to_all/all_to_all",
+     ("ipc_reader#2", "exchange/all_to_all")),
+    ("jit(p)/agg#1/ipc_reader#2/exchange/jit(_hash)/mul",
+     ("ipc_reader#2", "exchange")),
     ("jit(p)/agg#0/reduce_sum", ("agg#0", "")),
     ("jit(p)/epilogue/gather", ("epilogue", "")),
     ("jit(p)/agg#0/broadcast_join#1/cond/branch_1_fun/build/scatter",
@@ -459,26 +471,30 @@ def _pb(*fields) -> bytes:
     return out
 
 
-def _xspace(ops) -> bytes:
-    """An XSpace as the v5e's profile holds one: a device plane whose
-    "XLA Ops" events carry times only, the scope path in the `tf_op` stat
-    of each event's metadata; and a host plane."""
-    names = sorted({(name, scope) for name, scope, _s, _d in ops})
+def _device_plane(name, ops) -> bytes:
+    """A device plane as the v5e's profile holds one: "XLA Ops" events
+    that carry times only, the scope path in the `tf_op` stat of each
+    event's metadata."""
+    names = sorted({(op, scope) for op, scope, _s, _d in ops})
     ids = {key: i + 1 for i, key in enumerate(names)}
     metadata = [
         (4, _pb((1, ids[key]), (2, _pb(
             (1, ids[key]), (2, f"%{key[0]} = u32[8]{{0}} fusion(u32[2]{{0}} "
                                f"%a, s32[8]{{0}} %b), kind=kLoop"),
             (5, _pb((1, 7), (5, key[1]))))))) for key in names]
-    events = [(4, _pb((1, ids[(name, scope)]), (2, int(start * 1000)),
+    events = [(4, _pb((1, ids[(op, scope)]), (2, int(start * 1000)),
                       (3, int(dur * 1000))))
-              for name, scope, start, dur in ops]
-    device = _pb((2, "/device:TPU:0"),
-                 (3, _pb((1, 1), (2, "XLA Ops"), (3, 0), *events)),
-                 *metadata,
-                 (5, _pb((1, 7), (2, _pb((1, 7), (2, "tf_op"))))))
+              for op, scope, start, dur in ops]
+    return _pb((2, name),
+               (3, _pb((1, 1), (2, "XLA Ops"), (3, 0), *events)),
+               *metadata,
+               (5, _pb((1, 7), (2, _pb((1, 7), (2, "tf_op"))))))
+
+
+def _xspace(ops) -> bytes:
+    """One device plane and a host plane."""
     host = _pb((2, "/host:CPU"), (3, _pb((1, 2), (2, "main"), (3, 0))))
-    return _pb((1, device), (1, host))
+    return _pb((1, _device_plane("/device:TPU:0", ops)), (1, host))
 
 
 def test_device_subcommand_on_a_hand_built_profile(tmp_path, capsys):
@@ -496,6 +512,34 @@ def test_device_subcommand_on_a_hand_built_profile(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "9 operations" in out and "96.00 % under an operator" in out
     assert "broadcast_join#1/probe" in out and "unlabelled" in out
+
+
+def test_device_subcommand_on_two_planes(tmp_path, capsys):
+    """One block a device plane; what crosses devices is printed however
+    short `--top` cuts the table; the planes' busy seconds side by side."""
+    def ops(scatter_ns):
+        return [("fusion.1", "jit(p)/agg#1/reduce/add", 0.0, 900.0),
+                ("fusion.2", "jit(p)/agg#1/ipc_reader#2/exchange/scatter/"
+                             "scatter", 900.0, scatter_ns),
+                ("all-to-all.3", "jit(p)/agg#1/ipc_reader#2/exchange/"
+                                 "all_to_all/all_to_all", 2000.0, 10.0),
+                ("all-gather.4", "jit(p)/ipc_reader#5/broadcast/all_gather",
+                 2010.0, 5.0)]
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(
+        _pb((1, _device_plane("/device:TPU:0", ops(100.0))),
+            (1, _device_plane("/device:TPU:1", ops(50.0)))))
+    assert trace_cli.main(["device", str(tmp_path), "--top", "1"]) == 0
+    out = capsys.readouterr().out
+    assert out.count(" operations, busy ") == 2
+    rows = [ln.split()[:2] for ln in out.splitlines()
+            if ln.startswith("ipc_reader#")]
+    assert rows == 2 * [["ipc_reader#2", "exchange/scatter"],
+                        ["ipc_reader#2", "exchange/all_to_all"],
+                        ["ipc_reader#5", "broadcast"]]
+    assert "2 device planes: busiest /device:TPU:0 0.000001 s" in out
+    assert "least busy /device:TPU:1" in out and "4.93 % less" in out
 
 
 def test_short_op_name():
