@@ -38,6 +38,7 @@ from auron_tpu.ir.schema import DataType, Field, Schema
 from auron_tpu.memmgr import MemConsumer, SpillManager
 from auron_tpu.ops.agg.functions import AggSpec, HostAggSpec, make_spec
 from auron_tpu.ops.base import Operator, TaskContext, batch_size
+from auron_tpu.ops.segments import in_branch
 from auron_tpu.ops.sort_keys import (
     encode_sort_keys, keys_equal_prev, lexsort_indices_live,
 )
@@ -848,8 +849,18 @@ def _group_reduce_body(keys: List[Any], value_cols: List[List[Any]],
         seg_of_sorted = jnp.cumsum(is_boundary.astype(jnp.int32)) - 1
         seg_of_sorted = jnp.where(slive, seg_of_sorted, capacity - 1)
         n_groups = jnp.sum(is_boundary.astype(jnp.int32))
-        first_sorted_idx = jnp.nonzero(is_boundary, size=capacity,
-                                       fill_value=0)[0].astype(jnp.int32)
+        if in_branch():
+            # `jnp.nonzero(size=)` counts in 64 bits, which XLA:TPU does
+            # not compile inside a conditional's branch (ops/segments.py
+            # `inside_branch`): a boundary row's segment id is its rank,
+            # so one scatter of row numbers gives the same array
+            first_sorted_idx = jnp.zeros(capacity, jnp.int32).at[
+                jnp.where(is_boundary, seg_of_sorted, capacity)
+            ].set(jnp.arange(capacity, dtype=jnp.int32), mode="drop")
+        else:
+            first_sorted_idx = jnp.nonzero(
+                is_boundary, size=capacity,
+                fill_value=0)[0].astype(jnp.int32)
         key_src = jnp.take(perm, first_sorted_idx)
         g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
         out_cols: List[Any] = []

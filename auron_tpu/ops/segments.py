@@ -54,6 +54,54 @@ def _unsorted_mode() -> int:
     return getattr(_TRACE_MODE, "unsorted", 0)
 
 
+class inside_branch:
+    """Trace-time context: what is traced here becomes a branch of a
+    `lax.cond` (the stage program's choice of an aggregate's input,
+    parallel/stage.py `_do_agg`).  XLA:TPU cannot compile a 1-D 64-bit
+    running sum inside a conditional's branch: ahead of time for a v5e,
+    `lax.cond(p, lambda: jnp.cumsum(x), ...)` over int64[4194304] is
+    refused (RESOURCE_EXHAUSTED: scoped vmem, in the 64-bit
+    reduce-window's third level) and over int64[262144] it had not
+    compiled after 40 minutes, where the same `cumsum` outside a branch
+    compiles in 7 s (PR 29).  Blocked — a running sum along the rows of a
+    [n / 2048, 2048] view, then the same over the rows' totals, down to
+    one block of 2,048 — it compiles in 8-9 s at both sizes, so that is
+    the form `_int_cumsum` takes here, and only here: outside a branch
+    the programs are what they were.  Thread-local like
+    `unsorted_segments`."""
+
+    def __enter__(self):
+        _TRACE_MODE.branch = getattr(_TRACE_MODE, "branch", 0) + 1
+
+    def __exit__(self, *exc):
+        _TRACE_MODE.branch -= 1
+
+
+def in_branch() -> bool:
+    return bool(getattr(_TRACE_MODE, "branch", 0))
+
+
+_CUMSUM_BLOCK = 2048
+
+
+def _int_cumsum(x):
+    """Inclusive running sum of a 1-D integer column, modular on wrap."""
+    if not in_branch() or x.dtype.itemsize < 8:
+        return jnp.cumsum(x)
+    n = x.shape[0]
+    pad = -n % _CUMSUM_BLOCK
+    if n <= _CUMSUM_BLOCK:
+        # one block, always of the one length: which 1-D lengths the
+        # compiler takes inside a branch is a matter of trial (2,048 and
+        # 128 it takes, 512 it refuses)
+        return jnp.cumsum(jnp.pad(x, (0, pad)))[:n]
+    rows = jnp.cumsum(
+        jnp.pad(x, (0, pad)).reshape(-1, _CUMSUM_BLOCK), axis=1)
+    totals = rows[:, -1]
+    before = _int_cumsum(totals) - totals
+    return (rows + before[:, None]).reshape(-1)[:n]
+
+
 def _use_sorted() -> bool:
     return bool(conf.get("auron.segments.sorted.enable"))
 
@@ -98,7 +146,7 @@ def sorted_segment_sum(x, seg, num_segments: int):
         total = jnp.take(run, jnp.clip(ends - 1, 0), mode="clip")
         return jnp.where(nonempty, total, jnp.zeros((), x.dtype))
     # integer sums: modular cumsum difference is EXACT even on wrap
-    csum = jnp.cumsum(x)
+    csum = _int_cumsum(x)
     upper = jnp.take(csum, jnp.clip(ends - 1, 0), mode="clip")
     lower = jnp.where(starts > 0,
                       jnp.take(csum, jnp.clip(starts - 1, 0), mode="clip"),

@@ -16,7 +16,11 @@ program over a `jax.sharding.Mesh`:
 - broadcast exchange: `lax.all_gather` materializes the build side on
   every device (NativeBroadcastExchangeBase.collectNative analogue);
 - group aggregation: the same sort-based `_group_reduce_body` kernel the
-  serial engine uses, traced inline;
+  serial engine uses, traced inline — on the table it is given, or, where
+  that table is larger than the capacity the output is cut to and its
+  live rows fit that capacity, on those rows compacted to it (`_do_agg`:
+  chosen in the program from the live count, per device, like the probe
+  below);
 - broadcast/hash/sort-merge join: one lookup contract, (build row, found)
   per probe row, computed one of two ways.  A single integer or date key
   whose live build keys span less than the build side's capacity probes a
@@ -55,6 +59,7 @@ from auron_tpu.ir import plan as P
 from auron_tpu.ir.expr import Expr
 from auron_tpu.ir.node import Node
 from auron_tpu.ir.schema import DataType, Field, Schema, TypeId
+from auron_tpu.ops.segments import inside_branch
 from auron_tpu.ops.sort_keys import stable_argsort
 from auron_tpu.parallel.exchange import (
     all_to_all_repartition, bounded_quota, broadcast_all_gather,
@@ -118,6 +123,27 @@ def _live_first_perm(live: Array) -> Array:
         from auron_tpu.ops.radix_sort import stable_argsort_flags
         return stable_argsort_flags(jnp.logical_not(live))
     return stable_argsort(jnp.logical_not(live))
+
+
+def _compact_front(t: DeviceTable, n_live, new_cap: int) -> DeviceTable:
+    """The live rows of `t`, in their order, as the front of a
+    `new_cap`-row table; for a table with no more than `new_cap` of
+    them (a row past it would be dropped).  A running count of the
+    live mask gives each live row its destination, one scatter of row
+    numbers gives the first `new_cap` entries of the live-first
+    permutation, and every column is gathered once by those: the cost
+    follows `new_cap`, not `t`'s capacity — no sort of the flags
+    (`_live_first_perm`).  Stable, because `first`-like states and
+    per-device limit prefixes read the rows' order."""
+    with jax.named_scope("compact"):
+        cap = t.capacity
+        dest = jnp.cumsum(t.live.astype(jnp.int32)) - 1
+        perm = jnp.zeros(new_cap, jnp.int32).at[
+            jnp.where(t.live, dest, new_cap)
+        ].set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
+        ok = jnp.arange(new_cap, dtype=jnp.int32) < n_live
+        cols = [c.gather(perm, ok) for c in t.cols]
+        return DeviceTable(t.schema, cols, ok)
 
 
 def _direct_addressable(pkeys, bkeys) -> bool:
@@ -203,10 +229,13 @@ def explain_stage(plan, conv_ctx,
     """The stage path's EXPLAIN text: the driver-side tail, then every
     operator of the stage program under the label its device time is
     filed under.  `stats` (execute_plan_spmd's) marks each K=1 join with
-    the probe it took, `direct` or `search`, and each boundary that
+    the probe it took, `direct` or `search`, each aggregate whose input
+    is larger than its output's capacity with the input it worked on,
+    `compact` or `full`, and the live rows of it, and each boundary that
     crossed devices with what it moved."""
     stats = stats or {}
     probes = stats.get("join_probes") or {}
+    aggs = stats.get("agg_inputs") or {}
     crossed = {**(stats.get("exchanges") or {}),
                **(stats.get("broadcasts") or {})}
     exchanges = getattr(conv_ctx, "exchanges", None) or {}
@@ -217,6 +246,10 @@ def explain_stage(plan, conv_ctx,
         detail = ""
         if isinstance(node, P.Agg):
             detail = f" mode={node.exec_mode}"
+            if label in aggs:
+                a = aggs[label]
+                detail += (f" input={a['input']}"
+                           f" live={a['live']} of {a['capacity']}")
         elif isinstance(node, (P.BroadcastJoin, P.HashJoin,
                                P.SortMergeJoin)):
             detail = f" type={node.join_type}"
@@ -289,7 +322,8 @@ class _StageTracer:
         self.retry_guards: List[Any] = []
         # `shrink_guards` trip when an agg's group count overflows the
         # shrunk static capacity (auron.spmd.agg.capacity.hint); the
-        # driver retries once with shrinking disabled (full capacity).
+        # driver climbs a capacity ladder (4x wider per retry, x16 at
+        # most, then shrinking off: execute_plan_spmd).
         self.shrink_guards: List[Any] = []
         # `join_guards` trip when a K-expanded join's live output
         # overflows the compaction target; the driver retries with join
@@ -300,6 +334,11 @@ class _StageTracer:
         # number of devices that took the direct-address probe — a device
         # scalar — or None where the join traced the search alone)
         self.probes: List[Tuple[str, Any]] = []
+        # one entry per aggregate whose input is larger than the capacity
+        # its output is cut to, in trace order: (its label and its input's
+        # slots over all devices, [devices that compacted the input, live
+        # input rows] — a replicated int64 device vector)
+        self.agg_inputs: List[Tuple[Dict[str, Any], Any]] = []
         # one entry per exchange or broadcast boundary that crossed
         # devices, in trace order: (what is known of it at trace time,
         # its counts — a replicated int64 device vector); a one-device
@@ -622,6 +661,26 @@ class _StageTracer:
         return part.mode if part is not None else None
 
     def _do_agg(self, n: P.Agg) -> DeviceTable:
+        """One aggregate.  Its output is cut to `new_cap` rows (the
+        capacity hint's bucket) wherever its input is larger than that,
+        and there its cost follows the table it is GIVEN, so the program
+        counts the input's live rows and chooses, per device (`lax.cond`,
+        no collective inside a branch):
+
+        - `n_live <= new_cap` — compact: the live rows are brought to the
+          front of a `new_cap`-row table (`_compact_front`) and the body
+          runs at `new_cap` rows.  An aggregate has no more groups than
+          live rows (a global one over no rows has its one identity row),
+          so this side's output fits and its guard flag is constant False;
+        - otherwise — full: the body at the input's capacity, then
+          `_shrink_front`'s cut to `new_cap` rows and its flag
+          (`n_groups > new_cap`).
+
+        Both sides hand back a `new_cap`-row table of one schema; the
+        `psum` that makes the flag the shrink guard sits after the choice.
+        With the shrink off, or an input of no more than `new_cap` rows,
+        there is neither cut nor choice: the body at the input's capacity
+        and nothing else."""
         from auron_tpu.ops.agg.exec import (
             _group_reduce_body, _group_reduce_body_hash,
         )
@@ -642,72 +701,103 @@ class _StageTracer:
                 "shape) on a multi-device mesh")
         t = self.eval_node(n.child)
         agg = self._agg_exec_meta(n, t.schema)
-        merge = n.exec_mode == "final"
-        keys = self._eval_exprs(n.grouping, t)
-        nk = len(n.grouping)
-        if merge:
-            vcols: List[List[Any]] = []
-            off = nk
-            for spec in agg.specs:
-                k = len(spec.state_fields())
-                vcols.append(t.cols[off:off + k])
-                off += k
-        else:
-            vcols = []
-            for a in n.aggs:
-                vcols.append(self._eval_exprs(a.children, t)
-                             if a.children else [])
-        out_cols, n_groups = _group_reduce_body(
-            keys, vcols, t.live, agg.specs, agg._key_orders(), merge)
-        if nk == 0 and n.exec_mode in ("final", "single"):
-            # a global agg over an empty input still emits the identity
-            # row (count=0, sum=null — the serial _empty_global_agg
-            # contract).  The clipped row-0 states are exactly the
-            # identities: count's eval_final forces validity over the
-            # zeroed data, every other agg finalizes to null.  Under a
-            # round-robin exchange every device IS a live partition, so
-            # each empty device owes its own identity row; otherwise
-            # (single exchange / partial-final) only device 0 does.
-            empty = n_groups == 0
-            if n.exec_mode == "single" and \
-                    self._admitting_exchange_mode(n) == "round_robin":
-                force = empty
-            else:
-                force = jnp.logical_and(self._axis_index() == 0, empty)
-            n_groups = jnp.where(force, 1, n_groups)
-        live = jnp.arange(t.capacity, dtype=jnp.int32) < n_groups
-        if n.exec_mode in ("final", "single"):
-            final_cols = list(out_cols[:nk])
-            off = nk
-            for spec in agg.specs:
-                k = len(spec.state_fields())
-                final_cols.append(spec.eval_final(out_cols[off:off + k]))
-                off += k
-            return self._shrink_front(
-                DeviceTable(agg.schema, final_cols, live), n_groups)
-        return self._shrink_front(
-            DeviceTable(agg._state_schema(), out_cols, live), n_groups)
+        final = n.exec_mode in ("final", "single")
+        out_schema = agg.schema if final else agg._state_schema()
 
-    def _shrink_front(self, t: DeviceTable, n_live) -> DeviceTable:
-        """Cut a front-compacted table (all live rows at indices
-        [0, n_live)) down to the static capacity hint.  Aggs are the
-        plan's cardinality reducers, but the mask-liveness model keeps
-        their INPUT capacity — so without this every downstream exchange
-        / join / sort pays input-scale cost for a handful of groups
-        (round-4 root cause of the stage path losing to serial at bench
-        scale).  Overflow (more groups than the hint) trips a
-        shrink-guard; the driver climbs a capacity ladder (4x per
-        retry, then shrink off)."""
+        def aggregate_over(t: DeviceTable) -> Tuple[DeviceTable, Array]:
+            """The aggregate over `t`, at `t`'s capacity: its groups at the
+            front of the table, and how many there are."""
+            merge = n.exec_mode == "final"
+            keys = self._eval_exprs(n.grouping, t)
+            nk = len(n.grouping)
+            if merge:
+                vcols: List[List[Any]] = []
+                off = nk
+                for spec in agg.specs:
+                    k = len(spec.state_fields())
+                    vcols.append(t.cols[off:off + k])
+                    off += k
+            else:
+                vcols = []
+                for a in n.aggs:
+                    vcols.append(self._eval_exprs(a.children, t)
+                                 if a.children else [])
+            out_cols, n_groups = _group_reduce_body(
+                keys, vcols, t.live, agg.specs, agg._key_orders(), merge)
+            if nk == 0 and final:
+                # a global agg over an empty input still emits the identity
+                # row (count=0, sum=null — the serial _empty_global_agg
+                # contract).  The clipped row-0 states are exactly the
+                # identities: count's eval_final forces validity over the
+                # zeroed data, every other agg finalizes to null.  Under a
+                # round-robin exchange every device IS a live partition, so
+                # each empty device owes its own identity row; otherwise
+                # (single exchange / partial-final) only device 0 does.
+                empty = n_groups == 0
+                if n.exec_mode == "single" and \
+                        self._admitting_exchange_mode(n) == "round_robin":
+                    force = empty
+                else:
+                    force = jnp.logical_and(self._axis_index() == 0, empty)
+                n_groups = jnp.where(force, 1, n_groups)
+            live = jnp.arange(t.capacity, dtype=jnp.int32) < n_groups
+            if final:
+                final_cols = list(out_cols[:nk])
+                off = nk
+                for spec in agg.specs:
+                    k = len(spec.state_fields())
+                    final_cols.append(
+                        spec.eval_final(out_cols[off:off + k]))
+                    off += k
+                out_cols = final_cols
+            return DeviceTable(out_schema, out_cols, live), n_groups
+
         new_cap = bucket_capacity(self.agg_cap_hint) \
             if self.agg_cap_hint > 0 else 0
         if new_cap <= 0 or new_cap >= t.capacity:
-            return t
+            # the shrink is off, or the input is no larger than what the
+            # output would be cut to: no cut, no guard, no choice
+            return aggregate_over(t)[0]
+        n_live = jnp.sum(t.live.astype(jnp.int32))
+        fits = n_live <= new_cap
+
+        def compact_side():
+            out, _n_groups = aggregate_over(
+                _compact_front(t, n_live, new_cap))
+            return out.cols, out.live, jnp.bool_(False)
+
+        def full_side():
+            out, over = self._shrink_front(*aggregate_over(t), new_cap)
+            return out.cols, out.live, over
+
+        with inside_branch():
+            cols, live, over = lax.cond(fits, compact_side, full_side)
+        self.shrink_guards.append(
+            lax.psum(over.astype(jnp.int32), self.axis) > 0)
+        # devices on the compact side and the rows they looked at, for the
+        # driver's counter
+        self.agg_inputs.append((
+            {"label": self.labels.get(id(n), n.kind),
+             "capacity": t.capacity * self.n_dev},
+            lax.psum(jnp.stack([fits.astype(jnp.int32), n_live])
+                     .astype(jnp.int64), self.axis)))
+        return DeviceTable(out_schema, cols, live)
+
+    def _shrink_front(self, t: DeviceTable, n_live,
+                      new_cap: int) -> Tuple[DeviceTable, Array]:
+        """Cut a front-compacted table (all live rows at indices
+        [0, n_live)) down to `new_cap` rows, and say whether that lost a
+        row.  Aggs are the plan's cardinality reducers, but the
+        mask-liveness model keeps their INPUT capacity — so without this
+        every downstream exchange / join / sort pays input-scale cost for
+        a handful of groups (round-4 root cause of the stage path losing
+        to serial at bench scale).  `over` (more groups than `new_cap`)
+        becomes the aggregate's shrink guard; the driver climbs a
+        capacity ladder (4x per retry, then shrink off)."""
         with jax.named_scope("compact"):
-            over = n_live > new_cap
-            self.shrink_guards.append(
-                lax.psum(over.astype(jnp.int32), self.axis) > 0)
             cols = [jax.tree.map(lambda x: x[:new_cap], c) for c in t.cols]
-            return DeviceTable(t.schema, cols, t.live[:new_cap])
+            return (DeviceTable(t.schema, cols, t.live[:new_cap]),
+                    n_live > new_cap)
 
     # joins ---------------------------------------------------------------------
 
@@ -1568,7 +1658,9 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     `stats`, when given, receives what the last attempt's program
     reported of itself (the attempt that gave the result, or the one whose
     guard tripped last): `join_probes`, {operator label: "direct" |
-    "search" | "direct k/n"} for every K=1 join; over more than one
+    "search" | "direct k/n"} for every K=1 join; `agg_inputs`, {operator
+    label: input "compact" | "full" | "compact k/n", live rows, capacity}
+    for every aggregate that chose (`_agg_input_marks`); over more than one
     device also `exchanges` and `broadcasts`, {operator label: counts} for
     every boundary (`_crossing_stats`), and `sources`, {canonical rid:
     rows, cap, rows on the fullest and the emptiest device}.
@@ -1831,6 +1923,31 @@ def probe_counts(probes: Dict[str, str]) -> Dict[str, int]:
                                       for m in probes.values())}
 
 
+def _agg_input_marks(agg_box, agg_np, n_dev: int) -> Dict[str, dict]:
+    """{operator label: {"input": "compact" | "full" | "compact k/n",
+    "live": rows, "capacity": slots}} for the aggregates of one run that
+    were traced with a choice: `agg_np` holds, per aggregate, how many of
+    the `n_dev` devices compacted its input, and the input's live rows
+    over all devices (`capacity`: its slots over all devices)."""
+    counts = iter(np.asarray(agg_np).tolist() if agg_np is not None else ())
+    marks = {}
+    for what in agg_box:
+        k, live = next(counts), next(counts)
+        marks[what["label"]] = {
+            "input": "compact" if k == n_dev else
+            "full" if k == 0 else f"compact {k}/{n_dev}",
+            "live": live, "capacity": what["capacity"]}
+    return marks
+
+
+def agg_input_counts(aggs: Dict[str, dict]) -> Dict[str, int]:
+    """The counter's two numbers: aggregates run with a choice of input,
+    and those of them whose input every device compacted."""
+    return {"agg_inputs": len(aggs),
+            "agg_inputs_compact": sum(a["input"] == "compact"
+                                      for a in aggs.values())}
+
+
 def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
     """{"exchanges": {label: ..}, "broadcasts": {label: ..}} of one run,
     from what the tracer knew of each boundary (`cross_box`) and the
@@ -1860,10 +1977,20 @@ def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
     return out
 
 
+def _reported(probe_box, direct_np, agg_box, agg_np, cross_box, crossed_np,
+              n_dev: int) -> Dict[str, Any]:
+    """What one run's program reported of itself, as execute_plan_spmd's
+    `stats` hold it."""
+    return {"join_probes": _probe_marks(probe_box, direct_np, n_dev),
+            "agg_inputs": _agg_input_marks(agg_box, agg_np, n_dev),
+            **_crossing_stats(cross_box, crossed_np)}
+
+
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
     """execute_plan_spmd's `stats` as query totals: the probe counter's
-    two numbers and the boundaries' counts."""
+    two numbers, the aggregate inputs' two and the boundaries' counts."""
     return {**probe_counts(stats.get("join_probes") or {}),
+            **agg_input_counts(stats.get("agg_inputs") or {}),
             **crossing_totals(stats)}
 
 
@@ -2062,6 +2189,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         probe_box: List[Tuple[str, bool]] = []
         # what the tracer knew of each boundary that crossed devices
         cross_box: List[Dict[str, Any]] = []
+        # and of each aggregate traced with a choice of input
+        agg_box: List[Dict[str, Any]] = []
         labels = {id(node): label
                   for _depth, node, label in operator_labels(plan, conv_ctx)}
 
@@ -2083,6 +2212,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 probe_box.extend((label, flag is not None)
                                  for label, flag in tracer.probes)
                 cross_box.extend(what for what, _n in tracer.crossings)
+                agg_box.extend(what for what, _n in tracer.agg_inputs)
             with jax.named_scope("epilogue"):
                 guards = jnp.stack(tracer.guards) if tracer.guards else \
                     jnp.zeros(0, bool)
@@ -2102,6 +2232,11 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 crossed = jnp.concatenate(
                     [n for _what, n in tracer.crossings]) \
                     if tracer.crossings else None
+                # per aggregate traced with a choice, the devices that
+                # compacted its input and its live rows; likewise
+                agg_compact = jnp.concatenate(
+                    [n for _what, n in tracer.agg_inputs]) \
+                    if tracer.agg_inputs else None
                 cols, live = out.cols, out.live
                 count = jnp.sum(live.astype(jnp.int32))[None]
                 if compact_gather:
@@ -2114,16 +2249,17 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     cols = [c.gather(perm, ok) for c in cols]
                     live = ok
             return (cols, live, count, guards, retry_guards,
-                    shrink_guards, join_guards, probe_direct, crossed)
+                    shrink_guards, join_guards, probe_direct, crossed,
+                    agg_compact)
 
         shard = jitcheck.site("spmd.stage").jit(jax.shard_map(
             program, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: PS(axis), host_inputs),),
             out_specs=(PS(axis), PS(axis), PS(axis), PS(), PS(), PS(),
-                       PS(), PS(), PS()),
+                       PS(), PS(), PS(), PS()),
             check_vma=False))
     else:
-        shard, schema_box, probe_box, cross_box = cached
+        shard, schema_box, probe_box, cross_box, agg_box = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
     # compiles the whole stage program, so the span is the compile span
@@ -2134,10 +2270,11 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             cat="spmd", devices=n_dev,
             first_launch_included=cached is None):
         (out_cols, out_live, counts, guards, retry_guards, shrink_guards,
-         join_guards, probe_direct, crossed) = shard(host_inputs)
+         join_guards, probe_direct, crossed, agg_compact) = \
+            shard(host_inputs)
     if cached is None:
         _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box,
-                                     cross_box)
+                                     cross_box, agg_box)
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
@@ -2152,12 +2289,11 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             # the stage program (`spmd.run` was its enqueue).
             with tracing.span("spmd.wait", cat="spmd") as sp:
                 (counts_np, guards_np, retry_np, shrink_np, join_np,
-                 direct_np, crossed_np) = host_sync(
+                 direct_np, crossed_np, agg_np) = host_sync(
                     (counts, guards, retry_guards, shrink_guards,
-                     join_guards, probe_direct, crossed))
-                reported = {
-                    "join_probes": _probe_marks(probe_box, direct_np, n_dev),
-                    **_crossing_stats(cross_box, crossed_np)}
+                     join_guards, probe_direct, crossed, agg_compact))
+                reported = _reported(probe_box, direct_np, agg_box, agg_np,
+                                     cross_box, crossed_np, n_dev)
                 sp.set_args(**stage_totals(reported))
         else:
             # single batched fetch (CPU: transfers are memcpy-cheap, two
@@ -2166,12 +2302,13 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             # this path records no `spmd.fetch`
             with tracing.span("spmd.wait", cat="spmd") as sp:
                 (out_live_np, out_cols_np, counts_np, guards_np, retry_np,
-                 shrink_np, join_np, direct_np, crossed_np) = host_sync(
+                 shrink_np, join_np, direct_np, crossed_np,
+                 agg_np) = host_sync(
                     (out_live, out_cols, counts, guards, retry_guards,
-                     shrink_guards, join_guards, probe_direct, crossed))
-                reported = {
-                    "join_probes": _probe_marks(probe_box, direct_np, n_dev),
-                    **_crossing_stats(cross_box, crossed_np)}
+                     shrink_guards, join_guards, probe_direct, crossed,
+                     agg_compact))
+                reported = _reported(probe_box, direct_np, agg_box, agg_np,
+                                     cross_box, crossed_np, n_dev)
                 sp.set_args(**_note_gather(counts_np, out_live_np,
                                            out_cols_np),
                             **stage_totals(reported))

@@ -342,7 +342,11 @@ def test_counters_reach_the_record_the_span_and_explain_analyze(runs):
 # `rehearse_rows`, at commit 318f50a, the parent of the PR that brought the
 # boundaries' counts (its `execute_plan_spmd`, this jax; the same on both
 # seeds: a seed draws amounts, not shapes).  A change that is meant to move
-# the one-device program takes a new digest from the tree before it.
+# the one-device program takes a new digest from the tree before it.  The
+# PR that lets an aggregate compact its input did not move it: at
+# `rehearse_rows` no table of query 7 (65,536 slots at most) is larger than
+# the capacity hint's 262,144, so no aggregate has a choice to trace; the
+# program's `agg_inputs` is there and empty.
 _ONE_DEVICE_PROGRAM = \
     "7eec828d35c42a9e22f81f724b0addd059f5d6f87afaa03c309f34f752ebad04"
 
@@ -359,7 +363,8 @@ def test_one_device_lowers_to_the_program_it_was_and_counts_nothing(runs):
     assert hashlib.sha256(text.encode()).hexdigest() == _ONE_DEVICE_PROGRAM
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
-    assert sorted(one.stage_stats) == ["join_probes"]
+    assert sorted(one.stage_stats) == ["agg_inputs", "join_probes"]
+    assert one.stage_stats["agg_inputs"] == {}
     totals = tracing.find_query(one.query_id).metric_totals
     assert not set(_TOTALS) & set(totals)
     assert not set(_TOTALS) & set(_wait_args(one))

@@ -176,3 +176,31 @@ def test_multipass_lexsort_equals_fused_lexsort():
     with conf.scoped({"auron.sort.multipass.enable": "on"}):
         multi = np.asarray(lexsort_indices_live([w0, w1], live))
     assert np.array_equal(fused, multi)
+
+
+@pytest.mark.parametrize("n", [1, 2047, 2048, 2049, 8192, 4 * 2570])
+@pytest.mark.parametrize("dtype", [np.int64, np.int32])
+def test_integer_running_sum_inside_a_branch_is_the_same_sum(n, dtype):
+    """`inside_branch` gives a 64-bit running sum its blocked form (what
+    XLA:TPU compiles inside a `lax.cond`): the same numbers, a wrap
+    included, at sizes that are and are not whole blocks; int32 keeps
+    `jnp.cumsum`; and a sorted segment sum through either is one sum."""
+    rng = np.random.default_rng(n)
+    info = np.iinfo(dtype)
+    x = rng.integers(info.min // 4, info.max // 4, n).astype(dtype)
+    x[:8] = info.max // 2                 # the running sum wraps
+    want = np.cumsum(x, dtype=dtype)
+    # a function of its own each time: jax keeps a function's trace
+    plain = jax.make_jaxpr(lambda v: segments._int_cumsum(v))(x)
+    with segments.inside_branch():
+        got = np.asarray(segments._int_cumsum(jnp.asarray(x)))
+        blocked = jax.make_jaxpr(lambda v: segments._int_cumsum(v))(x)
+    assert got.dtype == dtype and np.array_equal(got, want)
+    assert (str(blocked) != str(plain)) == (dtype is np.int64)
+    assert not getattr(segments._TRACE_MODE, "branch", 0)
+    seg = jnp.asarray(np.sort(rng.integers(0, 16, n)).astype(np.int32))
+    sums = np.asarray(segments.sorted_segment_sum(jnp.asarray(x), seg, 16))
+    with segments.inside_branch():
+        same = np.asarray(
+            segments.sorted_segment_sum(jnp.asarray(x), seg, 16))
+    assert np.array_equal(sums, same)

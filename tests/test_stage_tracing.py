@@ -159,6 +159,34 @@ def test_operator_scopes_in_the_lowered_stage_program(files):
     assert f"{join_labels[0]}/{join_labels[1]}/probe/" in text
 
 
+def test_compact_scope_under_an_aggregate_that_chose(files):
+    """On one device the 1,200 fact rows lie in 2,048 slots: with the
+    capacity hint scoped down to 1,024 the partial aggregate chooses its
+    input, and both what it may do to it — bring the live rows to the
+    front of 1,024 slots, or cut its output to them — are filed under
+    `agg#…/compact`, inside the conditional; the final aggregate, whose
+    input is those 1,024 slots, has neither."""
+    plan, ctx = build_plan(files, "cccc3333")
+    with conf.scoped({"auron.spmd.agg.capacity.hint": 1024}):
+        program, inputs = spied_program(plan, ctx, data_mesh(1), {})
+        text = program.lower(inputs).as_text(debug_info=True)
+    explained = S.explain_stage(plan, ctx)
+    partial, final = [
+        line.split()[0] for line in explained.splitlines()
+        if "mode=partial" in line or "mode=final" in line][::-1]
+    # the running count, the scatter of row numbers and the columns'
+    # gathers on the compact side (branch 1); the cut on the full side
+    for branch, op in ((1, "jit(cumsum)"), (1, "scatter"),
+                       (1, "jit(_take)"), (0, "slice")):
+        assert f"{partial}/cond/branch_{branch}_fun/compact/{op}" in text, op
+    assert f"{final}/cond/" not in text and f"{final}/compact/" not in text
+    # the body's scopes on both sides of the choice
+    for branch in ("branch_0_fun", "branch_1_fun"):
+        for scope in ("group", "reduce"):
+            assert f"{partial}/cond/{branch}/{scope}/" in text, (branch,
+                                                                 scope)
+
+
 def _traced_execute(files, uid, scope):
     plan, ctx = build_plan(files, uid)
     rec = tracing.TraceRecorder(uid, max_events=10_000)
@@ -418,6 +446,13 @@ OPS = [
     ("jit(p)/broadcast_join#1/cond/branch_0_fun/probe/while/body/gather",
      ("broadcast_join#1", "probe")),
     ("jit(p)/broadcast_join#1/cond", ("broadcast_join#1", "")),
+    # an aggregate's choice of input: the compaction, the cut, the body
+    ("jit(p)/agg#1/ipc_reader#2/agg#3/cond/branch_1_fun/compact/scatter",
+     ("agg#3", "compact")),
+    ("jit(p)/agg#1/ipc_reader#2/agg#3/cond/branch_0_fun/compact/slice",
+     ("agg#3", "compact")),
+    ("jit(p)/agg#3/cond/branch_1_fun/reduce/while/body/jit(_take)/gather",
+     ("agg#3", "reduce")),
     ("jit(p)/jit(_take)/gather", (trace_cli.UNLABELLED, "")),
     ("", (trace_cli.UNLABELLED, "")),
 ])

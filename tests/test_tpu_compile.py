@@ -276,6 +276,51 @@ def test_compact_gather_permutation(one_chip, tpu_branches,
              _shape(one_chip, SORT_CAP, jnp.bool_))
 
 
+@pytest.mark.parametrize("cap", [CAP, BUILD_CAP],
+                         ids=["sf1-capacity", "agg-capacity-hint"])
+def test_integer_segment_sum_inside_a_conditional(one_chip, tpu_branches,
+                                                  no_persistent_cache, cap):
+    """COUNT and decimal SUM on either side of an aggregate's choice of
+    input (parallel/stage.py `_do_agg`): the int64 segment sum as a
+    branch of a `lax.cond`.  With `jnp.cumsum` inside the branch XLA:TPU
+    refuses the program at 2^22 rows and does not finish it at 2^18
+    (ops/segments.py `inside_branch`); the blocked form compiles in
+    seconds."""
+    from jax import lax
+    from auron_tpu.ops.segments import inside_branch, sorted_segment_sum
+
+    def either(pick, x, seg):
+        with inside_branch():
+            return lax.cond(pick,
+                            lambda: sorted_segment_sum(x, seg, cap),
+                            lambda: x)
+    _compile(either, jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip),
+             _shape(one_chip, cap, jnp.int64),
+             _shape(one_chip, cap, jnp.int32))
+
+
+def test_live_row_compaction_at_sf1_capacity(one_chip, tpu_branches,
+                                             no_persistent_cache):
+    """An aggregate's input brought down to the capacity its output is cut
+    to (parallel/stage.py `_compact_front`): an int32 running count over
+    the fact capacity, one scatter of row numbers into `BUILD_CAP` slots
+    and one `BUILD_CAP`-index gather a column — no sort, so it can afford
+    the real capacities."""
+    from auron_tpu.ir.schema import Field, Schema
+    from auron_tpu.parallel.stage import DeviceTable, _compact_front
+
+    def compact(key, amount, live):
+        t = DeviceTable(Schema((Field("k", I64), Field("a", F64))),
+                        [key, amount], live)
+        n_live = jnp.sum(live.astype(jnp.int32))
+        out = _compact_front(t, n_live, BUILD_CAP)
+        return out.cols, out.live
+    compiled = _compile(compact, _column(one_chip, I64, CAP),
+                        _column(one_chip, F64, CAP, exact_bits=True),
+                        _shape(one_chip, CAP, jnp.bool_))
+    assert "sort" not in compiled.as_text().lower()
+
+
 # ---------------------------------------------------------------------------
 # Mosaic — the two Pallas kernels, interpret=False
 # ---------------------------------------------------------------------------
