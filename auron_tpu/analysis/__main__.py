@@ -223,7 +223,7 @@ def run_protocol(regen: bool, golden_dir: str) -> int:
 def run_compilation(regen: bool, golden_dir: str) -> int:
     """The static compilation pass (`--compilation`): raw-jit lint,
     host-materialization inside jitted bodies, mutable-capture lint,
-    the strategy-fingerprint cache-key rule, the config-knob lint, and
+    the config-knob lint, and
     (with --regen-golden) the canonical-run compile manifest."""
     from auron_tpu.analysis import compilation as comp
 
@@ -276,8 +276,7 @@ def main(argv=None) -> int:
                     help="run the static compilation-hygiene pass "
                          "instead of the plan lint (raw-jit registry "
                          "bypass, host materialization inside jitted "
-                         "bodies, mutable-capture, strategy-fingerprint "
-                         "cache keys, config-knob lint)")
+                         "bodies, mutable-capture, config-knob lint)")
     ap.add_argument("--protocol", action="store_true",
                     help="run the static wire-protocol pass instead of "
                          "the plan lint (server dispatch ladders vs the "

@@ -21,12 +21,7 @@ actually performs; this pass sees every lexical path.  It scans
    (a module global rebound more than once, or the target of a
    ``global`` statement): the closure bakes the value at trace time
    and never sees updates — the stale-compile bug class;
-4. enforces the PR 7 CACHE-KEY RULE: a ``cached_jit`` whose body
-   reaches the kernel-strategy resolvers (ops/strategy.py) at trace
-   time must carry ``strategy_fingerprint()`` — or a value derived
-   from a resolver — in its cache key, else a strategy flip reuses a
-   program traced under the old strategy;
-5. cross-checks every literal ``conf.get/set/unset``/``conf.scoped``
+4. cross-checks every literal ``conf.get/set/unset``/``conf.scoped``
    key against the registered option set and CONFIG.md — unknown keys
    (literal typos fail at runtime, on the path that reads them),
    undocumented registered knobs (stale CONFIG.md) and documented-but-
@@ -46,7 +41,7 @@ import ast
 import difflib
 import os
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Set, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 from auron_tpu.analysis.diagnostics import AnalysisResult, DiagnosticSink
 # the PR 8 resolution stoplist: generic bare names must not resolve by
@@ -59,16 +54,6 @@ PASS_ID = "compilation"
 RAW_JIT_ALLOWLIST = ("runtime/jitcheck.py",)
 
 WAIVE_COMMENT = "jitcheck: waive"
-
-# strategy resolvers whose TRACE-TIME result a kernel body can bake in:
-# any cached_jit body reaching one must fingerprint its cache key
-STRATEGY_RESOLVERS = frozenset({
-    "sort_strategy", "join_probe_strategy", "group_strategy",
-    "join_bucket_bits", "multipass_enabled", "table_bits_key",
-})
-FINGERPRINT_NAMES = frozenset({
-    "strategy_fingerprint", "_strategy_fingerprint",
-})
 
 MAX_CLOSURE_DEPTH = 8
 
@@ -149,10 +134,6 @@ class _ModuleScan:
         self.site_vars: Set[str] = set()      # names bound to site(...)
         self.module_assign_counts: Dict[str, int] = {}
         self.global_decls: Set[str] = set()
-        # cached_jit sites: (site/family, key expr, builder expr, line,
-        # enclosing scope stack)
-        self.cached_sites: List[Tuple[str, ast.AST, ast.AST, int,
-                                      Tuple[ast.AST, ...]]] = []
 
     # -- module-level mutability --------------------------------------------
 
@@ -266,8 +247,6 @@ class _ModuleScan:
             if fam is None and isinstance(key_expr, ast.Tuple) and \
                     key_expr.elts:
                 fam = _const_str(key_expr.elts[0])
-            self.cached_sites.append((fam or "?", key_expr, builder,
-                                      node.lineno, scopes))
             body = self._resolve_builder(builder, scopes)
             if body is not None:
                 self.jit_bodies.append(JitBody(
@@ -528,87 +507,6 @@ class _BodyAnalysis:
                                              seen))
         return out
 
-    def reaches_resolver(self, scan: _ModuleScan, root: ast.AST,
-                         depth: int = 0,
-                         seen: Optional[Set[int]] = None) -> bool:
-        """Does `root`'s bounded closure call a strategy resolver?
-        Unlike the materialization walk, ambiguous bare names UNION all
-        candidates: for a boolean taint, over-approximating only asks a
-        key for a fingerprint it could legitimately need (an AggSpec
-        method call must taint through every spec implementation)."""
-        if seen is None:
-            seen = set()
-        if depth > MAX_CLOSURE_DEPTH or id(root) in seen:
-            return False
-        seen.add(id(root))
-        for node in ast.walk(root):
-            if not isinstance(node, ast.Call):
-                continue
-            f = node.func
-            name = f.id if isinstance(f, ast.Name) else \
-                (f.attr if isinstance(f, ast.Attribute) else None)
-            if name in STRATEGY_RESOLVERS:
-                return True
-            if name is None or name in GENERIC_NAMES:
-                continue
-            hit = self._resolve(scan, node)
-            cands = [hit] if hit is not None else \
-                self.module_defs.get(name, [])[:8]
-            for s2, d2 in cands:
-                if self.reaches_resolver(s2, d2, depth + 1, seen):
-                    return True
-        return False
-
-
-def _key_has_fingerprint(key_expr: ast.AST,
-                         scopes: Tuple[ast.AST, ...]) -> bool:
-    """Does a cache-key expression include strategy state?  Either a
-    direct `strategy_fingerprint()` call, or a name assigned from a
-    strategy resolver / fingerprint in an enclosing scope (the
-    `b_bits`-in-key pattern: the RESOLVED value is the key element)."""
-    def _call_names(node: ast.AST) -> Iterator[str]:
-        for n in ast.walk(node):
-            if isinstance(n, ast.Call):
-                f = n.func
-                if isinstance(f, ast.Name):
-                    yield f.id
-                elif isinstance(f, ast.Attribute):
-                    yield f.attr
-
-    for name in _call_names(key_expr):
-        if name in FINGERPRINT_NAMES or name in STRATEGY_RESOLVERS:
-            return True
-    # names in the key that derive from a resolver in an enclosing scope
-    key_names = {n.id for n in ast.walk(key_expr)
-                 if isinstance(n, ast.Name)}
-    derived: Set[str] = set()
-    for scope in scopes:
-        for node in ast.walk(scope):
-            if isinstance(node, ast.Assign):
-                calls = set(_call_names(node.value))
-                if calls & (STRATEGY_RESOLVERS | FINGERPRINT_NAMES):
-                    for t in node.targets:
-                        for n in ast.walk(t):
-                            if isinstance(n, ast.Name):
-                                derived.add(n.id)
-            # `pidx.b_bits`-style: attribute reads of a strategy-built
-            # object count through the attribute's base name
-            elif isinstance(node, ast.AnnAssign) and node.value is not None:
-                calls = set(_call_names(node.value))
-                if calls & (STRATEGY_RESOLVERS | FINGERPRINT_NAMES):
-                    for n in ast.walk(node.target):
-                        if isinstance(n, ast.Name):
-                            derived.add(n.id)
-    if key_names & derived:
-        return True
-    # attribute elements (x.b_bits, x.iters) in the key: the object was
-    # built by the strategy layer (ProbeIndex) — accept attribute reads
-    # whose attr names a resolver-derived field
-    for n in ast.walk(key_expr):
-        if isinstance(n, ast.Attribute) and n.attr in ("b_bits", "iters"):
-            return True
-    return False
-
 
 # ---------------------------------------------------------------------------
 # config-knob lint
@@ -750,27 +648,7 @@ def analyze_compilation(root: Optional[str] = None,
                              "'# jitcheck: waive (<reason>)' if the "
                              "rebinding is init-only")
 
-        # 4. strategy-fingerprint cache-key rule
-        for fam, key_expr, builder, line, scopes in scan.cached_sites:
-            body = scan._resolve_builder(builder, scopes)
-            if body is None:
-                continue
-            if not bodies.reaches_resolver(scan, body):
-                continue
-            if _key_has_fingerprint(key_expr, scopes + (body,)):
-                continue
-            if _line_has_waiver(scan.src_lines, line):
-                continue
-            sink.error(
-                PASS_ID, f"{scan.rel}:{line}", None,
-                f"cached_jit key for {fam!r} misses the strategy "
-                f"fingerprint: its body reaches a kernel-strategy "
-                f"resolver at trace time, so a strategy flip would "
-                f"reuse a program traced under the old strategy",
-                hint="add strategy_fingerprint() (ops/strategy.py) — "
-                     "or the resolved value — to the key tuple")
-
-    # 5. config-knob lint
+    # 4. config-knob lint
     registered = _registered_conf_keys()
     doc_keys = _config_md_keys(repo_root)
     for scan in scans:

@@ -465,9 +465,8 @@ class PartitioningContractsPass(Pass):
 # 4. TPU lints (advisory: warnings/info, never errors)
 # ---------------------------------------------------------------------------
 
-# VPU lane count / min f32 tile, per the Pallas TPU model: tiles are
-# (8 sublanes x 128 lanes); ops/kernels_pallas.py views rows as
-# (rows/128, 128) lane blocks.
+# VPU lane count / min f32 tile of the TPU: tiles are (8 sublanes x
+# 128 lanes).
 _LANES = 128
 _MIN_TILE_ROWS = 8 * _LANES
 
@@ -557,9 +556,6 @@ class TpuLintPass(Pass):
                     f"partition ids fall back to host hashing",
                     hint="hash on a flat key (or a precomputed hash "
                          "column) to keep the exchange on device")
-        if len(dts) == 1 and dts[0].id in (TypeId.INT64,
-                                           TypeId.TIMESTAMP_US):
-            return   # single-i64 fast-path shape (ops/kernels_pallas.py)
         if any(dt.id == TypeId.FLOAT64 for dt in dts):
             sink.info(
                 self.id, path, node,

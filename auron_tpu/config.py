@@ -661,16 +661,6 @@ SPMD_SINGLE_DEVICE = conf.define(
     "same way); device-resident source caching makes repeat executes "
     "transfer nothing.",
 )
-SORT_MULTIPASS = conf.define(
-    "auron.sort.multipass.enable", "auto",
-    "Lexsort strategy for the device sort kernels (agg grouping, sort, "
-    "window, SMJ): 'auto' composes stable single-key argsort passes "
-    "everywhere except the CPU backend (the multi-operand comparator "
-    "sort XLA lowers jnp.lexsort to takes minutes to COMPILE on TPU — "
-    "measured 201s for one 3-operand 4M-row lexsort vs ~2s/pass — "
-    "while on CPU the fused comparator sort compiles fast and runs "
-    "faster); 'on'/'off' force one form.",
-)
 SMJ_WINDOW_MAX_ROWS = conf.define(
     "auron.smj.window.max.rows", 1 << 20,
     "Cap on the build rows one streaming-SMJ window may materialize on "
@@ -682,18 +672,6 @@ SMJ_WINDOW_MAX_ROWS = conf.define(
     "keys keep the normal path (they are batch-bounded by the frontier "
     "advance).  0 disables the cap.  (The role of the reference's "
     "SMJ_FALLBACK_* knobs, conf.rs.)",
-)
-SPMD_GATHER_COMPACT = conf.define(
-    "auron.spmd.gather.compact", "auto",
-    "Two-phase result gather for SPMD stage programs: the program "
-    "compacts live rows to each shard's front and the host first syncs "
-    "only per-shard COUNTS + guard bits (bytes), then fetches a "
-    "bucket_capacity(max count) slice through a tiny cached slicing "
-    "program — instead of fetching every output column at full padded "
-    "capacity (~7MB for a 4k-row result).  Guard-tripped runs skip "
-    "the output fetch entirely.  'auto' = "
-    "non-CPU backends only (CPU transfers are memcpy-cheap and the "
-    "extra dispatch would only add latency); 'on'/'off' force.",
 )
 SORT_F64_EXACTBITS = conf.define(
     "auron.sort.f64.exactbits", "auto",
@@ -769,22 +747,6 @@ FFI_INGEST_CACHE_MB = conf.define(
     "source re-upload nothing — the serial-path sibling of "
     "auron.spmd.source.cache.mb.  0 disables.",
 )
-AGG_HASH_TABLE_MAX_BITS = conf.define(
-    "auron.agg.hash.table.max.bits", 16,
-    "Cap (log2) on the hash-grouping scatter table (ops/hash_group.py, "
-    "CPU backend): 2^16 slots stay cache-resident, ~3x faster scatter "
-    "than a 2*capacity table at megarow batches; groups beyond the slot "
-    "count cost extra (cheap) probe rounds.  0 disables the cap "
-    "(table = 2*batch capacity).",
-)
-AGG_GROUPING_STRATEGY = conf.define(
-    "auron.agg.grouping.strategy", "auto",
-    "Group-id assignment inside the agg reduce kernel: 'sort' (lexsort + "
-    "boundary scan — the TPU-native form), 'hash' (linear-probed scatter "
-    "table, ops/hash_group.py — the agg_hash_map.rs analogue; CPU "
-    "backend only, ignored elsewhere), or 'auto' (hash on CPU, sort "
-    "elsewhere).",
-)
 PARTIAL_AGG_SKIPPING_ENABLE = conf.define(
     "auron.partial.agg.skipping.enable", True,
     "Skip partial aggregation when cardinality reduction is poor "
@@ -838,17 +800,6 @@ NATIVE_LIB_ENABLE = conf.define(
     "auron.native.enable", True,
     "Use the C++ host runtime (libauron_host.so) when built; pure-python "
     "fallbacks are used otherwise.",
-)
-SORTED_SEGMENTS = conf.define(
-    "auron.segments.sorted.enable", True,
-    "Reduce sorted segment ids with gather-shaped cumulative kernels "
-    "instead of XLA scatter-add (ops/segments.py); off = "
-    "jax.ops.segment_* scatter path.",
-)
-PALLAS_ENABLE = conf.define(
-    "auron.pallas.enable", True,
-    "Use Pallas TPU kernels for hot device ops (hash partition ids); "
-    "falls back to plain XLA ops off-TPU or when disabled.",
 )
 STRING_WIDTH_BUCKETS = conf.define(
     "auron.string.width.buckets", "8,16,32,64,128,256",
@@ -1278,79 +1229,6 @@ FLEET_SCALE_COOLDOWN_SECONDS = conf.define(
     "a bursty queue cannot spawn a worker storm.",
 )
 
-# -- kernel-strategy layer (ops/strategy.py) --------------------------------
-
-KERNEL_SORT_STRATEGY = conf.define(
-    "auron.kernel.sort.strategy", "auto",
-    "Device argsort family for the encoded-sort-key kernels (Sort, "
-    "Window, SMJ windows, join build, agg sort path, SPMD exchanges): "
-    "'radix' = pack-sort (row index packed into the low bits of greedily "
-    "word-packed keys, composed LSD value sorts — ops/radix_sort.py; "
-    "measured 2.4x on u64 and 5x on u32 keys vs the XLA-CPU comparator "
-    "argsort at 4M rows), 'argsort' = the legacy comparator form, "
-    "'auto' = radix on the CPU backend above "
-    "auron.kernel.sort.radix.min.rows, argsort elsewhere (no recorded "
-    "chip numbers for pack-sort yet; the bench profile times both).  "
-    "Either way the permutation is bit-identical (stable order).",
-)
-KERNEL_SORT_RADIX_MIN_ROWS = conf.define(
-    "auron.kernel.sort.radix.min.rows", 1 << 15,
-    "Capacity floor below which 'auto' keeps the legacy argsort: small "
-    "sorts sit at the dispatch floor where the pack-sort's extra "
-    "shift/mask work and pass composition buy nothing.",
-)
-KERNEL_JOIN_PROBE_STRATEGY = conf.define(
-    "auron.kernel.join.probe.strategy", "auto",
-    "Hash-join probe kernel (ops/joins/kernel.py): 'partitioned' = "
-    "bucket-partitioned probe index (high radix bits of the u64 key "
-    "hash pick a bucket; a bounded binary search over the build side's "
-    "DEDUPLICATED hashes runs only within the bucket span, with the "
-    "iteration count fixed at build time from the measured max span), "
-    "'searchsorted' = the legacy double-searchsorted range scan, "
-    "'auto' = partitioned on the CPU backend for build capacities in "
-    "[auron.kernel.join.partitioned.min.rows, ...max.rows] (measured "
-    "3.1x at a 4k build table, 1.9x at 4M, 4M probes each).",
-)
-KERNEL_JOIN_PARTITIONED_MIN_ROWS = conf.define(
-    "auron.kernel.join.partitioned.min.rows", 1 << 10,
-    "Build-capacity floor for the 'auto' partitioned probe: below it "
-    "the legacy double searchsorted is already dispatch-bound and the "
-    "index build (plus its one max-span host sync per build table) "
-    "cannot pay for itself.",
-)
-KERNEL_JOIN_PARTITIONED_MAX_ROWS = conf.define(
-    "auron.kernel.join.partitioned.max.rows", 0,
-    "Build-capacity CEILING past which 'auto' falls back to the sorted "
-    "searchsorted path (the documented high-cardinality escape).  0 = "
-    "no ceiling; the recorded CPU measurements show the partitioned "
-    "probe still winning at 4M-row builds, so the default leaves it "
-    "open.",
-)
-KERNEL_JOIN_BUCKET_BITS = conf.define(
-    "auron.kernel.join.bucket.bits", 0,
-    "Radix width (log2 bucket count) of the partitioned-probe bucket "
-    "index.  0 = auto-size from the build capacity: "
-    "clamp(log2(capacity), 16, 20) — 2^16 buckets keep dim-table spans "
-    "at 1-3 entries, 2^20 holds megarow builds to ~5 search iterations.",
-)
-KERNEL_GROUP_STRATEGY = conf.define(
-    "auron.kernel.group.strategy", "auto",
-    "Unsorted (hash-grouped) segment-reduction kernel "
-    "(ops/hash_group.py via ops/segments.py): 'onehot' = chunked "
-    "one-hot/matmul reduction (sums ride the MXU on TPU-class "
-    "backends; min/max use a chunked masked reduce), 'scatter' = "
-    "jax.ops.segment_* scatter kernels, 'auto' = onehot only on "
-    "TPU-class backends AND only for static segment counts <= "
-    "auron.kernel.group.onehot.max.segments; on CPU the scatter floor "
-    "WINS and auto keeps it (measured 4M rows: G=64 scatter 158ms vs "
-    "onehot 225ms, G=256 155ms vs 831ms).",
-)
-KERNEL_GROUP_ONEHOT_MAX_SEGMENTS = conf.define(
-    "auron.kernel.group.onehot.max.segments", 1 << 10,
-    "Static segment-count ceiling for the one-hot group reduction: the "
-    "one-hot expansion costs n*G multiply-accumulates, so it is a "
-    "LOW-cardinality strategy by construction.",
-)
 LOCKCHECK_ENABLE = conf.define(
     "auron.lockcheck.enable", False,
     "Dynamic concurrency checking (runtime/lockcheck.py): every lock "
@@ -1445,23 +1323,6 @@ WIRE_PROTO_VERSION = conf.define(
     "structured refusal frame; minor drift is compatible by the "
     "fix-forward rule.",
 )
-KERNEL_COST_PROFILE_PATH = conf.define(
-    "auron.kernel.cost.profile.path", "",
-    "Path to a recorded kernel-profile artifact (a BENCH_r0x.json, a "
-    "raw worker-profile dict, or a perfscope.export_profile() export) "
-    "that seeds the strategy cost model (ops/strategy.py "
-    "KernelCostModel).  Empty = the embedded BENCH_r05 CPU numbers.",
-)
-KERNEL_COST_CALIBRATE = conf.define(
-    "auron.kernel.cost.calibrate", False,
-    "Resolve the strategy cost model from THIS process's live perfscope "
-    "ledgers (runtime/perfscope.py live_profile()) instead of the "
-    "embedded seed numbers: with auron.perf.enable on, kernels measured "
-    "during earlier queries re-price auto-resolution for later ones on "
-    "this machine's observed bandwidths.  Sites with no samples yet "
-    "fall through to auron.kernel.cost.profile.path / the seed, so a "
-    "cold process behaves exactly as before.",
-)
 PERF_ENABLE = conf.define(
     "auron.perf.enable", False,
     "Arm perfscope: every jitcheck-registered jit site records wall "
@@ -1519,13 +1380,6 @@ PERF_PEAK_PATH = conf.define(
     "Cache file for the CPU's measured machine-peak verdict (JSON keyed "
     "by platform).  Empty = <repo>/.jax_cache/perf_peak.json.",
 )
-PERF_EXPORT_PATH = conf.define(
-    "auron.perf.export.path", "",
-    "Default path for perfscope.export_profile(): the live per-site "
-    "ledgers rendered in kernel_profile_ms schema, valid as "
-    "auron.kernel.cost.profile.path input for a later process.  Empty "
-    "= export_profile() requires an explicit path argument.",
-)
 STATS_STORE_DIR = conf.define(
     "auron.stats.store.dir", "",
     "Arm the durable per-plan-signature statistics store "
@@ -1535,7 +1389,7 @@ STATS_STORE_DIR = conf.define(
     "profile fold into an append-only crash-safe JSONL file under this "
     "directory; on startup the store seeds MemForecaster admission "
     "forecasts, the CostModel's per-(signature, exchange) history (the "
-    "learned-initial-plan feed) and auron.kernel.cost.calibrate.  "
+    "learned-initial-plan feed) and the perfscope ledger.  "
     "Empty (default) = OFF, terminal path bit-identical.  In a fleet "
     "the DRIVER owns the store (worker records ship over harvest; "
     "worker processes never write it).",

@@ -113,6 +113,7 @@ def f64_bits_u32_pair(v):
     """
     import jax
     import jax.lax as lax
+    # by backend because it is a capability, not an alternative
     if jax.default_backend() == "cpu" or jax.default_backend() == "gpu":
         pair = lax.bitcast_convert_type(v.astype(jnp.float64), jnp.uint32)
         return pair[..., 0], pair[..., 1]

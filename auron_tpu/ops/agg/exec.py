@@ -63,7 +63,7 @@ jitcheck.waive_retraces(
     "keyed per spec struct: state capacities vary per merge")
 jitcheck.waive_retraces(
     "agg.group_reduce", 0,
-    "keyed per spec struct/orders/strategy: input capacities vary "
+    "keyed per spec struct/orders: input capacities vary "
     "across staged-merge truncation rungs")
 
 
@@ -137,7 +137,6 @@ class AggExec(Operator, MemConsumer):
 
         # device accumulator: staged grouped entries (cols, n_dev, cap)
         self._staged: List[Tuple[List[Any], Any, int]] = []
-        self._staged_unsorted = False          # any hash-grouped entries
         self._acc_rows = 0                     # host estimate after compaction
         self._host_groups: Dict = {}           # host path accumulator
         self._spills = SpillManager("agg")
@@ -195,74 +194,44 @@ class AggExec(Operator, MemConsumer):
             fields.extend(spec.state_fields())
         return Schema(tuple(fields))
 
-    def _grouping_strategy(self) -> str:
-        """sort | hash; 'auto' resolves to hash on the CPU backend (XLA's
-        comparator sort is ~3x numpy there; scatter/gather are fast) and
-        sort elsewhere.  hash is CPU-ONLY even when set explicitly: on
-        TPU scatters serialize, and the hash dispatch fuses every spec's
-        merge reduction into one kernel — the exact shape that SIGSEGVs
-        the libtpu AOT compiler (see _reduce)."""
-        import jax
-        if jax.default_backend() != "cpu":
-            return "sort"
-        s = str(conf.get("auron.agg.grouping.strategy"))
-        return "hash" if s in ("auto", "hash") else "sort"
-
-    def _reduce_kernel(self, merge: bool, strategy: str = "sort"):
-        """One cached jitted kernel: group (sort- or hash-based) +
-        segment-reduce; takes an explicit live mask so callers never sync
-        (the n_groups output stays on device)."""
+    def _reduce_kernel(self, merge: bool):
+        """One cached jitted kernel: group (sort) + segment-reduce; takes
+        an explicit live mask so callers never sync (the n_groups output
+        stays on device)."""
         from auron_tpu.ops.kernel_cache import cached_jit
         specs, orders = self.specs, self._key_orders()
         nk = len(self.grouping)
-        from auron_tpu.ops.sort_keys import multipass_enabled
-        from auron_tpu.ops.hash_group import table_bits_key
-        from auron_tpu.ops.strategy import strategy_fingerprint
         key = ("agg.group_reduce", self._spec_struct_key(), orders, merge,
-               nk, strategy,
-               # trace-time config the bodies read: a flag flip must not
-               # reuse a kernel traced under the old lexsort form / hash
-               # table size / kernel strategy
-               multipass_enabled(), table_bits_key(),
-               strategy_fingerprint())
+               nk)
 
         def build():
-            body = _group_reduce_body_hash if strategy == "hash" \
-                else _group_reduce_body
-
             def run(keys, value_cols, live):
-                return body(keys, value_cols, live, specs, orders, merge)
+                return _group_reduce_body(keys, value_cols, live, specs,
+                                          orders, merge)
             return run
         return cached_jit(key, build)
 
-    def _fused_update_kernel(self, capacity: int, sig, strategy: str):
+    def _fused_update_kernel(self, capacity: int, sig):
         """The prologue-fusion kernel: fragment stages + key/value
         evaluation + group-reduce in ONE cached jitted program (the
         partial-agg key-encode/update prologue fusion)."""
         from auron_tpu.exprs.compiler import EvalCtx, evaluate
         from auron_tpu.ops.kernel_cache import cached_jit
-        from auron_tpu.ops.sort_keys import multipass_enabled
         frag = self._fused_prologue
         specs, orders = self.specs, self._key_orders()
         grouping = self.grouping
         flat_inputs = self._flat_agg_inputs
         slices = self._agg_arg_slices
         out_schema = frag.schema
-        from auron_tpu.ops.hash_group import table_bits_key
-        from auron_tpu.ops.strategy import strategy_fingerprint
         key = ("agg.fused_update", frag.struct_key(),
                self._key_eval._structural_key(),
                None if self._val_eval is None
                else self._val_eval._structural_key(),
-               self._spec_struct_key(), orders, strategy,
-               multipass_enabled(), table_bits_key(), capacity, sig,
-               frag._conf_key(), strategy_fingerprint())
+               self._spec_struct_key(), orders, capacity, sig,
+               frag._conf_key())
         apply = frag.body_applier()
 
         def build():
-            body = _group_reduce_body_hash if strategy == "hash" \
-                else _group_reduce_body
-
             def run(cols, num_rows, pid):
                 frag_cols, live = apply(cols, num_rows, pid)
                 ectx = EvalCtx(cols=frag_cols, schema=out_schema,
@@ -271,46 +240,33 @@ class AggExec(Operator, MemConsumer):
                 keys = [evaluate(g, ectx) for g in grouping]
                 flat = [evaluate(v, ectx) for v in flat_inputs]
                 vcols = [flat[s:e] for s, e in slices]
-                return body(keys, vcols, live, specs, orders, False)
+                return _group_reduce_body(keys, vcols, live, specs,
+                                          orders, False)
             return run
         return cached_jit(key, build)
 
     def _reduce(self, keys: List[Any], vcols: List[List[Any]], live,
-                merge: bool, force_sort: bool = False):
+                merge: bool):
         """Dispatch a group reduction.  The update path is one fused
         kernel; the MERGE path splits into a shared sort-base kernel plus
         one kernel per agg spec: fusing two specs' merge reductions into a
         single program SIGSEGVs the current libtpu AOT compiler (observed
         on v5e; each piece compiles fine in isolation), and the split is
-        behaviorally identical with only extra async dispatches.
-
-        force_sort callers (spill runs, the merge-carry loop) depend on
-        key-sorted group output; everything else may take the hash path.
-        """
+        behaviorally identical with only extra async dispatches.  Group
+        output is key-sorted (spill runs and the merge-carry loop depend
+        on it)."""
         from auron_tpu.ops.kernel_cache import cached_jit
-        if not force_sort and self._grouping_strategy() == "hash":
-            # hash grouping is CPU-only, where the fused multi-spec merge
-            # kernel is safe (the SIGSEGV above is a libtpu AOT issue)
-            return self._reduce_kernel(merge, "hash")(keys, vcols, live)
         if not merge or len(self.specs) <= 1:
             return self._reduce_kernel(merge)(keys, vcols, live)
         orders = self._key_orders()
         nk = len(self.grouping)
-        from auron_tpu.ops.sort_keys import multipass_enabled
-        from auron_tpu.ops.strategy import strategy_fingerprint
-        base = cached_jit(("agg.sort_base", orders, nk, multipass_enabled(),
-                           strategy_fingerprint()),
+        base = cached_jit(("agg.sort_base", orders, nk),
                           lambda: _sort_base_builder(orders))
         perm, seg, n_groups, key_out = base(keys, live)
         out_cols: List[Any] = list(key_out)
         for spec, skey, cols in zip(self.specs, self._spec_struct_key(),
                                     vcols):
-            # the spec bodies reach the segment/group strategy layer at
-            # trace time (found by the static --compilation pass): the
-            # fingerprint keeps a strategy flip from reusing a program
-            # traced under the old kernel family
-            k = cached_jit(("agg.spec_merge", skey,
-                            strategy_fingerprint()),
+            k = cached_jit(("agg.spec_merge", skey),
                            lambda spec=spec: _spec_merge_builder(spec))
             out_cols.extend(k(cols, perm, seg, n_groups))
         return out_cols, n_groups
@@ -336,8 +292,7 @@ class AggExec(Operator, MemConsumer):
                 k = len(spec.state_fields())
                 vcols.append(states[off:off + k])
                 off += k
-            return self._reduce(keys, vcols, live, merge=True,
-                                force_sort=True)
+            return self._reduce(keys, vcols, live, merge=True)
         return run
 
     def _group_reduce(self, keys: List[Any], value_cols: List[List[Any]],
@@ -358,15 +313,8 @@ class AggExec(Operator, MemConsumer):
     # round trips per batch ~ 1/fanin — the design answer to the
     # per-batch-sync problem (VERDICT round 1, weak #2).
 
-    def _stage(self, cols: List[Any], n_dev, capacity: int,
-               unsorted: bool = False) -> None:
+    def _stage(self, cols: List[Any], n_dev, capacity: int) -> None:
         self._staged.append((cols, n_dev, capacity))
-        if unsorted:
-            # hash-grouped entries are first-winner ordered; spill files
-            # and the merge-carry loop need key-sorted runs, so the next
-            # _compact_staged must run the (sorting) merge kernel even if
-            # only one entry is staged
-            self._staged_unsorted = True
         # start the group count's device->host copy NOW (non-blocking):
         # by merge time the value is host-resident, so the one batched
         # count fetch in _compact_staged costs no extra round trip
@@ -398,7 +346,7 @@ class AggExec(Operator, MemConsumer):
         from auron_tpu.ops.kernel_cache import cached_jit, host_sync
         if not self._staged:
             return
-        if len(self._staged) == 1 and not self._staged_unsorted:
+        if len(self._staged) == 1:
             # nothing to merge, but callers (skip check, emission) rely on
             # _acc_rows reflecting the staged entry's true group count
             cols, n, cap = self._staged[0]
@@ -437,7 +385,6 @@ class AggExec(Operator, MemConsumer):
                                 static_argnames=("out_cap",))
             out_cols = kernel(out_cols, out_cap=out_cap)
         self._staged = [(list(out_cols), n, out_cap)]
-        self._staged_unsorted = False    # the merge kernel key-sorts
         self._acc_rows = n
         self.update_mem_used(self._staged_mem_bytes())
 
@@ -594,8 +541,7 @@ class AggExec(Operator, MemConsumer):
         """The plain (unfused) device update for one batch."""
         keys, vcols = self._eval_vcols(b, ctx, False)
         out_cols, n_dev = self._reduce(keys, vcols, b.row_mask(), False)
-        self._stage(out_cols, n_dev, b.capacity,
-                    unsorted=self._grouping_strategy() == "hash")
+        self._stage(out_cols, n_dev, b.capacity)
 
     def _execute_fused(self, ctx: TaskContext) -> Iterator[Batch]:
         """Prologue-fusion input loop: pull the fragment's RAW input
@@ -604,7 +550,6 @@ class AggExec(Operator, MemConsumer):
         path into the normal update (same results, no fusion win)."""
         import numpy as np_
         frag = self._fused_prologue
-        strategy = self._grouping_strategy()
         for b in frag.child_stream(ctx):
             if b.num_rows_known and b.num_rows == 0:
                 continue
@@ -621,13 +566,11 @@ class AggExec(Operator, MemConsumer):
                         continue
                     self._update_device_batch(fb, ctx)
                 continue
-            kernel = self._fused_update_kernel(b.capacity, frag._sig(b),
-                                               strategy)
+            kernel = self._fused_update_kernel(b.capacity, frag._sig(b))
             out_cols, n_dev = kernel(b.columns, b.num_rows_dev(),
                                      np_.int32(ctx.partition_id))
             frag.metrics.add("fused_batches", 1)
-            self._stage(out_cols, n_dev, b.capacity,
-                        unsorted=strategy == "hash")
+            self._stage(out_cols, n_dev, b.capacity)
         yield from self._emit_tail()
 
     def _execute_inner(self, ctx: TaskContext) -> Iterator[Batch]:
@@ -654,8 +597,7 @@ class AggExec(Operator, MemConsumer):
             keys, vcols = self._eval_vcols(b, ctx, merge_input)
             out_cols, n_dev = self._reduce(keys, vcols, b.row_mask(),
                                            merge_input)
-            self._stage(out_cols, n_dev, b.capacity,
-                        unsorted=self._grouping_strategy() == "hash")
+            self._stage(out_cols, n_dev, b.capacity)
             # partial-agg skipping (agg_ctx.rs:63-66)
             if self.supports_partial_skipping and \
                     self._input_rows >= int(conf.get(
@@ -765,7 +707,7 @@ class AggExec(Operator, MemConsumer):
                 vcols.append(states[off:off + k])
                 off += k
             out_cols, n_dev = self._reduce(keys, vcols, mb.row_mask(),
-                                           merge=True, force_sort=True)
+                                           merge=True)
             cap = mb.capacity
             if carry is not None:
                 out_cols, n_dev = self._merge_staged_kernel()(
@@ -831,13 +773,11 @@ def _group_reduce_body(keys: List[Any], value_cols: List[List[Any]],
     Returns (out_cols, n_groups) with n_groups a device scalar.  The two
     steps carry named scopes (`group`, `reduce`): a device profile files
     the sort and the segment arithmetic apart (auron_tpu.trace device)."""
-    from auron_tpu.ops.sort_keys import encode_sort_keys_bits
     capacity = live.shape[0]
     with jax.named_scope("group"):
         n_live = jnp.sum(live.astype(jnp.int32))
         words = encode_sort_keys(keys, orders)
-        perm = lexsort_indices_live(words, live,
-                                    encode_sort_keys_bits(keys))
+        perm = lexsort_indices_live(words, live)
         slive = jnp.arange(capacity, dtype=jnp.int32) < n_live
         sorted_words = [jnp.take(w, perm) for w in words]
         if sorted_words:
@@ -878,47 +818,14 @@ def _group_reduce_body(keys: List[Any], value_cols: List[List[Any]],
     return out_cols, n_groups
 
 
-def _group_reduce_body_hash(keys: List[Any], value_cols: List[List[Any]],
-                            live, specs, orders, merge: bool):
-    """Hash-table group reduction (ops/hash_group.py): same output
-    structure as `_group_reduce_body` but groups arrive in first-winner
-    row order, NOT key order — callers needing sorted runs must use the
-    sort body.  Value columns reduce in original row order via unsorted
-    (scatter) segment kernels."""
-    from auron_tpu.ops import segments
-    from auron_tpu.ops.hash_group import hash_group_structure
-    capacity = live.shape[0]
-    with jax.named_scope("group"):
-        words = encode_sort_keys(keys, orders)
-        if words:
-            seg, key_src, n_groups = hash_group_structure(words, live)
-        else:
-            first = jnp.argmax(live).astype(jnp.int32)
-            n_groups = jnp.any(live).astype(jnp.int32)
-            seg = jnp.where(live, 0, max(capacity - 1, 0)).astype(jnp.int32)
-            key_src = jnp.zeros(capacity, jnp.int32).at[0].set(first)
-        g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
-        out_cols: List[Any] = [k.gather(key_src, g_valid) for k in keys]
-    with jax.named_scope("reduce"), segments.unsorted_segments():
-        for spec, cols in zip(specs, value_cols):
-            if merge:
-                states = spec.merge_segments(cols, seg, capacity)
-            else:
-                states = spec.update_segments(cols, seg, capacity)
-            out_cols.extend(_clip_states(states, n_groups))
-    return out_cols, n_groups
-
-
 def _sort_base_builder(orders):
     """Shared half of the split merge reduction: sort + segment structure
     + key gather (no per-spec state math)."""
     def run(keys, live):
-        from auron_tpu.ops.sort_keys import encode_sort_keys_bits
         capacity = live.shape[0]
         n_live = jnp.sum(live.astype(jnp.int32))
         words = encode_sort_keys(keys, orders)
-        perm = lexsort_indices_live(words, live,
-                                    encode_sort_keys_bits(keys))
+        perm = lexsort_indices_live(words, live)
         slive = jnp.arange(capacity, dtype=jnp.int32) < n_live
         sorted_words = [jnp.take(w, perm) for w in words]
         if sorted_words:

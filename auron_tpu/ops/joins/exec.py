@@ -23,10 +23,9 @@ from auron_tpu.ir.schema import DataType, Field, Schema
 from auron_tpu.memmgr import MemConsumer, SpillManager
 from auron_tpu.ops.base import Operator, TaskContext, batch_size, compact_indices
 from auron_tpu.ops.joins.kernel import (
-    BuildTable, _build_pair_kernel, _build_range_kernel,
-    _build_range_kernel_partitioned, combine_sides, expand_pairs,
-    join_key_hash, null_columns_like, probe_ranges,
-    probe_ranges_partitioned, verify_pairs,
+    BuildTable, _build_pair_kernel, _build_range_kernel, combine_sides,
+    expand_pairs, join_key_hash, null_columns_like, probe_ranges,
+    verify_pairs,
 )
 
 _PAIR_SIDES = {"inner", "left", "right", "full"}
@@ -187,19 +186,9 @@ class _HashJoinBase(Operator):
                                            side_kind, is_final),
                 static_argnames=("chunk_cap",))
 
-        if table.probe is not None:
-            pidx = table.probe
-            range_k = cached_jit(
-                ("join.range.part", pidx.b_bits, pidx.iters),
-                lambda: _build_range_kernel_partitioned(pidx.b_bits,
-                                                        pidx.iters))
-            lo, counts, total_dev = range_k(
-                pkeys, pidx.uvals, pidx.ustart, pidx.ucnt,
-                pidx.bucket_start, b.num_rows_dev())
-        else:
-            range_k = cached_jit("join.range", _build_range_kernel)
-            lo, counts, total_dev = range_k(pkeys, table.sorted_hashes,
-                                            b.num_rows_dev())
+        range_k = cached_jit("join.range", _build_range_kernel)
+        lo, counts, total_dev = range_k(pkeys, table.sorted_hashes,
+                                        b.num_rows_dev())
         probe_matched = jnp.zeros(b.capacity, bool)
 
         def run_chunk(start: int, is_final: bool):
@@ -261,12 +250,8 @@ class _HashJoinBase(Operator):
         jt = self.join_type
         emit_pairs = jt in _PAIR_SIDES
         ph, pvalid = join_key_hash(pkeys, b.capacity)
-        if table.probe is not None:
-            lo, counts = probe_ranges_partitioned(table.probe, ph, pvalid,
-                                                  b.row_mask())
-        else:
-            lo, counts = probe_ranges(table.sorted_hashes, ph, pvalid,
-                                      b.row_mask())
+        lo, counts = probe_ranges(table.sorted_hashes, ph, pvalid,
+                                  b.row_mask())
         total = int(jnp.sum(counts))
         probe_matched = jnp.zeros(b.capacity, bool)
         chunk_cap = bucket_capacity(min(max(total, 1), batch_size()))

@@ -32,9 +32,8 @@ _FAMILY_BUILDS: Dict[str, int] = {}
 
 def _family(key: Hashable) -> str:
     """Kernel family = the leading string of a structured cache key
-    ("agg.group_reduce", "join.range.part", ...) — the unit the strategy
-    layer swaps implementations at, and the granularity kernel_check and
-    cache_info report builds by."""
+    ("agg.group_reduce", "join.range", ...) — the granularity
+    family_builds reports builds by."""
     if isinstance(key, tuple) and key and isinstance(key[0], str):
         return key[0]
     return str(key)
@@ -86,9 +85,7 @@ def cache_info() -> Dict[str, int]:
 
 
 def family_builds() -> Dict[str, int]:
-    """Cumulative kernel BUILDS by family — how a strategy flip shows up
-    in the cache (e.g. both a "join.range" and a "join.range.part" build
-    in one process means both probe strategies ran).  Copy, not view."""
+    """Cumulative kernel BUILDS by family.  Copy, not view."""
     return dict(_FAMILY_BUILDS)
 
 

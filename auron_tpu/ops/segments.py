@@ -25,33 +25,12 @@ jax.ops.segment_*.  Behavior matches jax.ops.segment_{sum,min,max}
 
 from __future__ import annotations
 
+import threading
+
 import jax
 import jax.numpy as jnp
 
-from auron_tpu.config import conf
-
-
-import threading
-
 _TRACE_MODE = threading.local()
-
-
-class unsorted_segments:
-    """Trace-time context: segment ids are NOT ascending (hash-grouped
-    reduction, ops/hash_group.py) — route to jax.ops.segment_* scatter
-    kernels instead of the sorted gather-shaped forms.  Thread-local so a
-    concurrent task tracing a sorted kernel on another thread cannot be
-    poisoned into caching the scatter form."""
-
-    def __enter__(self):
-        _TRACE_MODE.unsorted = getattr(_TRACE_MODE, "unsorted", 0) + 1
-
-    def __exit__(self, *exc):
-        _TRACE_MODE.unsorted -= 1
-
-
-def _unsorted_mode() -> int:
-    return getattr(_TRACE_MODE, "unsorted", 0)
 
 
 class inside_branch:
@@ -67,8 +46,8 @@ class inside_branch:
     [n / 2048, 2048] view, then the same over the rows' totals, down to
     one block of 2,048 — it compiles in 8-9 s at both sizes, so that is
     the form `_int_cumsum` takes here, and only here: outside a branch
-    the programs are what they were.  Thread-local like
-    `unsorted_segments`."""
+    the programs are what they were.  Thread-local, so a task tracing
+    on another thread is not marked."""
 
     def __enter__(self):
         _TRACE_MODE.branch = getattr(_TRACE_MODE, "branch", 0) + 1
@@ -102,10 +81,6 @@ def _int_cumsum(x):
     return (rows + before[:, None]).reshape(-1)[:n]
 
 
-def _use_sorted() -> bool:
-    return bool(conf.get("auron.segments.sorted.enable"))
-
-
 def _segment_ranges(seg, num_segments: int):
     sids = jnp.arange(num_segments, dtype=seg.dtype)
     starts = jnp.searchsorted(seg, sids, side="left")
@@ -118,20 +93,6 @@ def sorted_segment_sum(x, seg, num_segments: int):
     jax.ops.segment_sum(x, seg, num_segments))."""
     if x.shape[0] == 0:
         return jnp.zeros((num_segments,), x.dtype)
-    if _unsorted_mode():
-        # kernel-strategy dispatch (auron.kernel.group.strategy): the
-        # one-hot/matmul reduction replaces the scatter for small STATIC
-        # segment counts on TPU-class backends (ops/hash_group.py);
-        # trace-time read — jitted callers carry strategy_fingerprint()
-        # in their cache keys
-        from auron_tpu.ops.strategy import group_strategy
-        if group_strategy(num_segments) == "onehot":
-            from auron_tpu.ops.hash_group import onehot_segment_sum
-            return onehot_segment_sum(x, seg, num_segments)
-        return jax.ops.segment_sum(x, seg, num_segments=num_segments)
-    if not _use_sorted():
-        return jax.ops.segment_sum(x, seg, num_segments=num_segments,
-                                   indices_are_sorted=True)
     starts, ends, nonempty = _segment_ranges(seg, num_segments)
     if jnp.issubdtype(x.dtype, jnp.floating):
         # floats must NOT use the global-cumsum difference: an all-zero
@@ -209,16 +170,6 @@ def _sorted_segment_extreme(x, seg, num_segments: int, op_is_min: bool):
     fill = _extreme_identity(x.dtype, op_is_min)
     if x.shape[0] == 0:
         return jnp.full((num_segments,), fill, x.dtype)
-    if _unsorted_mode():
-        from auron_tpu.ops.strategy import group_strategy
-        if group_strategy(num_segments) == "onehot":
-            from auron_tpu.ops.hash_group import onehot_segment_extreme
-            return onehot_segment_extreme(x, seg, num_segments, op_is_min)
-        f = jax.ops.segment_min if op_is_min else jax.ops.segment_max
-        return f(x, seg, num_segments=num_segments)
-    if not _use_sorted():
-        f = jax.ops.segment_min if op_is_min else jax.ops.segment_max
-        return f(x, seg, num_segments=num_segments, indices_are_sorted=True)
     is_first = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
     run = segmented_running(x, is_first, op_is_min)
     starts, ends, nonempty = _segment_ranges(seg, num_segments)

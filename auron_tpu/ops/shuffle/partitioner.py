@@ -51,10 +51,7 @@ class PartitionIdComputer:
             ids = (jnp.arange(cap, dtype=jnp.int64) + row_start) % self.n
             return ids.astype(jnp.int32)
         if self.mode == "hash":
-            # plain XLA murmur3+pmod: the round-2 Pallas hash-pid kernel
-            # measured 2.3x SLOWER than this fused elementwise chain on a
-            # real TPU chip (BENCH_r03 kernel profile: 0.061ms pallas vs
-            # 0.027ms xla at 4M rows) and was removed by that verdict
+            # plain XLA murmur3+pmod: one fused elementwise chain
             keys = self._key_eval(batch, partition_id=partition_id)
             h = H.hash_columns(keys, seed=42, capacity=cap)
             return H.pmod(h, self.n)

@@ -24,7 +24,7 @@ from auron_tpu.ir.schema import Schema
 from auron_tpu.memmgr import MemConsumer, SpillManager
 from auron_tpu.ops.base import Operator, TaskContext, batch_size
 from auron_tpu.ops.sort_keys import (
-    encode_sort_keys, encode_sort_keys_bits, lexsort_indices,
+    encode_sort_keys, lexsort_indices,
 )
 
 NUM_MAX_MERGING_BATCHES = 16  # mirror of sort_exec.rs multi-level merge cap
@@ -69,8 +69,7 @@ class SortExec(Operator, MemConsumer):
             out = self._sort_batch_host(b)
         else:
             words = encode_sort_keys(key_cols, self._orders)
-            perm = lexsort_indices(words, b.num_rows, b.capacity,
-                                   encode_sort_keys_bits(key_cols))
+            perm = lexsort_indices(words, b.num_rows, b.capacity)
             out = b.gather(perm, b.num_rows)
         if self.fetch_limit is not None:
             out = out.head(self.fetch_offset + self.fetch_limit)
@@ -218,12 +217,7 @@ class HostKeyMerger:
             sorted_keys = all_keys[order]
             # safe prefix: rows <= bound, unless no run has data left.
             # This host-side searchsorted compares HOST-encoded words
-            # against each other only; it is agnostic to which device
-            # kernel (comparator argsort or radix pack-sort —
-            # auron.kernel.sort.strategy) produced the spilled runs,
-            # because both emit the identical stable permutation.
-            # tests/test_kernel_strategies.py::test_sort_spill_merge_*
-            # pins that invariant.
+            # against each other only.
             if all(h is None for h in heads):
                 safe = len(order)
             else:

@@ -5,7 +5,8 @@ sort").
 Each sort key column is transformed into one or more uint64 device vectors
 whose unsigned lexicographic order equals the SQL ordering (asc/desc,
 nulls_first, Spark NaN-greatest, decimal scales, string bytes).  Multi-key
-ordering = jnp.lexsort over the concatenated vector list.  The same encoding
+ordering = composed stable argsorts over the concatenated vector list
+(`_multipass_lexsort`), on every backend.  The same encoding
 drives Sort, SortMergeJoin, Window partitioning and sort-based Agg grouping.
 
 Numeric trick: IEEE doubles order correctly as unsigned ints after
@@ -17,7 +18,7 @@ a final tiebreaker word.
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, List, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -44,6 +45,8 @@ def _orderable_u64_from_f64(v):
     on TPU."""
     from auron_tpu.exprs.hashing import f64_bits_u32_pair
     import jax
+    # by backend because it is a capability, not an alternative: XLA:TPU
+    # demotes f64, so the f32 bits are all there is to order by there
     if jax.default_backend() not in ("cpu", "gpu"):
         return _orderable_u64_from_f32(v.astype(jnp.float32))
     lo, hi = f64_bits_u32_pair(v)
@@ -73,6 +76,8 @@ def f64_exact_bits_enabled() -> bool:
         return True
     if mode == "off":
         return False
+    # by backend because it is a capability: the sidecar exists for the
+    # backends that demote f64; the others hold the exact value already
     return _jax.default_backend() not in ("cpu", "gpu")
 
 
@@ -121,6 +126,8 @@ def f64_bits_of_column(col):
     if getattr(col, "bits", None) is not None:
         return col.bits
     data = col.data
+    # by backend because it is a capability: XLA:TPU has no 64-bit
+    # bitcast; where there is one it is the lossless way
     if jax.default_backend() in ("cpu", "gpu"):
         pair = lax.bitcast_convert_type(data.astype(jnp.float64), jnp.uint32)
         return (pair[..., 1].astype(jnp.uint64) << 32) | \
@@ -202,29 +209,6 @@ def encode_key_column(col, asc: bool = True, nulls_first: bool = True
     return [null_rank] + words
 
 
-def encode_key_column_bits(col) -> List[int]:
-    """Meaningful bit width of each word `encode_key_column` emits for
-    this column (of the UNFLIPPED value set — descending ~ keeps the
-    claim valid under masking).  Tighter-than-dtype claims (null-rank and
-    bool words are 1 bit) let the radix pack-sort fuse several words into
-    one value-sort pass; claiming the full dtype width is always safe,
-    just slower.  MUST stay in lockstep with encode_key_column."""
-    if isinstance(col, DeviceStringColumn):
-        words = [64] * ((col.width + 7) // 8) + [32]
-    else:
-        tid = col.dtype.id
-        if tid == TypeId.BOOL:
-            words = [1]
-        elif tid in _NARROW_INTS:
-            words = [32]
-        else:
-            # FLOAT32's u64 word only populates the high half, but its
-            # meaningful bits are the HIGH ones — the claim contract is
-            # low-bit-meaningful, so it declares the full 64
-            words = [64]
-    return [1] + words  # leading null-rank word
-
-
 def encode_sort_keys(cols: Sequence[Any],
                      orders: Sequence[Tuple[bool, bool]]) -> List[Any]:
     """cols+(asc, nulls_first) list -> u64 word list, most-significant
@@ -235,35 +219,11 @@ def encode_sort_keys(cols: Sequence[Any],
     return words
 
 
-def encode_sort_keys_bits(cols: Sequence[Any]) -> List[int]:
-    """Bit widths parallel to encode_sort_keys' word list."""
-    bits: List[int] = []
-    for col in cols:
-        bits.extend(encode_key_column_bits(col))
-    return bits
-
-
-def lexsort_indices(words: List[Any], num_rows, capacity: int,
-                    bits: Optional[List[int]] = None):
+def lexsort_indices(words: List[Any], num_rows, capacity: int):
     """Stable argsort by word list (most-significant first); padding rows
     (index >= num_rows) sort last.  Returns int32[capacity] permutation."""
     live = jnp.arange(capacity, dtype=jnp.int32) < jnp.asarray(num_rows, jnp.int32)
-    return lexsort_indices_live(words, live, bits)
-
-
-def multipass_enabled() -> bool:
-    """Resolve auron.sort.multipass.enable: 'auto' uses composed passes
-    everywhere except the CPU backend (XLA's comparator lexsort compiles
-    fast there and a single fused sort wins at runtime)."""
-    import jax as _jax
-
-    from auron_tpu.config import conf
-    mode = str(conf.get("auron.sort.multipass.enable"))
-    if mode == "on":
-        return True
-    if mode == "off":
-        return False
-    return _jax.default_backend() != "cpu"
+    return lexsort_indices_live(words, live)
 
 
 def stable_argsort(key):
@@ -297,29 +257,11 @@ def _multipass_lexsort(keys: List[Any]):
     return perm
 
 
-def lexsort_indices_live(words: List[Any], live,
-                         bits: Optional[List[int]] = None):
+def lexsort_indices_live(words: List[Any], live):
     """Same, from an explicit live mask (non-live rows sort last) — lets
-    kernels sort concatenations of padded segments without a host sync.
-
-    Kernel-strategy dispatch (auron.kernel.sort.strategy): the radix
-    pack-sort produces the SAME stable permutation from composed value
-    sorts (ops/radix_sort.py — 2.4-5x on this CPU backend); callers that
-    know their words' exact bit widths pass `bits`
-    (encode_sort_keys_bits) so the pack-sort can fuse words into fewer
-    passes.  Resolution happens at trace time: jitted callers include
-    strategy.strategy_fingerprint() in their cache keys."""
-    from auron_tpu.ops.strategy import sort_strategy
-    capacity = int(live.shape[0])
-    if sort_strategy(capacity, max(len(words), 1)) == "radix":
-        from auron_tpu.ops.radix_sort import radix_sort_indices
-        return radix_sort_indices(words, bits, live)
+    kernels sort concatenations of padded segments without a host sync."""
     pad_rank = jnp.where(live, jnp.uint64(0), jnp.uint64(1))
-    # jnp.lexsort: last key is primary
-    keys = list(reversed([pad_rank] + words))
-    if multipass_enabled():
-        return _multipass_lexsort(keys)
-    return jnp.lexsort(tuple(keys)).astype(jnp.int32)
+    return _multipass_lexsort(list(reversed([pad_rank] + words)))
 
 
 def keys_equal_prev(words: List[Any]):

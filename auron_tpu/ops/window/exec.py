@@ -161,10 +161,7 @@ class WindowExec(Operator, MemConsumer):
         pwords = encode_sort_keys(pcols, tuple((True, True)
                                                for _ in self.partition_by))
         owords = encode_sort_keys(ocols, orders)
-        from auron_tpu.ops.sort_keys import encode_sort_keys_bits
-        perm = lexsort_indices(pwords + owords, n, cap,
-                               encode_sort_keys_bits(pcols) +
-                               encode_sort_keys_bits(ocols))
+        perm = lexsort_indices(pwords + owords, n, cap)
         sorted_b = merged.gather(perm, n)
         sp = [jnp.take(w, perm) for w in pwords]
         so = [jnp.take(w, perm) for w in owords]

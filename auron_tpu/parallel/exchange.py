@@ -48,17 +48,9 @@ def _scatter_to_send(data, dest, valid, n_dev: int, quota: int):
     cap = dest.shape[0]
     safe_dest = jnp.where(valid, dest, n_dev)          # invalid -> dropped
     # within-destination slot: stable rank of each row among its dest
-    # group.  The key is tiny (values <= n_dev), so the radix strategy
-    # packs it with the row-index carry into ONE value sort instead of a
-    # full comparator argsort (same stable permutation either way).
-    from auron_tpu.ops.strategy import sort_strategy
-    if sort_strategy(cap) == "radix":
-        from auron_tpu.ops.radix_sort import radix_sort_indices
-        order = radix_sort_indices([safe_dest.astype(jnp.uint32)],
-                                   [max(int(n_dev).bit_length(), 1)])
-    else:
-        from auron_tpu.ops.sort_keys import stable_argsort
-        order = stable_argsort(safe_dest)              # groups by dest
+    # group
+    from auron_tpu.ops.sort_keys import stable_argsort
+    order = stable_argsort(safe_dest)                  # groups by dest
     sorted_dest = jnp.take(safe_dest, order)
     idx = jnp.arange(cap, dtype=jnp.int32)
     # start offset of each dest group in sorted order

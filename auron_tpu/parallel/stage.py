@@ -118,10 +118,6 @@ def _live_first_perm(live: Array) -> Array:
     """The stable permutation that brings live rows to the front (int32
     row numbers): join-chain compaction and the compact gather both cut
     or fetch a prefix of it."""
-    from auron_tpu.ops.strategy import sort_strategy
-    if sort_strategy(int(live.shape[0])) == "radix":
-        from auron_tpu.ops.radix_sort import stable_argsort_flags
-        return stable_argsort_flags(jnp.logical_not(live))
     return stable_argsort(jnp.logical_not(live))
 
 
@@ -294,7 +290,6 @@ class _StageTracer:
                  axis_sizes: Optional[Tuple[int, ...]] = None,
                  match_factor: int = 1,
                  agg_cap_hint: int = 0,
-                 hash_grouping: bool = False,
                  join_compact: bool = True):
         self.exchanges = getattr(conv_ctx, "exchanges", None) or {}
         self.broadcasts = getattr(conv_ctx, "broadcasts", None) or {}
@@ -350,10 +345,6 @@ class _StageTracer:
         self.agg_cap_hint = max(0, int(agg_cap_hint))
         # compact K-expanded join outputs back to pre-expansion capacity
         self.join_compact = bool(join_compact)
-        # hash-table group reduce (CPU mesh only — mirrors
-        # AggExec._grouping_strategy: XLA's comparator sort is ~3x numpy
-        # on CPU; on TPU scatters serialize and sort wins)
-        self.hash_grouping = bool(hash_grouping)
 
     def _axis_index(self):
         """Global device id; for a (dcn, ici) mesh the layout is
@@ -681,14 +672,7 @@ class _StageTracer:
         With the shrink off, or an input of no more than `new_cap` rows,
         there is neither cut nor choice: the body at the input's capacity
         and nothing else."""
-        from auron_tpu.ops.agg.exec import (
-            _group_reduce_body, _group_reduce_body_hash,
-        )
-        if self.hash_grouping:
-            # downstream consumers never rely on key order: exchanges
-            # hash keys, final aggs re-group, joins sort hashes, and the
-            # driver-side shadow sort re-orders the gathered result
-            _group_reduce_body = _group_reduce_body_hash
+        from auron_tpu.ops.agg.exec import _group_reduce_body
         if n.exec_mode == "single" and self.n_dev > 1 and \
                 not _single_agg_ok(n, self.exchanges):
             # a single-mode agg is per-partition; on a sharded SOURCE its
@@ -890,15 +874,10 @@ class _StageTracer:
         """(order, sorted_bh): the build side as a sorted u64 hash array,
         dead and null-key rows under a sentinel at its end."""
         from auron_tpu.ops.joins.kernel import _NULL_BUILD, join_key_hash
-        from auron_tpu.ops.strategy import sort_strategy
         bh, bvalid = join_key_hash(bkeys, build.capacity)
         bh = jnp.where(jnp.logical_and(build.live, bvalid), bh,
                        _NULL_BUILD)
-        if sort_strategy(build.capacity) == "radix":
-            from auron_tpu.ops.radix_sort import stable_argsort_u64
-            order = stable_argsort_u64(bh)
-        else:
-            order = stable_argsort(bh)
+        order = stable_argsort(bh)
         return order, jnp.take(bh, order)
 
     @staticmethod
@@ -1176,7 +1155,7 @@ class _StageTracer:
 
     def _do_sort(self, n: P.Sort) -> DeviceTable:
         from auron_tpu.ops.sort_keys import (
-            encode_sort_keys, encode_sort_keys_bits, lexsort_indices_live,
+            encode_sort_keys, lexsort_indices_live,
         )
         if n.fetch_limit is None:
             return self.eval_node(n.child)
@@ -1189,8 +1168,7 @@ class _StageTracer:
         keys = self._eval_exprs(tuple(x.child for x in n.sort_exprs), t)
         orders = tuple((x.asc, x.nulls_first) for x in n.sort_exprs)
         words = encode_sort_keys(keys, orders)
-        perm = lexsort_indices_live(words, t.live,
-                                    encode_sort_keys_bits(keys))
+        perm = lexsort_indices_live(words, t.live)
         rank = jnp.zeros(t.capacity, jnp.int32).at[perm].set(
             jnp.arange(t.capacity, dtype=jnp.int32))
         live = jnp.logical_and(t.live, rank < n.fetch_limit)
@@ -1219,7 +1197,7 @@ class _StageTracer:
 
     def _do_window(self, n: P.Window) -> DeviceTable:
         from auron_tpu.ops.sort_keys import (
-            encode_sort_keys, encode_sort_keys_bits, lexsort_indices_live,
+            encode_sort_keys, lexsort_indices_live,
         )
         from auron_tpu.ops.window.exec import (
             _coerce_to, _default_window_type, compute_window_fn,
@@ -1243,9 +1221,7 @@ class _StageTracer:
         pwords = encode_sort_keys(
             pcols, tuple((True, True) for _ in n.partition_by))
         owords = encode_sort_keys(ocols, orders)
-        perm = lexsort_indices_live(pwords + owords, t.live,
-                                    encode_sort_keys_bits(pcols) +
-                                    encode_sort_keys_bits(ocols))
+        perm = lexsort_indices_live(pwords + owords, t.live)
         allv = jnp.ones(cap, bool)
         sorted_cols = [c.gather(perm, allv) for c in t.cols]
         sorted_args = [[a.gather(perm, allv) for a in args]
@@ -2139,20 +2115,10 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     # same input shapes reuse the compiled shard_map program (a fresh
     # jax.jit closure per call would re-trace+re-compile every time)
     from auron_tpu.config import conf as _conf
-    from auron_tpu.ops.strategy import \
-        strategy_fingerprint as _strategy_fingerprint
     if agg_cap_hint is None:
         agg_cap_hint = int(_conf.get("auron.spmd.agg.capacity.hint"))
-    hash_grouping = (
-        np.asarray(mesh.devices).flat[0].platform == "cpu" and
-        str(_conf.get("auron.agg.grouping.strategy")) in ("auto", "hash"))
-    _gmode = str(_conf.get("auron.spmd.gather.compact"))
-    compact_gather = _gmode == "on" or (
-        _gmode == "auto" and
-        np.asarray(mesh.devices).flat[0].platform != "cpu")
     cache_key = (
         plan, axis, n_dev, match_factor, agg_cap_hint, join_compact,
-        compact_gather,
         _mesh_fingerprint(mesh),
         # EVERY config the tracer (or kernels it calls) reads at trace
         # time must appear here: rid canonicalization makes equal plans
@@ -2161,14 +2127,9 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         float(_conf.get("auron.spmd.exchange.quota.margin")),
         bool(_conf.get("auron.string.ascii.case.enable")),
         bool(_conf.get("auron.case.sensitive")),
-        bool(_conf.get("auron.segments.sorted.enable")),
-        str(_conf.get("auron.sort.multipass.enable")),
         str(_conf.get("auron.sort.f64.exactbits")),
-        bool(_conf.get("auron.pallas.enable")),
-        str(_conf.get("auron.agg.grouping.strategy")),
         int(_conf.get("auron.string.device.max.width")),
         str(_conf.get("auron.string.width.buckets")),
-        _strategy_fingerprint(),
         tuple(sorted((rid, job.child, job.partitioning)
                      for rid, job in (getattr(conv_ctx, "exchanges", None)
                                       or {}).items())),
@@ -2204,7 +2165,6 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                   axis_sizes=axis_sizes,
                                   match_factor=match_factor,
                                   agg_cap_hint=agg_cap_hint,
-                                  hash_grouping=hash_grouping,
                                   join_compact=join_compact)
             out = tracer.eval_node(plan)
             if not schema_box:
@@ -2239,15 +2199,14 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     if tracer.agg_inputs else None
                 cols, live = out.cols, out.live
                 count = jnp.sum(live.astype(jnp.int32))[None]
-                if compact_gather:
-                    # compact live rows to the shard front so the host
-                    # can fetch ONLY a bucket_capacity(count) slice
-                    # instead of the full padded capacity (VERDICT r4 #2:
-                    # "gather only final aggregated rows")
-                    perm = _live_first_perm(live)
-                    ok = jnp.take(live, perm)
-                    cols = [c.gather(perm, ok) for c in cols]
-                    live = ok
+                # compact live rows to the shard front so the host
+                # can fetch ONLY a bucket_capacity(count) slice
+                # instead of the full padded capacity (VERDICT r4 #2:
+                # "gather only final aggregated rows")
+                perm = _live_first_perm(live)
+                ok = jnp.take(live, perm)
+                cols = [c.gather(perm, ok) for c in cols]
+                live = ok
             return (cols, live, count, guards, retry_guards,
                     shrink_guards, join_guards, probe_direct, crossed,
                     agg_compact)
@@ -2278,40 +2237,21 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
-    with tracing.span("spmd.gather", cat="spmd",
-                      compact=bool(compact_gather)), \
+    with tracing.span("spmd.gather", cat="spmd"), \
             jitcheck.declared_transfer("spmd.gather"):  # jitcheck: waive (THE per-stage result fetch: counts+guards first, compacted slice second)
-        if compact_gather:
-            # phase 1: a few BYTES decide everything — per-shard live
-            # counts + guard bits.  A tripped guard never pays the
-            # output fetch at all, and a clean run fetches only the
-            # compacted slice below.  `spmd.wait` is the host's wait for
-            # the stage program (`spmd.run` was its enqueue).
-            with tracing.span("spmd.wait", cat="spmd") as sp:
-                (counts_np, guards_np, retry_np, shrink_np, join_np,
-                 direct_np, crossed_np, agg_np) = host_sync(
-                    (counts, guards, retry_guards, shrink_guards,
-                     join_guards, probe_direct, crossed, agg_compact))
-                reported = _reported(probe_box, direct_np, agg_box, agg_np,
-                                     cross_box, crossed_np, n_dev)
-                sp.set_args(**stage_totals(reported))
-        else:
-            # single batched fetch (CPU: transfers are memcpy-cheap, two
-            # round trips would only add dispatch latency): the wait for
-            # the program and the result's fetch are one round trip, so
-            # this path records no `spmd.fetch`
-            with tracing.span("spmd.wait", cat="spmd") as sp:
-                (out_live_np, out_cols_np, counts_np, guards_np, retry_np,
-                 shrink_np, join_np, direct_np, crossed_np,
-                 agg_np) = host_sync(
-                    (out_live, out_cols, counts, guards, retry_guards,
-                     shrink_guards, join_guards, probe_direct, crossed,
-                     agg_compact))
-                reported = _reported(probe_box, direct_np, agg_box, agg_np,
-                                     cross_box, crossed_np, n_dev)
-                sp.set_args(**_note_gather(counts_np, out_live_np,
-                                           out_cols_np),
-                            **stage_totals(reported))
+        # phase 1: a few BYTES decide everything — per-shard live
+        # counts + guard bits.  A tripped guard never pays the
+        # output fetch at all, and a clean run fetches only the
+        # compacted slice below.  `spmd.wait` is the host's wait for
+        # the stage program (`spmd.run` was its enqueue).
+        with tracing.span("spmd.wait", cat="spmd") as sp:
+            (counts_np, guards_np, retry_np, shrink_np, join_np,
+             direct_np, crossed_np, agg_np) = host_sync(
+                (counts, guards, retry_guards, shrink_guards,
+                 join_guards, probe_direct, crossed, agg_compact))
+            reported = _reported(probe_box, direct_np, agg_box, agg_np,
+                                 cross_box, crossed_np, n_dev)
+            sp.set_args(**stage_totals(reported))
         if stats is not None:
             # before the guards: a tripped exchange guard's fill is what
             # says why
@@ -2333,20 +2273,19 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             raise SpmdGuardTripped(
                 "duplicate-key build side at match factor 1: result "
                 "discarded", retryable=True)
-        if compact_gather:
-            # phase 2: slice each shard to the smallest capacity bucket
-            # that holds its rows (one tiny cached program), then fetch
-            with tracing.span("spmd.fetch", cat="spmd") as sp:
-                per_cap = out_live.shape[0] // n_dev
-                kmax = max(int(np.max(np.asarray(counts_np))), 1)
-                K = min(bucket_capacity(kmax), per_cap)
-                if K < per_cap:
-                    slicer = _gather_slicer(mesh, axis, K, out_cols,
-                                            out_live)
-                    out_cols, out_live = slicer(out_cols, out_live)
-                out_live_np, out_cols_np = host_sync((out_live, out_cols))
-                sp.set_args(**_note_gather(counts_np, out_live_np,
-                                           out_cols_np))
+        # phase 2: slice each shard to the smallest capacity bucket
+        # that holds its rows (one tiny cached program), then fetch
+        with tracing.span("spmd.fetch", cat="spmd") as sp:
+            per_cap = out_live.shape[0] // n_dev
+            kmax = max(int(np.max(np.asarray(counts_np))), 1)
+            K = min(bucket_capacity(kmax), per_cap)
+            if K < per_cap:
+                slicer = _gather_slicer(mesh, axis, K, out_cols,
+                                        out_live)
+                out_cols, out_live = slicer(out_cols, out_live)
+            out_live_np, out_cols_np = host_sync((out_live, out_cols))
+            sp.set_args(**_note_gather(counts_np, out_live_np,
+                                       out_cols_np))
         live_np = np.asarray(out_live_np)
         arrays = []
         for f, c in zip(out_schema, out_cols_np):

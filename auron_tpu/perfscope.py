@@ -6,13 +6,9 @@
 
 `report` executes one TPC-DS corpus query with `auron.perf.enable` armed
 and renders the per-site roofline table (calls, bytes, seconds, achieved
-GB/s vs the measured machine peak); `--export` additionally persists the
-live ledgers in kernel_profile_ms schema — a valid
-`auron.kernel.cost.profile.path` input — and `--calibrate` proves the
-loop closes by printing the cost model before/after it re-resolves from
-the live profile.  `check` compares achieved per-site bandwidth against
-committed floors with tolerance bands (tools/perf_check.sh's teeth;
-`--regen-golden` rewrites the baseline).  `ab` interleaves warm
+GB/s vs the measured machine peak).  `check` compares achieved per-site
+bandwidth against committed floors with tolerance bands
+(tools/perf_check.sh's teeth; `--regen-golden` rewrites the baseline).  `ab` interleaves warm
 disarmed/armed runs of the same query and gates that results stay
 bit-identical and the overhead ratio stays small — the evidence that
 the always-installed site shim is free when off.  This is the
@@ -77,38 +73,9 @@ def _cmd_report(args: argparse.Namespace) -> int:
             with open(args.json, "w") as f:
                 json.dump(doc, f, indent=2, sort_keys=True)
             print(f"rooflines -> {args.json}")
-        if args.export:
-            path = perfscope.export_profile(args.export)
-            print(f"live kernel profile -> {path}")
-        if args.calibrate:
-            _show_calibration(args.export)
     finally:
         perfscope.configure(False)
     return 0
-
-
-def _show_calibration(export_path) -> None:
-    """Prove the loop closes: the calibrate-mode cost model resolves
-    from the live ledgers (and an exported profile round-trips through
-    auron.kernel.cost.profile.path to the same numbers)."""
-    from auron_tpu.config import conf
-    from auron_tpu.ops import strategy
-
-    def fields(m):
-        return {k: round(getattr(m, k), 2) for k in
-                ("argsort_ns", "packsort_pass_ns", "gather_ns",
-                 "searchsorted_ns", "scatter_ns")}
-
-    seed = strategy.cost_model()
-    with conf.scoped({"auron.kernel.cost.calibrate": True}):
-        live = strategy.cost_model()
-    print(f"cost model (seed):       {fields(seed)}")
-    print(f"cost model (calibrated): {fields(live)}")
-    if export_path:
-        with conf.scoped({"auron.kernel.cost.profile.path": export_path,
-                          "auron.kernel.cost.calibrate": False}):
-            replayed = strategy.cost_model()
-        print(f"cost model (exported):   {fields(replayed)}")
 
 
 def _cmd_check(args: argparse.Namespace) -> int:
@@ -245,12 +212,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     corpus_args(rep)
     rep.add_argument("--json", default=None,
                      help="also write the rooflines doc as JSON")
-    rep.add_argument("--export", default=None,
-                     help="persist the live ledgers in kernel_profile_ms "
-                          "schema (valid cost.profile.path input)")
-    rep.add_argument("--calibrate", action="store_true",
-                     help="print the cost model before/after resolving "
-                          "from the live profile")
     rep.set_defaults(fn=_cmd_report)
 
     chk = sub.add_parser("check",

@@ -44,10 +44,9 @@ aqe_decisions`, the query history record (`/queries/<id>`), EXPLAIN
 ANALYZE, the `aqe.replan` trace span and the
 `auron_adaptive_{broadcast,coalesce,skew_split}_total` counters.
 
-The unified `CostModel` merges the PR 7 kernel-profile numbers
-(ops/strategy.KernelCostModel — measured per-row costs of the kernel
-families) with LIVE per-signature execution history (observed exchange
-bytes/rows per (plan signature, exchange ordinal)), and feeds three
+The unified `CostModel` holds LIVE per-signature execution history
+(observed exchange bytes/rows per (plan signature, exchange ordinal))
+beside two recorded per-row kernel costs, and feeds three
 consumers: this module's replan thresholds, the conversion-side
 projection/filter adjacency choice (frontend/converters._scan — the
 SystemML-style cost-chosen fusion exposure, not a greedy rewrite), and
@@ -194,23 +193,26 @@ class FetchAction:
 # the unified cost model
 # ---------------------------------------------------------------------------
 
+# Per-row costs `filter_adjacency_pays` weighs, in ns: the one pair of
+# numbers the kernel cost model resolved to in every run before it was
+# retired (its seed: a 2^22-row gather in 52.749 ms and a filter +
+# compaction in 126.191 ms, a host-CPU record of round 5).  The choice is
+# default off (`auron.adaptive.fuse.adjacency.enable`); a chip measurement
+# would replace both.
+_GATHER_NS = 52.749 * 1e6 / (1 << 22)             # 12.58
+_FILTER_COMPACT_NS = 126.191 * 1e6 / (1 << 22)    # 30.09
+
+
 class CostModel:
-    """ONE cost model over both information sources the engine has:
+    """The cost model: a bounded per-key history of observed exchange
+    volumes ((plan signature, exchange ordinal) -> recent bytes/rows),
+    recorded at every stage boundary, so repeated submissions of one
+    plan shape can be costed from what the SAME exchange actually
+    produced last time.
 
-    - the **kernel half** — ops/strategy.KernelCostModel, per-row
-      nanosecond costs measured from recorded kernel profiles (the PR 7
-      seed, overridable via auron.kernel.cost.profile.path); and
-    - the **live half** — a bounded per-key history of observed
-      exchange volumes ((plan signature, exchange ordinal) -> recent
-      bytes/rows), recorded at every stage boundary, so repeated
-      submissions of one plan shape can be costed from what the SAME
-      exchange actually produced last time.
-
-    Consumers: the replan thresholds here, the kernel strategy layer
-    (`kernel` exposes the per-row numbers the resolvers already use),
-    the conversion-side filter-adjacency choice (`filter_adjacency_
-    pays`), and the stage-boundary admission re-forecast
-    (`stage_mem_estimate`)."""
+    Consumers: the replan thresholds here, the conversion-side
+    filter-adjacency choice (`filter_adjacency_pays`), and the
+    stage-boundary admission re-forecast (`stage_mem_estimate`)."""
 
     #: decoded/padded in-memory expansion of wire bytes (v2 frames are
     #: raw device layout, but capacities pad to powers of two and reduce
@@ -221,16 +223,6 @@ class CostModel:
         self._keep = keep
         self._lock = lockcheck.Lock("adaptive.cost")
         self._history: Dict[Tuple[str, str], deque] = {}
-
-    # -- kernel half -------------------------------------------------------
-
-    @property
-    def kernel(self):
-        """The profile-seeded per-row kernel cost model (PR 7)."""
-        from auron_tpu.ops import strategy
-        return strategy.cost_model()
-
-    # -- live half ---------------------------------------------------------
 
     def record_exchange(self, signature: str, stats: ExchangeStats
                         ) -> None:
@@ -316,20 +308,18 @@ class CostModel:
         program (else the extra node can never fuse and is pure cost)
         and (b) the re-evaluation cost stays under the materialization
         the fused chain saves: per the recorded profile, one standalone
-        operator boundary costs ~one gather per row (`gather_ns`) plus
+        operator boundary costs ~one gather per row (`_GATHER_NS`) plus
         a compaction, while re-evaluating K predicates costs
-        ~K * (filter_compact - gather) per row.  With the r05 CPU
-        numbers that admits 1-2 cheap predicates and declines long
-        conjunctions — a measured line, not a vibe."""
+        ~K * (filter_compact - gather) per row.  With the recorded
+        numbers that admits one cheap predicate and declines
+        conjunctions."""
         from auron_tpu.runtime.fusion import _exprs_fusable
         if _exprs_fusable(predicates, schema) is not None:
             return False
-        m = self.kernel
         # residual per-row predicate cost: the filter family's measured
         # cost minus its gather/compact component
-        pred_ns = max(1.0, (126.191 * 1e6 / (1 << 22)) - m.gather_ns) \
-            if m.gather_ns < 30.0 else m.gather_ns * 0.5
-        saved_ns = 2.0 * m.gather_ns   # one avoided materialization +
+        pred_ns = _FILTER_COMPACT_NS - _GATHER_NS
+        saved_ns = 2.0 * _GATHER_NS    # one avoided materialization +
         #                                the compaction the chain defers
         return len(predicates) * pred_ns <= saved_ns
 

@@ -67,8 +67,7 @@ _COUNTERS: Dict[str, int] = {
     "rss_sidecar_deaths": 0,
     "rss_cleanups": 0,
     # data plane (PR 14): exchange bytes through the shuffle writers /
-    # readers (all transports), for the BENCH_r06 delta and the
-    # dataplane_check gate
+    # readers (all transports), for the dataplane_check gate
     "shuffle_bytes_pushed": 0,
     "shuffle_bytes_fetched": 0,
     # adaptive execution (runtime/adaptive.py): stage-boundary replan

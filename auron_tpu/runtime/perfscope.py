@@ -19,15 +19,8 @@ drive the next plan.  This module makes both live:
   write output once); families with a different algorithmic byte count
   declare their own estimator (`declare_estimator`);
 - achieved GB/s is computed against a MACHINE PEAK measured once by a
-  STREAM-style memcpy probe and cached to disk (like bench.py's probe
-  verdict) — `rooflines()` is the table /rooflines, the report CLI and
-  bench.py all render;
-- the loop closes through `live_profile()` / `export_profile()`: the
-  observed per-site per-row costs are folded into the
-  `kernel_profile_ms` schema `ops/strategy.KernelCostModel` consumes,
-  so `auron.kernel.cost.calibrate` (live, in-process) or
-  `auron.kernel.cost.profile.path` (exported file) runs strategy auto
-  resolution on THIS machine's numbers instead of the embedded seed.
+  STREAM-style memcpy probe and cached to disk — `rooflines()` is the
+  table /rooflines and the report CLI render.
 
 COST CONTRACT: off by default.  Disarmed, the shim is ONE module-flag
 read + one indirect call per kernel execution (same class of cost as a
@@ -52,7 +45,7 @@ from auron_tpu.runtime import lockcheck
 __all__ = [
     "wrap", "enabled", "configure", "record", "declare_estimator",
     "estimator_for", "snapshot", "rooflines", "kernel_seconds",
-    "kernel_bytes", "live_profile", "export_profile", "profile_version",
+    "kernel_bytes",
     "machine_peak_gbps", "measure_peak", "device_peak_gbps",
     "DEVICE_PEAK_GBPS", "attribution_scope",
     "reset_state", "render_report",
@@ -72,9 +65,6 @@ _ARMED = _env_bool("AURON_TPU_AURON_PERF_ENABLE")
 
 # leaf-only guard (never held across a conf read or a device sync)
 _LOCK = lockcheck.Lock("perfscope")
-
-_PROFILE_VERSION = 0   # bumped per recorded sample batch: cache buster
-                       # for strategy._MODEL_CACHE under calibrate mode
 
 # armed-path parameters, cached at configure() time: the shim must not
 # pay a conf.get (scoped-dict walk) per kernel execution — re-arm after
@@ -295,10 +285,9 @@ def _signature_key(in_leaves: List[Any]) -> str:
 def record(site: str, seconds: Optional[float], nbytes: int,
            signature: str = "<none>") -> None:
     """Record one kernel execution into the ledger (the shim's sink;
-    public so tests and calibration harnesses can feed synthetic
-    observations).  `seconds=None` = an untimed call (bytes + call
-    count only — the off-stride executions under sampling)."""
-    global _PROFILE_VERSION
+    public so tests and the statistics store can feed observations).
+    `seconds=None` = an untimed call (bytes + call count only — the
+    off-stride executions under sampling)."""
     ns = None if seconds is None else int(seconds * 1e9)
     cap, alpha, max_sigs = _CAP, _ALPHA, _MAX_SIGS
     with _LOCK:
@@ -312,15 +301,6 @@ def record(site: str, seconds: Optional[float], nbytes: int,
         if stats is None:
             stats = led.sigs[sig] = _SigStats()
         stats.add(ns, int(nbytes), cap, alpha)
-        _PROFILE_VERSION += 1
-
-
-def profile_version() -> int:
-    """Monotonic sample counter — strategy.cost_model's cache buster
-    under `auron.kernel.cost.calibrate` (new observations must be able
-    to flip a cached resolution)."""
-    with _LOCK:
-        return _PROFILE_VERSION
 
 
 # ---------------------------------------------------------------------------
@@ -655,91 +635,9 @@ def render_report(doc: Optional[Dict[str, Any]] = None) -> str:
     return "\n".join(lines)
 
 
-# ---------------------------------------------------------------------------
-# cost-model calibration (the loop back into ops/strategy.py)
-# ---------------------------------------------------------------------------
-
-# Live-site -> kernel_profile_ms schema mapping: (site glob, profile
-# key, bytes per ROW at that site's shape).  The per-row normalization
-# is how heterogeneous corpus shapes fold into the fixed-rows schema
-# KernelCostModel.from_profile consumes: rows ~= bytes / bytes_per_row,
-# per_row_ns = ns / rows, ms_at_profile_rows = per_row_ns * rows0 / 1e6.
-# Approximate by construction (a fused site is more than its dominant
-# family) — but measured-on-this-machine approximate beats an embedded
-# seed from another machine, which is the calibration contract.
-_PROFILE_FAMILIES: Tuple[Tuple[str, str, int], ...] = (
-    ("agg.sort_base", "argsort_u64_ms", 24),   # sort estimator: 2x8B key + 4B idx
-    ("strategy.bench", "argsort_u64_ms", 12),
-    ("join.range*", "probe_searchsorted_ms", 12),  # 8B probe + 4B out
-    ("join.pair", "probe_searchsorted_ms", 12),
-    ("batch.gather", "gather_rows_ms", 20),        # 8B in + 4B idx + 8B out
-    ("filter.compact_gather", "filter_compact_ms", 5),
-    ("agg.spec_merge", "segment_sum_sorted_ms", 20),
-    ("pallas.hash_pid", "hash_pid_xla_ms", 12),
-)
-
-
-def live_profile() -> Tuple[Dict[str, float], int]:
-    """(kernel_profile_ms-schema dict, rows) from the live ledger —
-    what `ops/strategy.cost_model()` consumes under
-    `auron.kernel.cost.calibrate`.  Families with no observed site keep
-    no entry (from_profile falls back to the seed per key)."""
-    from auron_tpu.ops.strategy import _SEED_PROFILE_ROWS
-    rows0 = _SEED_PROFILE_ROWS
-    with _LOCK:
-        totals = {n: led.totals() for n, led in _SITES.items()}
-    acc: Dict[str, Tuple[float, float]] = {}   # key -> (ns, rows)
-    for name, (calls, ns, nbytes) in totals.items():
-        if not calls or not nbytes:
-            continue
-        for glob, key, bpr in _PROFILE_FAMILIES:
-            if name == glob or fnmatch.fnmatchcase(name, glob):
-                rows = nbytes / float(bpr)
-                a_ns, a_rows = acc.get(key, (0.0, 0.0))
-                acc[key] = (a_ns + ns, a_rows + rows)
-                break
-    profile = {key: round(ns / rows * rows0 / 1e6, 4)
-               for key, (ns, rows) in acc.items() if rows > 0}
-    return profile, rows0
-
-
-def export_profile(path: Optional[str] = None) -> Optional[str]:
-    """Persist the live profile (kernel_profile_ms schema + the raw
-    per-site table) to `path` (default `auron.perf.export.path`; None
-    when neither is set).  The written file is a valid
-    `auron.kernel.cost.profile.path` target, so a calibrated SECOND run
-    — or another process on this machine — resolves strategy from these
-    observed numbers."""
-    if path is None:
-        try:
-            from auron_tpu.config import conf
-            path = str(conf.get("auron.perf.export.path")).strip()
-        except Exception:  # noqa: BLE001
-            path = ""
-    if not path:
-        return None
-    profile, rows = live_profile()
-    doc = {
-        "perfscope": 1,
-        "platform": _platform(),
-        "rows": rows,
-        "kernel_profile_ms": profile,
-        "machine_peak_gbps": machine_peak_gbps(),
-        "sites": snapshot(),
-    }
-    d = os.path.dirname(os.path.abspath(path))
-    if d:
-        os.makedirs(d, exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2)
-    return path
-
-
 def reset_state() -> None:
     """Test hook: drop the ledger (estimator declarations and the peak
     verdict describe the code/machine, not a run — they persist)."""
-    global _PROFILE_VERSION
     with _LOCK:
         _SITES.clear()
         _CALL_SEQ.clear()
-        _PROFILE_VERSION += 1

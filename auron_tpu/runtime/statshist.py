@@ -4,7 +4,7 @@ lockcheck owns locks, jitcheck owns compiles, wirecheck owns frames,
 perfscope owns what the kernels DELIVER — statshist owns what queries
 DID, across restarts.  Every statistics surface the engine built before
 this module — the `/queries` ring, MemForecaster's last-8 peaks, the
-CostModel's live exchange histograms, perfscope's calibrated profiles —
+CostModel's live exchange histograms, perfscope's ledgers —
 lives in process memory and dies with it, so a restarted server re-pays
 every bad first plan and bad first forecast.  This module is the
 statistics plane that outlives the process:
@@ -26,8 +26,8 @@ statistics plane that outlives the process:
   first run, marked provenance `store` on /scheduler),
   `adaptive.CostModel`'s per-(signature, exchange) history (exactly
   the learned-initial-plan feed the ROADMAP AQE item names), and the
-  perfscope ledger (so `auron.kernel.cost.calibrate` survives restart
-  instead of re-measuring).
+  perfscope ledger (so /rooflines survives a restart instead of
+  re-measuring).
 - **regress** — each terminal record is compared to its signature
   baseline (EMA +/- `auron.stats.regression.factor` on wall, exec,
   shuffle bytes, spills, after `auron.stats.regression.min.runs`
@@ -406,7 +406,7 @@ def _check_regression_locked(st: SigState, dims: Dict[str, float]
 def _kern_profile_slice() -> Dict[str, Dict[str, float]]:
     """The perfscope ledger's per-site totals (calls/seconds/bytes) —
     the store's kernel-profile record, refreshed at each terminal so
-    `auron.kernel.cost.calibrate` can be re-seeded after restart."""
+    the ledger can be re-seeded after restart."""
     from auron_tpu.runtime import perfscope
     out: Dict[str, Dict[str, float]] = {}
     for site, ent in perfscope.snapshot().items():

@@ -29,8 +29,8 @@ os.environ.setdefault("AURON_TPU_AURON_LOCKCHECK_ENABLE", "1")
 
 # compilation-hygiene checking is ON for the whole suite too (env
 # fallback of `auron.jitcheck.enable`) — also BEFORE auron_tpu import:
-# jit sites decide probed-vs-raw when they WRAP a program, and the
-# pallas module-level jits wrap at import.  Every retrace storm and
+# jit sites decide probed-vs-raw when they WRAP a program, and
+# module-level jits wrap at import.  Every retrace storm and
 # undeclared implicit device->host transfer the suite exercises raises
 # a structured JitcheckError at the offending site.
 os.environ.setdefault("AURON_TPU_AURON_JITCHECK_ENABLE", "1")

@@ -5,7 +5,6 @@ cell's plain reference and against the one-device program's, where the
 rows lie before and after what crosses devices, and what the program
 counts of it."""
 
-import hashlib
 import json
 import os
 
@@ -20,8 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from auron_tpu import config
 from auron_tpu.columnar.batch import DeviceColumn, bucket_capacity
 from auron_tpu.exprs import hashing as H
-from auron_tpu.frontend import converters, strategy
-from auron_tpu.frontend.converters import ConvertContext, ShuffleJob
+from auron_tpu.frontend.converters import ShuffleJob
 from auron_tpu.frontend.session import AuronSession
 from auron_tpu.ir import plan as P
 from auron_tpu.ir.expr import col
@@ -336,31 +334,15 @@ def test_counters_reach_the_record_the_span_and_explain_analyze(runs):
     assert text.count("probe=direct") == 4
 
 
-# -- (v) one device: the program it was --------------------------------------
+# -- (v) one device -----------------------------------------------------------
 
-# sha256 of the lowered text of query 7's stage program on data_mesh(1) at
-# `rehearse_rows`, at commit 318f50a, the parent of the PR that brought the
-# boundaries' counts (its `execute_plan_spmd`, this jax; the same on both
-# seeds: a seed draws amounts, not shapes).  A change that is meant to move
-# the one-device program takes a new digest from the tree before it.  The
-# PR that lets an aggregate compact its input did not move it: at
-# `rehearse_rows` no table of query 7 (65,536 slots at most) is larger than
-# the capacity hint's 262,144, so no aggregate has a choice to trace; the
-# program's `agg_inputs` is there and empty.
-_ONE_DEVICE_PROGRAM = \
-    "7eec828d35c42a9e22f81f724b0addd059f5d6f87afaa03c309f34f752ebad04"
-
-
-def test_one_device_lowers_to_the_program_it_was_and_counts_nothing(runs):
-    from stage_spy import spied_program
-    cat, params, session, plan, _got = runs[SEEDS[0]]
-    tags = strategy.apply(plan)
-    ctx = ConvertContext()
-    converted = converters.convert_recursively(plan, tags, ctx)
-    assert not ctx.sources
-    program, inputs = spied_program(converted, ctx, data_mesh(1), {})
-    text = program.lower(inputs).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == _ONE_DEVICE_PROGRAM
+def test_one_device_counts_nothing(runs):
+    """A program over one device has no boundary to count at (its text is
+    pinned in test_one_program.py); at `rehearse_rows` no table of query
+    7 (65,536 slots at most) is larger than the capacity hint's 262,144,
+    so no aggregate has a choice to trace: the program's `agg_inputs` is
+    there and empty."""
+    _cat, _params, session, plan, _got = runs[SEEDS[0]]
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
     assert sorted(one.stage_stats) == ["agg_inputs", "join_probes"]

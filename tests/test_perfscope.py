@@ -1,9 +1,8 @@
 """perfscope coverage (runtime/perfscope.py): estimator units per
 declared kernel family, ledger bounds (signature cap, reservoir ring,
-EMA, sampled-call estimates), the /rooflines + Prometheus surfaces, the
-profile-export -> cost-model calibration round-trip (a strategy
-resolution must PROVABLY flip on a synthetic profile), and the
-disarmed-default zero-ledger claim the tools/perf_check.sh A/B rides."""
+EMA, sampled-call estimates), the /rooflines + Prometheus surfaces, and
+the disarmed-default zero-ledger claim the tools/perf_check.sh A/B
+rides."""
 
 import json
 import os
@@ -87,15 +86,6 @@ def test_declare_estimator_overrides_and_redeclares():
         assert perfscope.estimator_for("test.fam.x")([], []) == 9
     finally:
         perfscope.declare_estimator("test.fam.*", perfscope.default_estimator)
-
-
-def test_estimators_declared_for_profile_families():
-    """Every _PROFILE_FAMILIES site glob must resolve SOME estimator —
-    the calibration mapping depends on bytes being recorded there."""
-    for glob, key, bpr in perfscope._PROFILE_FAMILIES:
-        probe = glob.replace("*", "x")
-        assert callable(perfscope.estimator_for(probe)), (glob, key)
-        assert bpr > 0
 
 
 # ---------------------------------------------------------------------------
@@ -350,118 +340,6 @@ def test_metrics_empty_until_armed():
     /metrics entirely — no misleading zero-valued series."""
     from auron_tpu.runtime.profiling import _prometheus_text
     assert "auron_kernel_seconds{" not in _prometheus_text()
-
-
-# ---------------------------------------------------------------------------
-# calibration round-trip
-# ---------------------------------------------------------------------------
-
-def _synthetic_gather_heavy_ledger():
-    """A ledger where random gather costs ~100x the seed while sorts are
-    cheap — shaped to flip any gather-vs-sort arbitration."""
-    # batch.gather: 20 B/row; 1e6 rows' bytes in 2 s => gather is SLOW
-    perfscope.record("batch.gather", 2.0, 20 * 10 ** 6, signature="g")
-    # agg.sort_base: 24 B/row; 1e6 rows' bytes in 1 ms => sort is FAST
-    perfscope.record("agg.sort_base", 0.001, 24 * 10 ** 6, signature="s")
-
-
-def test_live_profile_normalizes_per_row():
-    _synthetic_gather_heavy_ledger()
-    profile, rows = perfscope.live_profile()
-    from auron_tpu.ops.strategy import _SEED_PROFILE_ROWS
-    assert rows == _SEED_PROFILE_ROWS
-    # 2 s over 1e6 rows = 2000 ns/row => ms at 4M rows = 2000*4.19e6/1e6
-    expected_ms = 2.0 / 10 ** 6 * rows * 1e3
-    assert abs(profile["gather_rows_ms"] - expected_ms) / expected_ms < 0.01
-    assert "argsort_u64_ms" in profile
-    # families with no observed site keep no entry (seed fallback)
-    assert "hash_pid_xla_ms" not in profile
-
-
-def test_calibrate_mode_resolves_from_live_ledger():
-    from auron_tpu.ops import strategy
-    _synthetic_gather_heavy_ledger()
-    seed = strategy.KernelCostModel.from_profile(
-        dict(strategy._SEED_PROFILE_MS), strategy._SEED_PROFILE_ROWS)
-    with config.conf.scoped({"auron.kernel.cost.calibrate": True}):
-        live = strategy.cost_model()
-    assert live.gather_ns > 100 * seed.gather_ns
-    assert live.argsort_ns < seed.argsort_ns
-    # new samples invalidate the cached resolution (version-keyed)
-    perfscope.record("batch.gather", 4.0, 20 * 10 ** 6, signature="g")
-    with config.conf.scoped({"auron.kernel.cost.calibrate": True}):
-        live2 = strategy.cost_model()
-    assert live2.gather_ns > live.gather_ns
-
-
-def test_calibrate_without_samples_falls_back_to_static():
-    from auron_tpu.ops import strategy
-    with config.conf.scoped({"auron.kernel.cost.calibrate": True}):
-        m = strategy.cost_model()
-    static = strategy.KernelCostModel.from_profile(
-        dict(strategy._SEED_PROFILE_MS), strategy._SEED_PROFILE_ROWS)
-    assert m == static
-
-
-def test_profile_flips_a_strategy_resolution(tmp_path):
-    """The PROOF auto-resolution consults the profile: a synthetic
-    artifact where the measured radix sort LOST to argsort must flip
-    `sort_strategy('auto')` from the seed's radix pick to argsort."""
-    from auron_tpu.ops import strategy
-    rows = 1 << 22
-    with config.conf.scoped({"auron.kernel.sort.strategy": "auto"}):
-        assert strategy.sort_strategy(rows) == "radix", \
-            "precondition: the embedded seed picks radix on CPU at scale"
-        path = str(tmp_path / "slow_radix.json")
-        json.dump({"kernel_profile_ms": {
-                       "argsort_u64_ms": 1000.0,
-                       "radix_sort_u64_ms": 5000.0},
-                   "rows": rows}, open(path, "w"))
-        with config.conf.scoped({"auron.kernel.cost.profile.path": path}):
-            assert strategy.sort_strategy(rows) == "argsort", (
-                "a profile where radix measured 5x slower than argsort "
-                "did not flip the auto sort resolution")
-
-
-def test_calibrate_fingerprint_moves_with_the_model():
-    """Cached traced programs must refresh when calibration moves the
-    model — but NOT per recorded kernel (quantized fingerprint)."""
-    from auron_tpu.ops import strategy
-    with config.conf.scoped({"auron.kernel.cost.calibrate": True}):
-        fp_cold = strategy.strategy_fingerprint()
-        _synthetic_gather_heavy_ledger()
-        fp_live = strategy.strategy_fingerprint()
-        # one more sample that barely moves the average: fingerprint
-        # holds (2-significant-digit quantization)
-        perfscope.record("batch.gather", 2.0, 20 * 10 ** 6, signature="g")
-        fp_live2 = strategy.strategy_fingerprint()
-    fp_off = strategy.strategy_fingerprint()
-    assert fp_cold != fp_live
-    assert fp_live == fp_live2
-    assert fp_off[-1] == 0   # calibrate off: constant contribution
-
-
-def test_export_profile_roundtrip(tmp_path):
-    """export_profile writes a valid auron.kernel.cost.profile.path
-    target: a second (calibrate-OFF) process resolves the SAME model
-    from the file that calibrate mode resolved live."""
-    from auron_tpu.ops import strategy
-    _synthetic_gather_heavy_ledger()
-    path = str(tmp_path / "live_profile.json")
-    assert perfscope.export_profile(path) == path
-    doc = json.load(open(path))
-    assert doc["kernel_profile_ms"] and doc["rows"] > 0
-    assert doc["sites"]["batch.gather"]["calls"] == 1
-    with config.conf.scoped({"auron.kernel.cost.calibrate": True}):
-        live = strategy.cost_model()
-    with config.conf.scoped({"auron.kernel.cost.profile.path": path}):
-        from_file = strategy.cost_model()
-    assert abs(from_file.gather_ns - live.gather_ns) < 1e-6
-    assert abs(from_file.argsort_ns - live.argsort_ns) < 1e-6
-
-
-def test_export_profile_unset_path_is_none():
-    assert perfscope.export_profile() is None
 
 
 # ---------------------------------------------------------------------------

@@ -75,19 +75,6 @@ def test_each_row_own_segment():
         np.asarray(segments.sorted_segment_sum(x, seg, 3)), [5, -3, 7])
 
 
-def test_scatter_fallback_path():
-    from auron_tpu.config import conf
-    old = conf.get("auron.segments.sorted.enable")
-    conf.set("auron.segments.sorted.enable", False)
-    try:
-        x = jnp.arange(10, dtype=jnp.int64)
-        seg = jnp.asarray(np.array([0] * 5 + [2] * 5, np.int32))
-        np.testing.assert_array_equal(
-            np.asarray(segments.sorted_segment_sum(x, seg, 3)), [10, 0, 35])
-    finally:
-        conf.set("auron.segments.sorted.enable", old)
-
-
 @pytest.mark.parametrize("n", [1, 2, 17, 1000, 4096, 5001])
 def test_segmented_scan_equals_a_per_segment_numpy_scan(n):
     """The segmented scan (a rolled doubling loop on every backend:
@@ -150,32 +137,6 @@ def test_sorted_segment_sum_exact_zero_segments():
             assert got[s] == 0.0, f"segment {s}: {got[s]!r} != exact 0.0"
         else:
             assert abs(got[s] - expect) < 1e-6 * max(1.0, abs(expect))
-
-
-def test_multipass_lexsort_equals_fused_lexsort():
-    """auron.sort.multipass.enable: the composed single-key stable
-    argsort passes (the TPU form — one fused multi-operand comparator
-    sort takes minutes to compile there) produce EXACTLY the fused
-    jnp.lexsort permutation, including stability on duplicate keys and
-    non-live rows sorting last."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from auron_tpu.config import conf
-    from auron_tpu.ops.sort_keys import lexsort_indices_live
-
-    rng = np.random.default_rng(11)
-    n = 5000
-    # heavy duplication exercises stability; two words exercise the
-    # multi-key composition order
-    w0 = jnp.asarray(rng.integers(0, 7, n).astype(np.uint64))
-    w1 = jnp.asarray(rng.integers(0, 5, n).astype(np.uint64))
-    live = jnp.asarray(rng.random(n) < 0.8)
-    with conf.scoped({"auron.sort.multipass.enable": "off"}):
-        fused = np.asarray(lexsort_indices_live([w0, w1], live))
-    with conf.scoped({"auron.sort.multipass.enable": "on"}):
-        multi = np.asarray(lexsort_indices_live([w0, w1], live))
-    assert np.array_equal(fused, multi)
 
 
 @pytest.mark.parametrize("n", [1, 2047, 2048, 2049, 8192, 4 * 2570])

@@ -200,8 +200,7 @@ def _traced_execute(files, uid, scope):
 
 def test_leaf_spans_nest_under_their_parents(files):
     table, rec = _traced_execute(
-        files, "leaves", {"auron.spmd.gather.compact": "on",
-                          "auron.task.parallelism": 4})
+        files, "leaves", {"auron.task.parallelism": 4})
     assert table.num_rows == 4
     spans = [s for s in rec.snapshot() if s.dur_ns >= 0]
     by_id = {s.id: s for s in spans}
@@ -277,8 +276,7 @@ def test_program_spans_on_the_profilers_clock(files, tmp_path):
     opts.host_tracer_level = 1
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        _table, rec = _traced_execute(
-            files, "profiled", {"auron.spmd.gather.compact": "on"})
+        _table, rec = _traced_execute(files, "profiled", {})
     finally:
         jax.profiler.stop_trace()
     [path] = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",

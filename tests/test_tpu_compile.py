@@ -3,9 +3,8 @@
 libtpu is installed in the CPU sandbox and compiles for a chip that is
 described, not attached (the on-chip-measurement guide, section 2,
 rehearsal 3).  These tests lower the kernel families the stage tracer calls
-on TPC-DS q01/q07/q19 — in their TPU branches — and the two Pallas kernels
-with `interpret=False`, for one v5e chip, from `ShapeDtypeStruct`s.  What
-Mosaic or XLA:TPU would refuse on the chip it refuses here, at no chip time.
+on TPC-DS q01/q07/q19 for one v5e chip, from `ShapeDtypeStruct`s.  What
+XLA:TPU would refuse on the chip it refuses here, at no chip time.
 A compile that passes is not a chip run: nothing executes, so nothing here
 says anything about results or speed (`python chip_smoke.py` does).
 
@@ -17,14 +16,16 @@ v5e: 7 s at 2^14, 34 s at 2^17, 43 s at 2^20, 52 s at 2^22; with
 `jnp.argsort`'s int64 ones 13 s, 60 s, 99 s, 111 s — CHANGES.md, PR 22),
 and a test is kept to a few seconds.
 
-`jax.default_backend()` still answers "cpu" here, so every site that picks
-its branch by backend would compile its CPU branch: the `tpu_branches`
-fixture steers them in the test (tri-state options set to what `auto`
-resolves to on a TPU, `jax.default_backend` patched for the sites that have
-only the backend test).  The topology is described inside a module-scoped
-fixture — never at import, in a `skipif` or in `parametrize` arguments:
-only one process may load libtpu, and every xdist worker imports this file.
-All compiles run in this test's own process, in this one file.
+Every job has one kernel, the one the chip runs, so these compile what the
+suite runs — but for the three float64 capability sites (exprs/hashing.py
+`f64_bits_u32_pair`, ops/sort_keys.py `_orderable_u64_from_f64` and
+`f64_bits_of_column`): `jax.default_backend()` still answers "cpu" here
+and they would take the arm with a 64-bit bitcast, which XLA:TPU does not
+have.  The `tpu_branches` fixture patches `jax.default_backend` for them
+and forces the exact-bits sidecar on.  The topology is described inside a
+module-scoped fixture — never at import, in a `skipif` or in `parametrize`
+arguments: only one process may load libtpu, and every xdist worker imports
+this file.  All compiles run in this test's own process, in this one file.
 """
 
 import os
@@ -47,12 +48,7 @@ BUILD_CAP = 1 << 18     # a dimension-side build table (agg capacity hint)
 I32, I64, F64 = DataType.int32(), DataType.int64(), DataType.float64()
 
 # what `auto` resolves to when the backend is a TPU
-TPU_OPTIONS = {
-    "auron.sort.multipass.enable": "on",
-    "auron.sort.f64.exactbits": "on",
-    "auron.agg.grouping.strategy": "sort",
-    "auron.spmd.gather.compact": "on",
-}
+TPU_OPTIONS = {"auron.sort.f64.exactbits": "on"}
 
 
 @pytest.fixture(scope="module")
@@ -73,7 +69,7 @@ def one_chip(topo):
 
 @pytest.fixture
 def tpu_branches(monkeypatch):
-    """Steer the branch-by-backend sites into their TPU branches."""
+    """Steer the float64 capability sites into their TPU arms."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with conf.scoped(TPU_OPTIONS):
         yield
@@ -115,7 +111,7 @@ def _compile(fn, *args):
 
 
 # ---------------------------------------------------------------------------
-# XLA:TPU — the stage tracer's kernel families, TPU branches
+# XLA:TPU — the stage tracer's kernel families
 # ---------------------------------------------------------------------------
 
 def test_murmur3_hash_and_pmod_at_sf1_capacity(one_chip, tpu_branches,
@@ -157,8 +153,6 @@ def test_join_probe_and_pair_expansion_at_sf1_capacity(
     from auron_tpu.ops.joins.kernel import (
         expand_pairs, join_key_hash, probe_ranges,
     )
-    from auron_tpu.ops.strategy import join_probe_strategy
-    assert join_probe_strategy(BUILD_CAP) == "searchsorted"
 
     def ranges(pkey, sorted_hashes, plive):
         ph, pvalid = join_key_hash([pkey], CAP)
@@ -169,18 +163,6 @@ def test_join_probe_and_pair_expansion_at_sf1_capacity(
     _compile(lambda lo, counts: expand_pairs(lo, counts, 0, CAP),
              _shape(one_chip, CAP, jnp.int32),
              _shape(one_chip, CAP, jnp.int64))
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64, jnp.int64])
-def test_onehot_group_reduce_at_sf1_capacity(one_chip, tpu_branches,
-                                             no_persistent_cache, dtype):
-    """The one-hot/matmul segment reduce `auto` picks on TPU-class
-    backends for small static segment counts (ops/strategy.py)."""
-    from auron_tpu.ops.hash_group import onehot_segment_sum
-    from auron_tpu.ops.strategy import group_strategy
-    assert group_strategy(64) == "onehot"
-    _compile(lambda x, seg: onehot_segment_sum(x, seg, 64),
-             _shape(one_chip, CAP, dtype), _shape(one_chip, CAP, jnp.int32))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float64, jnp.int64])
@@ -213,18 +195,12 @@ def test_multipass_key_sort(one_chip, tpu_branches, no_persistent_cache):
     int64 key and a nullable exact-bits f64 key: u32 rank words and u64
     value words."""
     from auron_tpu.ops.sort_keys import (
-        encode_sort_keys, encode_sort_keys_bits, lexsort_indices_live,
-        multipass_enabled,
+        encode_sort_keys, lexsort_indices_live,
     )
-    from auron_tpu.ops.strategy import sort_strategy
-    assert multipass_enabled()
-    assert sort_strategy(SORT_CAP, 4) == "argsort"
 
     def order(k1, k2, live):
-        keys = [k1, k2]
-        words = encode_sort_keys(keys, [(True, True), (False, False)])
-        return lexsort_indices_live(words, live,
-                                    encode_sort_keys_bits(keys))
+        words = encode_sort_keys([k1, k2], [(True, True), (False, False)])
+        return lexsort_indices_live(words, live)
     _compile(order, _column(one_chip, I64, SORT_CAP),
              _column(one_chip, F64, SORT_CAP, exact_bits=True),
              _shape(one_chip, SORT_CAP, jnp.bool_))
@@ -232,8 +208,8 @@ def test_multipass_key_sort(one_chip, tpu_branches, no_persistent_cache):
 
 @pytest.mark.parametrize("merge", [False, True],
                          ids=["partial-update", "final-merge"])
-def test_sort_strategy_group_reduce(one_chip, tpu_branches,
-                                    no_persistent_cache, merge):
+def test_sort_based_group_reduce(one_chip, tpu_branches,
+                                 no_persistent_cache, merge):
     """The sort-based group-reduce of q07's shape (one key; avg, avg,
     count) as the stage tracer calls it (ops/agg/exec.py
     `_group_reduce_body`), update and merge forms."""
@@ -264,9 +240,7 @@ def test_compact_gather_permutation(one_chip, tpu_branches,
     gather and of join-chain compaction (parallel/stage.py
     `_live_first_perm`: a bool-key sort with an int32 payload), then the
     column gather."""
-    from auron_tpu.ops.strategy import sort_strategy
     from auron_tpu.parallel.stage import _live_first_perm
-    assert sort_strategy(SORT_CAP) == "argsort"
 
     def compact(col, live):
         perm = _live_first_perm(live)
@@ -319,31 +293,3 @@ def test_live_row_compaction_at_sf1_capacity(one_chip, tpu_branches,
                         _column(one_chip, F64, CAP, exact_bits=True),
                         _shape(one_chip, CAP, jnp.bool_))
     assert "sort" not in compiled.as_text().lower()
-
-
-# ---------------------------------------------------------------------------
-# Mosaic — the two Pallas kernels, interpret=False
-# ---------------------------------------------------------------------------
-
-def _is_mosaic(compiled) -> bool:
-    return "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_hash_partition_ids_compiles_for_the_chip(
-        one_chip, no_persistent_cache):
-    from auron_tpu.ops import kernels_pallas as KP
-    # underneath the jit site's wrapper: its own jitted function
-    compiled = KP.hash_partition_ids_i64.__wrapped__.lower(
-        _shape(one_chip, CAP, jnp.int64), _shape(one_chip, CAP, jnp.bool_),
-        n_parts=200, interpret=False).compile()
-    assert _is_mosaic(compiled)
-
-
-@pytest.mark.parametrize("b_bits", [4, 8])
-def test_pallas_radix_bucket_hist_compiles_for_the_chip(
-        one_chip, no_persistent_cache, b_bits):
-    from auron_tpu.ops import kernels_pallas as KP
-    compiled = KP.radix_bucket_hist.__wrapped__.lower(
-        _shape(one_chip, CAP, jnp.uint32), b_bits=b_bits,
-        interpret=False).compile()
-    assert _is_mosaic(compiled)

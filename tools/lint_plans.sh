@@ -17,7 +17,7 @@ cd "$(dirname "$0")/.."
 
 JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m auron_tpu.analysis --quiet "$@"
 
-python -m compileall -q auron_tpu tests tools bench.py
+python -m compileall -q auron_tpu tests tools
 
 if command -v ruff >/dev/null 2>&1; then
     ruff check auron_tpu tests tools

@@ -3,8 +3,8 @@
 # tools/*_check.sh family:
 #
 #   1. the perfscope unit suite must pass (estimator units, reservoir
-#      bounds, the /rooflines + Prometheus surfaces, the calibration
-#      round-trip, the disarmed zero-ledger claim);
+#      bounds, the /rooflines + Prometheus surfaces, the disarmed
+#      zero-ledger claim);
 #   2. the OFF-default claim must hold: an interleaved warm q01 serial
 #      A/B with perfscope disarmed vs armed stays bit-identical and the
 #      armed overhead stays under AURON_PERF_MAX_OVERHEAD (default 2%);
