@@ -84,8 +84,9 @@ class SessionResult:
     # what the stage program reported of itself (parallel/stage.py::
     # execute_plan_spmd's `stats`): `join_probes`, the probe each K=1
     # join took, and `agg_inputs`, the input each aggregate that chose
-    # worked on, by operator label; over more than one device also
-    # `exchanges`, `broadcasts` and `sources`, what crossed devices
+    # worked on, by operator label; `ingest`, what the scan leaves'
+    # tasks read; over more than one device also `exchanges`,
+    # `broadcasts` and `sources`, what crossed devices
     stage_stats: Dict[str, object] = field(default_factory=dict)
 
     def to_pylist(self) -> List[dict]:
@@ -115,12 +116,15 @@ class SessionResult:
                    normalize=normalize, stage_plan=stage_plan)
 
     def stage_totals(self) -> Dict[str, int]:
-        """The stage program's counters as query totals: `join_probes`
-        (K=1 joins run) and `join_probes_direct` (those that probed by
-        direct address on every device); `agg_inputs` (aggregates whose
-        input is larger than their output's capacity) and
-        `agg_inputs_compact` (those whose input every device compacted to
-        that capacity first); over more than one device also
+        """The stage path's counters as query totals: `scan_rows`,
+        `scan_batches` (what the scan leaves' tasks read for this execute;
+        0 where every leaf was cached) and `scan_device_batches` (those of
+        them that were device batches on the way; 0 expected);
+        `join_probes` (K=1 joins run) and `join_probes_direct` (those
+        that probed by direct address on every device); `agg_inputs`
+        (aggregates whose input is larger than their output's capacity)
+        and `agg_inputs_compact` (those whose input every device compacted
+        to that capacity first); over more than one device also
         `exchange_rows`, `exchange_rows_moved`, `exchange_buffer_bytes`,
         `exchange_fill_pct_max`, `broadcast_rows`, `broadcast_slots` and
         `broadcast_buffer_bytes` (stage.py::crossing_totals)."""

@@ -54,11 +54,20 @@ class Operator:
     def execute(self, ctx: TaskContext) -> Iterator[Batch]:
         raise NotImplementedError
 
+    def execute_arrow(self, ctx: TaskContext) -> Iterator[Any]:
+        """execute() for a caller that wants the output on the host: an
+        operator that holds its output as Arrow before it makes a device
+        batch of it (the file scans) yields the record batches; every
+        other yields its batches."""
+        return self.execute(ctx)
+
     # -- helpers ------------------------------------------------------------
 
-    def execute_with_metrics(self, ctx: TaskContext) -> Iterator[Batch]:
-        """Wraps execute() with output_rows/batches + compute-time metrics
-        and task-cancellation checks."""
+    def execute_with_metrics(self, ctx: TaskContext,
+                             arrow: bool = False) -> Iterator[Batch]:
+        """Wraps execute() (`arrow`: execute_arrow()) with
+        output_rows/batches + compute-time metrics and task-cancellation
+        checks."""
         import time
 
         from auron_tpu.faults import fault_point
@@ -69,7 +78,7 @@ class Operator:
         # failure recovery works end to end
         fault_point("op.execute")
         from auron_tpu.runtime import perfscope
-        it = self.execute(ctx)
+        it = self.execute_arrow(ctx) if arrow else self.execute(ctx)
         while True:
             # with perfscope armed, kernels executed during this pull
             # attribute their bytes/seconds to THIS operator's metric
@@ -102,7 +111,7 @@ class Operator:
             self.metrics.add("elapsed_compute_ns", time.perf_counter_ns() - t0)
             if not ctx.is_running:
                 return
-            if batch.num_rows_known:
+            if not isinstance(batch, Batch) or batch.num_rows_known:
                 self.metrics.add("output_rows", batch.num_rows)
             else:
                 # lazy batch: never force a sync just for a metric

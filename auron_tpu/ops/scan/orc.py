@@ -31,6 +31,15 @@ class OrcScanExec(Operator):
         self.positional_evolution = positional_evolution
 
     def execute(self, ctx: TaskContext) -> Iterator[Batch]:
+        return self._read(
+            ctx, lambda rb: Batch.from_arrow(rb, schema=self.schema))
+
+    def execute_arrow(self, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+        return self._read(ctx, None)
+
+    def _read(self, ctx: TaskContext, to_device) -> Iterator[Any]:
+        """The read loop: record batches evolved to the plan's schema,
+        each handed on as `to_device` makes it, or as it is."""
         from pyarrow import orc
         if ctx.partition_id >= len(self.file_groups):
             return  # extra partitions are empty
@@ -52,7 +61,7 @@ class OrcScanExec(Operator):
             tbl = f.read()
             out = self._evolve(tbl)
             for rb in out.to_batches(max_chunksize=batch_size()):
-                yield Batch.from_arrow(rb, schema=self.schema)
+                yield rb if to_device is None else to_device(rb)
 
     def _evolve(self, tbl: pa.Table) -> pa.Table:
         from auron_tpu.config import conf

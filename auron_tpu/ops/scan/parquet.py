@@ -61,6 +61,14 @@ class ParquetScanExec(Operator):
         return self.file_groups[gi], pv
 
     def execute(self, ctx: TaskContext) -> Iterator[Batch]:
+        return self._read(ctx, Batch.from_arrow)
+
+    def execute_arrow(self, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+        return self._read(ctx, None)
+
+    def _read(self, ctx: TaskContext, to_device) -> Iterator[Any]:
+        """The read loop: record batches patched to the plan's schema,
+        each handed on as `to_device` makes it, or as it is."""
         if not self.file_groups:
             return
         found = self._files_for(ctx)
@@ -68,6 +76,7 @@ class ParquetScanExec(Operator):
             return
         group, pvals = found
         names = [self.file_schema[i].name for i in self.projection]
+        out_schema = to_arrow_schema(self.schema)
         filt = None
         if self.predicate is not None and \
                 conf.get("auron.parquet.enable.page.filtering"):
@@ -105,34 +114,28 @@ class ParquetScanExec(Operator):
                 if rb is None:
                     break
                 with tracing.span("scan.to_device", cat="scan"):
-                    batch = self._to_batch(rb, names, pvals)
-                yield batch
+                    out = self._patch(rb, names, pvals, out_schema)
+                    if to_device is not None:
+                        out = to_device(out)
+                yield out
 
     def _prune_row_groups(self, pf: pq.ParquetFile, filt) -> List[int]:
         from auron_tpu.ops.scan.pushdown import prune_parquet_row_groups
         return prune_parquet_row_groups(
             pf, filt, use_bloom=bool(conf.get("auron.parquet.enable.bloom.filter")))
 
-    def _to_batch(self, rb: pa.RecordBatch, names, pvals) -> Batch:
+    def _patch(self, rb: pa.RecordBatch, names, pvals,
+               schema: pa.Schema) -> pa.RecordBatch:
         # re-order/patch missing columns (schema evolution: absent -> null)
-        arrays = []
-        fields = []
-        out_schema = self.schema
-        for i, n in enumerate(names):
-            f = self.file_schema.field(n)
-            if n in rb.schema.names:
-                arrays.append(rb.column(rb.schema.get_field_index(n)))
-            else:
-                from auron_tpu.ir.schema import to_arrow_type
-                arrays.append(pa.nulls(rb.num_rows, type=to_arrow_type(f.dtype)))
-        if self.partition_schema:
-            from auron_tpu.ir.schema import to_arrow_type
-            for f, v in zip(self.partition_schema, pvals):
-                arrays.append(pa.array([v] * rb.num_rows,
-                                       type=to_arrow_type(f.dtype)))
-        out = pa.RecordBatch.from_arrays(arrays,
-                                         schema=to_arrow_schema(out_schema))
-        return Batch.from_arrow(out)
+        at = {n: i for i, n in enumerate(rb.schema.names)}
+        arrays = [rb.column(at[n]) if n in at else
+                  pa.nulls(rb.num_rows, type=schema.field(j).type)
+                  for j, n in enumerate(names)]
+        for j, (_f, v) in enumerate(zip(self.partition_schema or (), pvals),
+                                    len(names)):
+            arrays.append(pa.array([v] * rb.num_rows,
+                                   type=schema.field(j).type))
+        return pa.RecordBatch.from_arrays(arrays, schema=schema)
 
 
 class ParquetSinkExec(Operator):

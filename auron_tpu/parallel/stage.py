@@ -1639,7 +1639,9 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     for every aggregate that chose (`_agg_input_marks`); over more than one
     device also `exchanges` and `broadcasts`, {operator label: counts} for
     every boundary (`_crossing_stats`), and `sources`, {canonical rid:
-    rows, cap, rows on the fullest and the emptiest device}.
+    rows, cap, rows on the fullest and the emptiest device}.  `ingest`
+    holds what the scan leaves' tasks read (`INGEST_COUNTS`), summed over
+    the attempts.
 
     A tripped join guard (duplicate build keys past the current match
     factor) retries ONCE with auron.spmd.join.match.factor pair
@@ -1962,10 +1964,31 @@ def _reported(probe_box, direct_np, agg_box, agg_np, cross_box, crossed_np,
             **_crossing_stats(cross_box, crossed_np)}
 
 
+# what `spmd.ingest` reports of an attempt's scan leaves: leaves in the
+# plan, those `_SCAN_TABLES` served, tasks run for the others, and what
+# the tasks read: record batches kept, their rows and Arrow bytes, and
+# the batches that were device batches on the way (none: a scan read for
+# a stage hands on the Arrow it made)
+INGEST_COUNTS = ("scans", "cached", "tasks", "batches", "rows", "bytes",
+                 "device_batches")
+
+
+def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
+    """What an execute's scan tasks read, as query totals; none in what
+    a program reports of itself."""
+    ingest = stats.get("ingest")
+    if not ingest:
+        return {}
+    return {"scan_" + name: ingest[name]
+            for name in ("rows", "batches", "device_batches")}
+
+
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
-    """execute_plan_spmd's `stats` as query totals: the probe counter's
-    two numbers, the aggregate inputs' two and the boundaries' counts."""
-    return {**probe_counts(stats.get("join_probes") or {}),
+    """execute_plan_spmd's `stats` as query totals: what the scan tasks
+    read, the probe counter's two numbers, the aggregate inputs' two and
+    the boundaries' counts."""
+    return {**ingest_totals(stats),
+            **probe_counts(stats.get("join_probes") or {}),
             **agg_input_counts(stats.get("agg_inputs") or {}),
             **crossing_totals(stats)}
 
@@ -2059,9 +2082,16 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     # FFI sources, then shard row-wise over the mesh
     from auron_tpu.runtime import tracing
     source_tables = dict(source_tables)
-    with tracing.span("spmd.ingest", cat="spmd"):
-        scan_rids, scan_tables = _materialize_scans(plan, conv_ctx)
+    with tracing.span("spmd.ingest", cat="spmd") as sp:
+        scan_rids, scan_tables, read = _materialize_scans(plan, conv_ctx)
+        sp.set_args(**read)
     source_tables.update(scan_tables)
+    if stats is not None:
+        # over the attempts of one execute: a retried attempt finds the
+        # first one's scans cached
+        ingest = stats.setdefault("ingest", dict.fromkeys(INGEST_COUNTS, 0))
+        for name, n in read.items():
+            ingest[name] += n
 
     # shard + device_put each source ONCE per (table, mesh, axis, string
     # config): repeat executes of the same query hit device-resident
@@ -2408,9 +2438,12 @@ def precheck_plan(plan, conv_ctx) -> None:
 
 
 def _materialize_scans(plan, conv_ctx):
-    """Run every Parquet/Orc scan leaf through the serial engine (host IO
-    + pruning); rids are deterministic walk-order indexes so the compiled
-    program's binding structure is stable across conversions.
+    """Read every Parquet/Orc scan leaf as the serial engine's tasks do
+    (host IO + pruning) and keep the Arrow record batches the scans made:
+    no device batch lies between a file and `_shard_table`.  rids are
+    deterministic walk-order indexes so the compiled program's binding
+    structure is stable across conversions.  Returns (rids, tables, what
+    was read: `INGEST_COUNTS`).
 
     Scan PARTITIONS read in parallel on a thread pool (round-3 fix: one
     host thread serially materializing every split was the wall at
@@ -2419,7 +2452,6 @@ def _materialize_scans(plan, conv_ctx):
     deterministic."""
     import pyarrow as pa
 
-    from auron_tpu.ir.schema import to_arrow_schema
     from auron_tpu.runtime.executor import execute_plan
     from auron_tpu.runtime.task_pool import run_tasks
 
@@ -2453,22 +2485,28 @@ def _materialize_scans(plan, conv_ctx):
     def read(job):
         rid, node, pid, n_parts = job
         return rid, pid, execute_plan(node, partition_id=pid,
-                                      num_partitions=n_parts).batches
+                                      num_partitions=n_parts, arrow=True)
 
     results = run_tasks(read, jobs, "auron-scan")
 
-    per_rid: Dict[str, List[Tuple[int, List[Any]]]] = {}
-    for rid, pid, batches in results:
-        per_rid.setdefault(rid, []).append((pid, batches))
+    counts = dict.fromkeys(INGEST_COUNTS, 0)
+    counts.update(scans=len(nodes), cached=len(cached), tasks=len(jobs))
+    per_rid: Dict[str, Dict[int, Any]] = {}
+    for rid, pid, res in results:
+        per_rid.setdefault(rid, {})[pid] = res
+        counts["device_batches"] += res.device_batches
     tables: Dict[str, Any] = dict(cached)
     for rid, node in nodes.items():
         if rid in cached:
             continue
-        batches = [b for _pid, bs in sorted(per_rid.get(rid, []))
-                   for b in bs]
-        schema = to_arrow_schema(node.schema)
-        t = pa.Table.from_batches(batches, schema=schema) \
-            if batches else pa.Table.from_batches([], schema=schema)
+        parts = [res for _pid, res in sorted(per_rid[rid].items())]
+        batches = [b for res in parts for b in res.batches]
+        # the scan's output schema (the file's columns as projected, then
+        # the partition columns), which every batch of it has
+        t = pa.Table.from_batches(batches, schema=parts[0].schema)
         tables[rid] = t
         _SCAN_TABLES.put(node, fps[rid], t)
-    return rids, tables
+        counts["batches"] += len(batches)
+        counts["rows"] += t.num_rows
+        counts["bytes"] += t.nbytes
+    return rids, tables, counts

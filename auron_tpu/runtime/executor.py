@@ -32,6 +32,8 @@ class ExecutionResult:
     batches: List[pa.RecordBatch]
     metrics: MetricNode
     schema: Optional["pa.Schema"] = None   # plan output (empty results)
+    # output batches that came back from the device (`task.to_host`)
+    device_batches: int = 0
 
     def to_table(self) -> pa.Table:
         if not self.batches:
@@ -62,11 +64,13 @@ class NativeExecutionRuntime:
             mem_manager=get_manager())
         self.error: Optional[BaseException] = None
 
-    def batches(self) -> Iterator[Batch]:
-        """Pull the stream; errors are recorded and re-raised (the setError
-        + rethrow-on-next-loadNextBatch contract, rt.rs:207-238)."""
+    def batches(self, arrow: bool = False) -> Iterator[Batch]:
+        """Pull the stream (`arrow`: the root's execute_arrow, record
+        batches where it holds them); errors are recorded and re-raised
+        (the setError + rethrow-on-next-loadNextBatch contract,
+        rt.rs:207-238)."""
         try:
-            yield from self.root.execute_with_metrics(self.ctx)
+            yield from self.root.execute_with_metrics(self.ctx, arrow)
         except BaseException as e:  # noqa: BLE001 - ferried to caller
             self.error = e
             if self.ctx.is_running:
@@ -83,12 +87,12 @@ class NativeExecutionRuntime:
 
 def execute_plan(plan: P.PlanNode, partition_id: int = 0,
                  num_partitions: int = 1,
-                 resources: Optional[ResourceRegistry] = None
-                 ) -> ExecutionResult:
+                 resources: Optional[ResourceRegistry] = None,
+                 arrow: bool = False) -> ExecutionResult:
     """Convenience driver: run one partition of a plan to completion."""
     td = P.TaskDefinition(plan=plan, partition_id=partition_id,
                           num_partitions=num_partitions)
-    return execute_task(td, resources)
+    return execute_task(td, resources, arrow)
 
 
 def task_attempt_counts() -> tuple:
@@ -115,8 +119,12 @@ def _device_retryable(exc: BaseException) -> bool:
 
 
 def execute_task(task: P.TaskDefinition,
-                 resources: Optional[ResourceRegistry] = None
-                 ) -> ExecutionResult:
+                 resources: Optional[ResourceRegistry] = None,
+                 arrow: bool = False) -> ExecutionResult:
+    """Run one task and return its output as Arrow.  `arrow` pulls the
+    root's execute_arrow: a file scan then hands on the record batches
+    it read and nothing of them reaches the device (a stage's ingest,
+    parallel/stage.py::_materialize_scans)."""
     from auron_tpu.runtime import (
         counters, jitcheck, profiling, retry, task_logging, tracing,
     )
@@ -145,13 +153,15 @@ def execute_task(task: P.TaskDefinition,
                 # convert BEFORE the row-count check: to_arrow fetches
                 # count + columns in one round trip, while `b.num_rows`
                 # alone would pay a separate sync for lazy batches
-                out = []
-                for b in rt.batches():
-                    with tracing.span("task.to_host", cat="task"):
-                        rb = b.to_arrow()
+                out, from_device = [], 0
+                for rb in rt.batches(arrow):
+                    if isinstance(rb, Batch):
+                        from_device += 1
+                        with tracing.span("task.to_host", cat="task"):
+                            rb = rb.to_arrow()
                     if rb.num_rows > 0:
                         out.append(rb)
-                return out
+                return out, from_device
 
     def _count_retry(_attempt_no, _exc):
         retries_box[0] += 1
@@ -169,7 +179,7 @@ def execute_task(task: P.TaskDefinition,
         with tracing.span("task.execute", cat="task",
                           stage=task.stage_id,
                           partition=task.partition_id):
-            out = retry.call_with_retry(
+            out, from_device = retry.call_with_retry(
                 _attempt, policy=retry.RetryPolicy.from_conf(),
                 label=f"task stage={task.stage_id} "
                       f"part={task.partition_id}",
@@ -201,7 +211,8 @@ def execute_task(task: P.TaskDefinition,
     # second-run-compiles-zero contract); per-site totals ride /metrics
     metrics.add("jit_compiles",
                 sum(jitcheck.compile_counts().values()) - jit0)
-    return ExecutionResult(out, metrics, schema=out_schema)
+    return ExecutionResult(out, metrics, schema=out_schema,
+                           device_batches=from_device)
 
 
 def execute_task_bytes(task_bytes: bytes,

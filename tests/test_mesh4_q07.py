@@ -345,8 +345,11 @@ def test_one_device_counts_nothing(runs):
     _cat, _params, session, plan, _got = runs[SEEDS[0]]
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
-    assert sorted(one.stage_stats) == ["agg_inputs", "join_probes"]
+    assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_probes"]
     assert one.stage_stats["agg_inputs"] == {}
+    # the driver's own count of what the scan leaves' tasks read (PR 31)
+    assert one.stage_stats["ingest"]["scans"] == 5
+    assert one.stage_stats["ingest"]["device_batches"] == 0
     totals = tracing.find_query(one.query_id).metric_totals
     assert not set(_TOTALS) & set(totals)
     assert not set(_TOTALS) & set(_wait_args(one))
