@@ -717,13 +717,15 @@ SPMD_SOURCE_CACHE_MB = conf.define(
     "(table identity, mesh, string layout), so a repeat execute of the "
     "same query transfers nothing host-to-device (the reference's hot "
     "path does zero per-batch host work, rt.rs:141-238).  0 disables; "
-    "LRU eviction past the budget.",
+    "LRU eviction past the budget, never of what the query in flight "
+    "reads.",
 )
 SPMD_SCAN_CACHE_MB = conf.define(
     "auron.spmd.scan.cache.mb", 2048,
     "Host-byte budget (MB) for the SPMD materialized-scan cache: scan "
     "leaves are re-read from disk only when a file's (mtime, size) "
-    "changes.  0 disables; LRU eviction past the budget.",
+    "changes.  0 disables; LRU eviction past the budget, never of what "
+    "the query in flight reads.",
 )
 SPMD_JOIN_MATCH_FACTOR = conf.define(
     "auron.spmd.join.match.factor", 4,

@@ -120,6 +120,11 @@ class SessionResult:
         `scan_batches` (what the scan leaves' tasks read for this execute;
         0 where every leaf was cached) and `scan_device_batches` (those of
         them that were device batches on the way; 0 expected);
+        `scan_cached` and `shards_cached` (scan leaves and sources the
+        two source caches served), `source_evictions` (entries this
+        execute's stores evicted from them) and `source_over_budget_bytes`
+        (bytes they hold past their budgets because this execute reads
+        them; 0 where a query fits the budgets);
         `join_probes` (K=1 joins run) and `join_probes_direct` (those
         that probed by direct address on every device); `agg_inputs`
         (aggregates whose input is larger than their output's capacity)

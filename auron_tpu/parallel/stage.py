@@ -42,7 +42,9 @@ unconvertible plan sections (AuronConvertStrategy).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any, Callable, Collection, Dict, List, Optional, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -1461,10 +1463,19 @@ def _string_cfg_fingerprint() -> Tuple:
 
 
 class _ByteBudgetLRU:
-    """Byte-bounded LRU map: key -> (value, nbytes).  Eviction keeps at
-    least one entry so a single oversized value still caches (it would
-    thrash forever otherwise).  Subclasses supply the budget and layer
-    their keying semantics on top."""
+    """Byte-bounded LRU map: key -> (value, nbytes).  A store evicts
+    least-recently-used entries until the bytes fit the budget, but never
+    an entry the execute in flight reads: the caller hands `_store` the
+    keys of its attempt's sources (`reads`: those it has been served,
+    has stored or is about to store), and the budget evicts among the
+    others only.  What one query reads is the
+    floor: a query reads several sources, and were one of them alone past
+    the budget, keeping just the newest entry would have every store
+    evict the query's other sources and every execute read all of them
+    again, forever.  If what the attempt reads is over the budget by
+    itself, it all stays (`over_budget_bytes`) until another query's
+    stores evict it, least recently used first.  Subclasses supply the
+    budget and layer their keying semantics on top."""
 
     def __init__(self):
         self._entries: "collections.OrderedDict[Any, Tuple[Any, int]]" = \
@@ -1492,18 +1503,34 @@ class _ByteBudgetLRU:
         if e is not None:
             self._bytes -= e[1]
 
-    def _store(self, key, value, nbytes: int) -> bool:
+    def _store(self, key, value, nbytes: int,
+               reads: Collection = ()) -> int:
+        """Returns how many entries the budget evicted (a disabled cache
+        stores nothing and evicts nothing)."""
         budget = self._budget()
         if budget <= 0:
-            return False
+            return 0
         self._evict_key(key)
         self._entries[key] = (value, nbytes)
         self._bytes += nbytes
-        while self._bytes > budget and len(self._entries) > 1:
-            old_key, (_v, b) = self._entries.popitem(last=False)
-            self._bytes -= b
-            self._dropped(old_key)
-        return True
+        evicted = 0
+        if self._bytes > budget:
+            for old_key in [k for k in self._entries
+                            if k != key and k not in reads]:
+                if self._bytes <= budget:
+                    break
+                self._evict_key(old_key)
+                self._dropped(old_key)
+                evicted += 1
+        return evicted
+
+    def held_bytes(self) -> int:
+        return self._bytes
+
+    def over_budget_bytes(self) -> int:
+        """Bytes held past the budget: what an execute's own reads kept
+        there (0 where the cache is disabled: it holds nothing)."""
+        return max(0, self._bytes - max(0, self._budget()))
 
     def _dropped(self, key) -> None:
         """Hook: called for keys evicted by the byte budget."""
@@ -1524,7 +1551,8 @@ class _DeviceShardCache(_ByteBudgetLRU):
     while the table object is alive; a weakref finalizer evicts every
     entry for a table the moment it is garbage collected (no stale-id
     reuse window).  Entries are bounded by device bytes
-    (auron.spmd.source.cache.mb); eviction drops the JAX array
+    (auron.spmd.source.cache.mb), except for what the execute in flight
+    reads (`_ByteBudgetLRU`); eviction drops the JAX array
     references and XLA frees the buffers once no running program holds
     them."""
 
@@ -1549,22 +1577,32 @@ class _DeviceShardCache(_ByteBudgetLRU):
     # for the other run's mesh (ADVICE r4)
 
     def get(self, table, shard_key: Tuple) -> Optional[dict]:
-        key = (id(table), *shard_key)
+        key = self.key_of(table, shard_key)
         e = self._lookup(key)
         if e is None or e["ref"]() is not table:
             return None
         return e
 
-    def put(self, table, entry: dict, shard_key: Tuple) -> None:
+    @staticmethod
+    def key_of(table, shard_key: Tuple) -> Tuple:
+        return (id(table), *shard_key)
+
+    def put(self, table, entry: dict, shard_key: Tuple,
+            reads: Collection = ()) -> int:
+        """Stores the entry; returns how many entries that evicted.
+        `reads`: the keys (`key_of`) of the attempt's sources, which the
+        budget does not evict."""
         tid = id(table)
-        key = (tid, *shard_key)
+        key = self.key_of(table, shard_key)
         nbytes = sum(
             int(getattr(x, "nbytes", 0))
             for x in jax.tree.leaves((entry["cols"], entry["live"])))
         entry["ref"] = weakref.ref(
             table, lambda _r, tid=tid: self._evict_tid(tid))
-        if self._store(key, entry, nbytes):
+        evicted = self._store(key, entry, nbytes, reads)
+        if key in self._entries:
             self._tid_keys.setdefault(tid, set()).add(key)
+        return evicted
 
     def clear(self) -> None:
         super().clear()
@@ -1596,7 +1634,8 @@ class _ScanTableCache(_ByteBudgetLRU):
     is taken BEFORE the scan reads (no stat-after-read TOCTOU: a file
     rewritten mid-read changes the fingerprint the next get computes, so
     the stale entry never matches).  Bounded by arrow bytes
-    (auron.spmd.scan.cache.mb)."""
+    (auron.spmd.scan.cache.mb), except for what the execute in flight
+    reads (`_ByteBudgetLRU`)."""
 
     def _budget(self) -> int:
         from auron_tpu.config import conf as _conf
@@ -1607,10 +1646,14 @@ class _ScanTableCache(_ByteBudgetLRU):
             return None
         return self._lookup((node, fp))
 
-    def put(self, node, fp: Optional[Tuple], table) -> None:
+    def put(self, node, fp: Optional[Tuple], table,
+            reads: Collection = ()) -> int:
+        """Stores the table; returns how many entries that evicted.
+        `reads`: the keys (node, fingerprint) of the attempt's leaves,
+        which the budget does not evict."""
         if fp is None:
-            return
-        self._store((node, fp), table, int(table.nbytes))
+            return 0
+        return self._store((node, fp), table, int(table.nbytes), reads)
 
 
 _SCAN_TABLES = _ScanTableCache()
@@ -1640,8 +1683,10 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     device also `exchanges` and `broadcasts`, {operator label: counts} for
     every boundary (`_crossing_stats`), and `sources`, {canonical rid:
     rows, cap, rows on the fullest and the emptiest device}.  `ingest`
-    holds what the scan leaves' tasks read (`INGEST_COUNTS`), summed over
-    the attempts.
+    holds what the scan leaves' tasks read (`INGEST_COUNTS`) and `shard`
+    what was placed on the device (`SHARD_COUNTS`), each with the state of
+    its source cache (`CACHE_STATE`): summed over the attempts, but the
+    caches' bytes as the last attempt left them.
 
     A tripped join guard (duplicate build keys past the current match
     factor) retries ONCE with auron.spmd.join.match.factor pair
@@ -1971,16 +2016,38 @@ def _reported(probe_box, direct_np, agg_box, agg_np, cross_box, crossed_np,
 # a stage hands on the Arrow it made)
 INGEST_COUNTS = ("scans", "cached", "tasks", "batches", "rows", "bytes",
                  "device_batches")
+# and of `_SCAN_TABLES` after the attempt's stores: entries those stores
+# evicted, the Arrow bytes it holds, and those of them past its budget
+# (kept because the attempt reads them; 0 where a query fits the budget)
+CACHE_STATE = ("evicted", "held_bytes", "over_budget_bytes")
+# what `spmd.shard` reports of an attempt's sources: those
+# `_DEVICE_SHARDS` served, those padded and put, and `CACHE_STATE` of
+# that cache, in device bytes counted from the arrays
+SHARD_COUNTS = ("cached", "placed") + CACHE_STATE
+# the two of them that say where a cache stands, not what an attempt did:
+# over an execute's attempts the last reading stands, the others add up
+_GAUGES = ("held_bytes", "over_budget_bytes")
+
+
+def _add_attempt(total: Dict[str, int], counts: Dict[str, int]) -> None:
+    for name, n in counts.items():
+        total[name] = n if name in _GAUGES else total.get(name, 0) + n
 
 
 def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
-    """What an execute's scan tasks read, as query totals; none in what
-    a program reports of itself."""
+    """What an execute's scan tasks read and what the two source caches
+    did for it, as query totals; none in what a program reports of
+    itself."""
     ingest = stats.get("ingest")
     if not ingest:
         return {}
-    return {"scan_" + name: ingest[name]
-            for name in ("rows", "batches", "device_batches")}
+    shard = stats.get("shard") or dict.fromkeys(SHARD_COUNTS, 0)
+    return {**{"scan_" + name: ingest[name]
+               for name in ("rows", "batches", "device_batches", "cached")},
+            "shards_cached": shard["cached"],
+            "source_evictions": ingest["evicted"] + shard["evicted"],
+            "source_over_budget_bytes": (ingest["over_budget_bytes"]
+                                         + shard["over_budget_bytes"])}
 
 
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
@@ -2089,9 +2156,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     if stats is not None:
         # over the attempts of one execute: a retried attempt finds the
         # first one's scans cached
-        ingest = stats.setdefault("ingest", dict.fromkeys(INGEST_COUNTS, 0))
-        for name, n in read.items():
-            ingest[name] += n
+        _add_attempt(stats.setdefault("ingest", {}), read)
 
     # shard + device_put each source ONCE per (table, mesh, axis, string
     # config): repeat executes of the same query hit device-resident
@@ -2103,6 +2168,11 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     schemas = {}
     # rows of every source on each device: what a device is given to scan
     device_rows = np.zeros(n_dev, np.int64)
+    # what this attempt reads of `_DEVICE_SHARDS`: the budget evicts none
+    # of it while the attempt stores the rest
+    shard_reads = {_DEVICE_SHARDS.key_of(table, shard_key)
+                   for table in source_tables.values()}
+    placed = dict.fromkeys(SHARD_COUNTS, 0)
     with tracing.span("spmd.shard", cat="spmd",
                       sources=len(source_tables)) as shard_span:
         for rid, table in source_tables.items():
@@ -2130,7 +2200,11 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                         # is the transfer and not its enqueue (untraced,
                         # the wait falls to the stage program's start)
                         jax.block_until_ready((e["cols"], e["live"]))
-                _DEVICE_SHARDS.put(table, e, shard_key)
+                placed["evicted"] += _DEVICE_SHARDS.put(
+                    table, e, shard_key, shard_reads)
+                placed["placed"] += 1
+            else:
+                placed["cached"] += 1
             host_inputs[rid] = (e["cols"], e["live"])
             schemas[rid] = e["schema"]
             if n_dev > 1 and stats is not None:
@@ -2138,9 +2212,14 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     "rows": table.num_rows,
                     "cap": e["live"].shape[0] // n_dev,
                     "rows_max": max(dealt), "rows_min": min(dealt)}
+        placed.update(held_bytes=_DEVICE_SHARDS.held_bytes(),
+                      over_budget_bytes=_DEVICE_SHARDS.over_budget_bytes())
+        shard_span.set_args(**placed)
         if n_dev > 1 and shard_span.armed:
             shard_span.set_args(device_rows_max=int(device_rows.max()),
                                 device_rows_min=int(device_rows.min()))
+    if stats is not None:
+        _add_attempt(stats.setdefault("shard", {}), placed)
     # program cache: repeat executions of the SAME converted plan over the
     # same input shapes reuse the compiled shard_map program (a fresh
     # jax.jit closure per call would re-trace+re-compile every time)
@@ -2443,7 +2522,7 @@ def _materialize_scans(plan, conv_ctx):
     no device batch lies between a file and `_shard_table`.  rids are
     deterministic walk-order indexes so the compiled program's binding
     structure is stable across conversions.  Returns (rids, tables, what
-    was read: `INGEST_COUNTS`).
+    was read and what `_SCAN_TABLES` did: `INGEST_COUNTS`, `CACHE_STATE`).
 
     Scan PARTITIONS read in parallel on a thread pool (round-3 fix: one
     host thread serially materializing every split was the wall at
@@ -2459,6 +2538,10 @@ def _materialize_scans(plan, conv_ctx):
     nodes: Dict[str, Any] = {}
     fps: Dict[str, Optional[Tuple]] = {}
     cached: Dict[str, Any] = {}
+    # what this attempt reads of `_SCAN_TABLES`, by key: every leaf's
+    # entry, served or about to be stored, which the stores below do not
+    # evict
+    reads = set()
     jobs: List[Tuple[str, Any, int, int]] = []
     for node in _walk_native(plan, conv_ctx):
         if node.kind not in ("parquet_scan", "orc_scan"):
@@ -2471,6 +2554,7 @@ def _materialize_scans(plan, conv_ctx):
         # fingerprint BEFORE reading (a rewrite during the read changes
         # the fp the next lookup computes -> stale entry never matches)
         fps[rid] = _scan_files_fp(node)
+        reads.add((node, fps[rid]))
         hit = _SCAN_TABLES.get(node, fps[rid])
         if hit is not None:
             # same table OBJECT across executes -> the device shard
@@ -2489,7 +2573,7 @@ def _materialize_scans(plan, conv_ctx):
 
     results = run_tasks(read, jobs, "auron-scan")
 
-    counts = dict.fromkeys(INGEST_COUNTS, 0)
+    counts = dict.fromkeys(INGEST_COUNTS + CACHE_STATE, 0)
     counts.update(scans=len(nodes), cached=len(cached), tasks=len(jobs))
     per_rid: Dict[str, Dict[int, Any]] = {}
     for rid, pid, res in results:
@@ -2505,8 +2589,10 @@ def _materialize_scans(plan, conv_ctx):
         # the partition columns), which every batch of it has
         t = pa.Table.from_batches(batches, schema=parts[0].schema)
         tables[rid] = t
-        _SCAN_TABLES.put(node, fps[rid], t)
+        counts["evicted"] += _SCAN_TABLES.put(node, fps[rid], t, reads)
         counts["batches"] += len(batches)
         counts["rows"] += t.num_rows
         counts["bytes"] += t.nbytes
+    counts.update(held_bytes=_SCAN_TABLES.held_bytes(),
+                  over_budget_bytes=_SCAN_TABLES.over_budget_bytes())
     return rids, tables, counts

@@ -345,7 +345,8 @@ def test_one_device_counts_nothing(runs):
     _cat, _params, session, plan, _got = runs[SEEDS[0]]
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
-    assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_probes"]
+    assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_probes",
+                                       "shard"]
     assert one.stage_stats["agg_inputs"] == {}
     # the driver's own count of what the scan leaves' tasks read (PR 31)
     assert one.stage_stats["ingest"]["scans"] == 5
@@ -355,7 +356,19 @@ def test_one_device_counts_nothing(runs):
     assert not set(_TOTALS) & set(_wait_args(one))
     assert totals["join_probes_direct"] == 4
     shard = [s.args for s in one.trace.snapshot() if s.name == "spmd.shard"]
-    assert shard == [{"sources": 5}]
+    # what `_DEVICE_SHARDS` did for the attempt (PR 33): each source
+    # served or placed (shards placed for four devices serve no other
+    # mesh; an earlier one-device run's do), nothing evicted, nothing
+    # past the budget
+    [shard] = shard
+    assert shard == {"sources": 5, "cached": shard["cached"],
+                     "placed": 5 - shard["cached"], "evicted": 0,
+                     "held_bytes": shard["held_bytes"],
+                     "over_budget_bytes": 0}
+    assert shard["cached"] in (0, 5)
+    assert shard["held_bytes"] > 0
+    assert one.stage_stats["shard"] == {
+        k: shard[k] for k in S.SHARD_COUNTS}
     text = one.explain_analyze()
     assert " slots=" not in text and " quota=" not in text
 

@@ -265,7 +265,9 @@ def test_ingest_hands_on_the_arrow_the_scan_read(fmt, case, tmp_path):
     assert read == {
         "scans": 1, "cached": 0, "tasks": max(1, len(node.file_groups)),
         "batches": len(kept), "rows": want.num_rows, "bytes": got.nbytes,
-        "device_batches": 0}
+        "device_batches": 0,
+        # and `_SCAN_TABLES` holds it, inside its budget
+        "evicted": 0, "held_bytes": got.nbytes, "over_budget_bytes": 0}
     assert len(kept) == len(got.to_batches()) and \
         all(b.num_rows <= BATCH for b in kept)
     if case == "pruned_row_group" and fmt == "parquet":
@@ -471,7 +473,8 @@ def test_spmd_ingest_reports_what_it_read_and_what_was_cached(fmt, tmp_path):
         "rows": 2 * ROWS, "device_batches": 0}
     assert span.args["bytes"] == \
         S._SCAN_TABLES.get(node, S._scan_files_fp(node)).nbytes > 0
-    assert stats["ingest"] == {k: span.args[k] for k in S.INGEST_COUNTS}
+    assert stats["ingest"] == {k: span.args[k]
+                               for k in S.INGEST_COUNTS + S.CACHE_STATE}
     assert S.stage_totals(stats)["scan_rows"] == 2 * ROWS
     # unchanged files: every leaf out of `_SCAN_TABLES`, no task
     again = {}
@@ -510,7 +513,7 @@ def test_scan_totals_in_the_query_record(tmp_path):
         assert ingest["rows"] > 0 and ingest["device_batches"] == 0
         assert {k: totals[k] for k in totals if k.startswith("scan_")} == {
             "scan_rows": ingest["rows"], "scan_batches": ingest["batches"],
-            "scan_device_batches": 0}
+            "scan_device_batches": 0, "scan_cached": 0}
         assert res.stage_totals()["scan_rows"] == ingest["rows"]
         warm = session.execute(queries.build("q03", catalog))
         assert warm.stage_stats["ingest"]["cached"] == 3
