@@ -16,8 +16,9 @@ Conversions are vectorized numpy (no per-row Python):
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
@@ -53,56 +54,94 @@ def arrow_to_batch(rb: pa.RecordBatch, capacity: Optional[int] = None,
 def arrow_array_to_column(dt: DataType, arr: pa.Array, cap: int) -> Column:
     if isinstance(arr, pa.ChunkedArray):
         arr = arr.combine_chunks()
+    col = arrow_array_to_host_column(dt, arr, cap)
+    if isinstance(col, HostColumn):
+        return col
+    return jax.tree.map(jnp.asarray, col)
+
+
+def arrow_array_to_host_column(dt: DataType,
+                               arr: Union[pa.Array, pa.ChunkedArray], cap: int,
+                               dealt: Optional[Sequence[int]] = None
+                               ) -> Column:
+    """The host half of `arrow_array_to_column`: the padded column with
+    numpy leaves, or the verdict `HostColumn`.
+
+    `arr` is an Array or a ChunkedArray, read chunk by chunk.  `dealt` says
+    how many of its rows, in order, go to each of `len(dealt)` parts of
+    `cap` slots (default: one part, all rows): part d's rows lie from slot
+    d * cap on, and every slot past them is zero and invalid."""
     from auron_tpu.columnar.serde import note_copy
-    n = len(arr)
     if not is_device_type(dt):
         return HostColumn(dt, arr)
-    validity = np.zeros(cap, dtype=bool)
-    validity[:n] = _arrow_validity(arr)
+    n = len(arr)
+    dealt = [n] if dealt is None else dealt
+    slots = len(dealt) * cap
+    # (chunk, its first slot), zero-copy slices of `arr`
+    pieces: List[Tuple[pa.Array, int]] = []
+    row = 0
+    for d, rows in enumerate(dealt):
+        part = arr.slice(row, rows)
+        at = d * cap
+        for chunk in (part.chunks if isinstance(part, pa.ChunkedArray)
+                      else [part]):
+            if len(chunk):
+                pieces.append((chunk, at))
+                at += len(chunk)
+        row += rows
+    validity = np.zeros(slots, dtype=bool)
+    for chunk, at in pieces:
+        validity[at: at + len(chunk)] = _arrow_validity(chunk)
     if dt.is_stringlike:
         note_copy("ingest.arrow.string")
-        lengths, flat = _arrow_string_parts(arr)
-        max_len = int(lengths.max()) if n else 0
+        parts = [_arrow_string_parts(chunk) for chunk, _ in pieces]
+        max_len = max((int(lengths.max()) for lengths, _ in parts),
+                      default=0)
         if max_len > int(conf.get("auron.string.device.max.width")):
             return HostColumn(dt, arr)
         w = bucket_width(max(max_len, 1))
-        mat = np.zeros((cap, w), dtype=np.uint8)
-        if n:
+        mat = np.zeros((slots, w), dtype=np.uint8)
+        ln = np.zeros(slots, dtype=np.int32)
+        for (chunk, at), (lengths, flat) in zip(pieces, parts):
+            rows = slice(at, at + len(chunk))
             row_ids, within, src = _scatter_indices(lengths, w)
-            mat[row_ids, within] = flat[src]
-            mat[:n][~validity[:n]] = 0
-        ln = np.zeros(cap, dtype=np.int32)
-        if n:
-            ln[:n] = np.where(validity[:n], lengths, 0)
-        return DeviceStringColumn(dt, jnp.asarray(mat), jnp.asarray(ln),
-                                  jnp.asarray(validity))
+            mat[rows][row_ids, within] = flat[src]
+            mat[rows][~validity[rows]] = 0
+            ln[rows] = np.where(validity[rows], lengths, 0)
+        return DeviceStringColumn(dt, mat, ln, validity)
     # flat types: read raw fixed-width values straight from the Arrow values
     # buffer (null slots hold garbage, masked below), avoiding to_numpy's
     # object-dtype detours for date/timestamp/decimal.
     npdt = dt.numpy_dtype()
-    data = np.zeros(cap, dtype=npdt)
+    data = np.zeros(slots, dtype=npdt)
     if n:
         note_copy("ingest.arrow.fixed")
+    for chunk, at in pieces:
+        rows = slice(at, at + len(chunk))
         if dt.id == TypeId.DECIMAL:
-            vals = _decimal128_unscaled_int64(arr)
+            vals = _decimal128_unscaled_int64(chunk)
         elif dt.id == TypeId.TIMESTAMP_US:
-            if not (pa.types.is_timestamp(arr.type) and arr.type.unit == "us"):
-                arr = arr.cast(pa.timestamp("us"))
-            vals = _primitive_values(arr, np.int64)
+            if not (pa.types.is_timestamp(chunk.type)
+                    and chunk.type.unit == "us"):
+                chunk = chunk.cast(pa.timestamp("us"))
+            vals = _primitive_values(chunk, np.int64)
         elif dt.id == TypeId.BOOL:
-            vals = _bitpacked_values(arr)
+            vals = _bitpacked_values(chunk)
         else:
-            phys = arr.type
-            if pa.types.is_dictionary(phys):
-                arr = arr.dictionary_decode()
-            vals = _primitive_values(arr, None).astype(npdt, copy=False)
-        data[:n] = np.where(validity[:n], vals, 0)
+            if pa.types.is_dictionary(chunk.type):
+                chunk = chunk.dictionary_decode()
+            vals = _primitive_values(chunk, None).astype(npdt, copy=False)
+        if chunk.null_count == 0:
+            data[rows] = vals
+        else:
+            # `data` is zero where a null leaves it alone
+            np.copyto(data[rows], vals, where=validity[rows])
     bits = None
     if dt.id == TypeId.FLOAT64:
         from auron_tpu.ops.sort_keys import f64_exact_bits_enabled
         if f64_exact_bits_enabled():
-            bits = jnp.asarray(data.view(np.uint64))
-    return DeviceColumn(dt, jnp.asarray(data), jnp.asarray(validity), bits)
+            bits = data.view(np.uint64)
+    return DeviceColumn(dt, data, validity, bits)
 
 
 def _arrow_validity(arr: pa.Array) -> np.ndarray:
@@ -134,20 +173,21 @@ def _primitive_values(arr: pa.Array, npdt) -> np.ndarray:
 
 
 def _bitpacked_values(arr: pa.Array) -> np.ndarray:
-    buf = arr.buffers()[1]
-    bits = np.unpackbits(np.frombuffer(buf, dtype=np.uint8), bitorder="little")
-    return bits[arr.offset: arr.offset + len(arr)].astype(bool)
+    # the bytes that hold the array's bits, not the whole buffer: a chunk
+    # may be a short slice of a long one
+    lo, hi = arr.offset // 8, -(-(arr.offset + len(arr)) // 8)
+    buf = np.frombuffer(arr.buffers()[1], dtype=np.uint8)[lo:hi]
+    bits = np.unpackbits(buf, bitorder="little")
+    return bits[arr.offset - 8 * lo:][:len(arr)].astype(bool)
 
 
 def _decimal128_unscaled_int64(arr: pa.Array) -> np.ndarray:
     """decimal128 values buffer is 16-byte LE two's-complement; for p<=18 the
     value fits the low word (high word is the sign extension)."""
-    arr = arr.combine_chunks() if isinstance(arr, pa.ChunkedArray) else arr
     buf = arr.buffers()[1]
     off = arr.offset
-    raw = np.frombuffer(buf, dtype=np.uint64)
-    lo = raw[0 + 2 * off: 2 * (off + len(arr)): 2]
-    return lo.view(np.int64).copy()
+    raw = np.frombuffer(buf, dtype=np.int64)
+    return raw[2 * off: 2 * (off + len(arr)): 2]
 
 
 def _arrow_string_parts(arr: pa.Array) -> Tuple[np.ndarray, np.ndarray]:

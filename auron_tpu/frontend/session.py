@@ -121,7 +121,9 @@ class SessionResult:
         0 where every leaf was cached) and `scan_device_batches` (those of
         them that were device batches on the way; 0 expected);
         `scan_cached` and `shards_cached` (scan leaves and sources the
-        two source caches served), `source_evictions` (entries this
+        two source caches served), `shard_put_bytes` (bytes of the sources
+        they did not serve, padded on the host and put on the device;
+        0 where every source was cached), `source_evictions` (entries this
         execute's stores evicted from them) and `source_over_budget_bytes`
         (bytes they hold past their budgets because this execute reads
         them; 0 where a query fits the budgets);

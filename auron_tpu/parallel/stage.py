@@ -53,7 +53,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from auron_tpu.columnar.batch import (
-    Batch, DeviceColumn, DeviceStringColumn, HostColumn, bucket_capacity,
+    DeviceColumn, DeviceStringColumn, HostColumn, bucket_capacity,
 )
 from auron_tpu.exprs import hashing as H
 from auron_tpu.exprs.compiler import EvalCtx, device_capable, evaluate
@@ -1386,55 +1386,25 @@ def _rows_per_device(n: int, n_dev: int) -> List[int]:
 def _shard_table(table, mesh: Mesh, axis: str) -> Tuple[Schema, List[Any],
                                                         Array, int]:
     """Split an arrow table row-wise across the mesh: returns flat arrays
-    of shape [n_dev*cap] (sharded along the axis) + live mask."""
-    import pyarrow as pa
+    of shape [n_dev*cap] (to be sharded along the axis) + live mask.  The
+    columns' leaves are numpy arrays, built on the host at their final
+    shape: `jax.device_put` under the mesh's sharding is their one
+    crossing, each device's rows straight to it."""
+    from auron_tpu.columnar.arrow_interop import arrow_array_to_host_column
     from auron_tpu.ir.schema import from_arrow_schema
     n_dev = int(np.prod([mesh.shape[a] for a in axis])) \
         if isinstance(axis, tuple) else mesh.shape[axis]
-    n = table.num_rows
-    per_dev = -(-max(n, 1) // n_dev)
-    cap = bucket_capacity(per_dev)
+    dealt = _rows_per_device(table.num_rows, n_dev)
+    cap = bucket_capacity(max(dealt))
     schema = from_arrow_schema(table.schema)
-    dev_batches = []
-    for d in range(n_dev):
-        chunk = table.slice(d * per_dev, per_dev)
-        arrays = [c.combine_chunks() if c.num_chunks else
-                  pa.array([], type=c.type) for c in chunk.columns]
-        rb = pa.RecordBatch.from_arrays(arrays, schema=table.schema)
-        b = Batch.from_arrow(rb, capacity=cap, schema=schema)
-        if b.has_host_columns():
-            raise SpmdUnsupported("host-resident column in SPMD source")
-        dev_batches.append(b)
-    # normalize string widths across shards, then stack host-side
     cols: List[Any] = []
-    for ci, f in enumerate(schema):
-        parts = [db.columns[ci] for db in dev_batches]
-        if isinstance(parts[0], DeviceStringColumn):
-            w = max(p.width for p in parts)
-            data = np.concatenate([
-                np.pad(np.asarray(p.data), ((0, 0), (0, w - p.width)))
-                for p in parts])
-            cols.append(DeviceStringColumn(
-                f.dtype, jnp.asarray(data),
-                jnp.asarray(np.concatenate(
-                    [np.asarray(p.lengths) for p in parts])),
-                jnp.asarray(np.concatenate(
-                    [np.asarray(p.validity) for p in parts]))))
-        else:
-            bits = None
-            if all(p.bits is not None for p in parts):
-                # keep the exact-f64 sidecar across the shard stack (all
-                # parts come from Batch.from_arrow, so presence is uniform)
-                bits = jnp.asarray(np.concatenate(
-                    [np.asarray(p.bits) for p in parts]))
-            cols.append(DeviceColumn(
-                f.dtype,
-                jnp.asarray(np.concatenate(
-                    [np.asarray(p.data) for p in parts])),
-                jnp.asarray(np.concatenate(
-                    [np.asarray(p.validity) for p in parts])), bits))
+    for f, arr in zip(schema, table.columns):
+        col = arrow_array_to_host_column(f.dtype, arr, cap, dealt)
+        if isinstance(col, HostColumn):
+            raise SpmdUnsupported("host-resident column in SPMD source")
+        cols.append(col)
     live = np.zeros(n_dev * cap, bool)
-    for d, got in enumerate(_rows_per_device(n, n_dev)):
+    for d, got in enumerate(dealt):
         live[d * cap: d * cap + got] = True
     return schema, cols, jnp.asarray(live), cap
 
@@ -2021,9 +1991,10 @@ INGEST_COUNTS = ("scans", "cached", "tasks", "batches", "rows", "bytes",
 # (kept because the attempt reads them; 0 where a query fits the budget)
 CACHE_STATE = ("evicted", "held_bytes", "over_budget_bytes")
 # what `spmd.shard` reports of an attempt's sources: those
-# `_DEVICE_SHARDS` served, those padded and put, and `CACHE_STATE` of
-# that cache, in device bytes counted from the arrays
-SHARD_COUNTS = ("cached", "placed") + CACHE_STATE
+# `_DEVICE_SHARDS` served, those padded and put, the bytes `shard.put`
+# handed to `device_put` for them (their one crossing), and `CACHE_STATE`
+# of that cache, in device bytes counted from the arrays
+SHARD_COUNTS = ("cached", "placed", "shard_put_bytes") + CACHE_STATE
 # the two of them that say where a cache stands, not what an attempt did:
 # over an execute's attempts the last reading stands, the others add up
 _GAUGES = ("held_bytes", "over_budget_bytes")
@@ -2045,6 +2016,7 @@ def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
     return {**{"scan_" + name: ingest[name]
                for name in ("rows", "batches", "device_batches", "cached")},
             "shards_cached": shard["cached"],
+            "shard_put_bytes": shard["shard_put_bytes"],
             "source_evictions": ingest["evicted"] + shard["evicted"],
             "source_over_budget_bytes": (ingest["over_budget_bytes"]
                                          + shard["over_budget_bytes"])}
@@ -2185,15 +2157,23 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                                            axis)
                     if sp.armed:
                         sp.set_args(rows=table.num_rows, cap=cap,
-                                    bytes=table.nbytes)
+                                    bytes=table.nbytes,
+                                    host_bytes=sum(
+                                        x.nbytes
+                                        for x in jax.tree.leaves(cols)
+                                        if isinstance(x, np.ndarray)))
                         if n_dev > 1:
                             sp.set_args(rows_max=max(dealt),
                                         rows_min=min(dealt))
                 with tracing.span("shard.put", cat="spmd") as sp:
+                    handed = jax.tree.leaves((cols, live))
+                    put_bytes = sum(x.nbytes for x in handed)
                     e = {"schema": schema,
                          "cols": jax.tree.map(
                              lambda x: jax.device_put(x, sharded), cols),
                          "live": jax.device_put(live, sharded)}
+                    sp.set_args(bytes=put_bytes, arrays=len(handed))
+                    placed["shard_put_bytes"] += put_bytes
                     if sp.armed:
                         # device_put returns before the bytes have moved:
                         # a traced run waits for them here, so the span

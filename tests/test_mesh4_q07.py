@@ -362,10 +362,13 @@ def test_one_device_counts_nothing(runs):
     # past the budget
     [shard] = shard
     assert shard == {"sources": 5, "cached": shard["cached"],
-                     "placed": 5 - shard["cached"], "evicted": 0,
+                     "placed": 5 - shard["cached"],
+                     "shard_put_bytes": shard["shard_put_bytes"],
+                     "evicted": 0,
                      "held_bytes": shard["held_bytes"],
                      "over_budget_bytes": 0}
     assert shard["cached"] in (0, 5)
+    assert (shard["shard_put_bytes"] > 0) == (shard["placed"] > 0)
     assert shard["held_bytes"] > 0
     assert one.stage_stats["shard"] == {
         k: shard[k] for k in S.SHARD_COUNTS}

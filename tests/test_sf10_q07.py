@@ -169,6 +169,8 @@ def test_from_the_second_execute_on_nothing_is_read_or_placed(runs, seed):
     assert _span_args(got[0], "spmd.shard")["placed"] == 5
     arrow_bytes = first["held_bytes"]
     device_bytes = _span_args(got[0], "spmd.shard")["held_bytes"]
+    assert _span_args(got[0], "spmd.shard")["shard_put_bytes"] == \
+        device_bytes
     assert arrow_bytes == first["bytes"] > 0 and device_bytes > 0
     for res in got[1:]:
         ingest = _span_args(res, "spmd.ingest")
@@ -181,7 +183,7 @@ def test_from_the_second_execute_on_nothing_is_read_or_placed(runs, seed):
             "over_budget_bytes": 0}
         shard = _span_args(res, "spmd.shard")
         assert {k: shard[k] for k in S.SHARD_COUNTS} == {
-            "cached": 5, "placed": 0, "evicted": 0,
+            "cached": 5, "placed": 0, "shard_put_bytes": 0, "evicted": 0,
             "held_bytes": device_bytes, "over_budget_bytes": 0}
         names = [s.name for s in res.trace.snapshot()]
         assert "shard.pad" not in names and "shard.put" not in names

@@ -141,9 +141,10 @@ def test_a_leaf_over_the_budget_stays_beside_its_plans_leaves(tmp_path):
         [shard] = named(spans, "spmd.shard")
         device_bytes = shard.args["held_bytes"]
         assert device_bytes > fact_table().nbytes
+        # what was put is what is held: every source crossed once
         assert {k: shard.args[k] for k in S.SHARD_COUNTS} == {
-            "cached": 0, "placed": 3, "evicted": 0,
-            "held_bytes": device_bytes,
+            "cached": 0, "placed": 3, "shard_put_bytes": device_bytes,
+            "evicted": 0, "held_bytes": device_bytes,
             "over_budget_bytes": device_bytes - MB}
         assert len(named(spans, "shard.pad")) == 3
         # every leaf is there, the heavy one among them
@@ -164,18 +165,20 @@ def test_a_leaf_over_the_budget_stays_beside_its_plans_leaves(tmp_path):
             assert named(spans2, name) == [], name
         [shard2] = named(spans2, "spmd.shard")
         assert {k: shard2.args[k] for k in S.SHARD_COUNTS} == {
-            "cached": 3, "placed": 0, "evicted": 0,
+            "cached": 3, "placed": 0, "shard_put_bytes": 0, "evicted": 0,
             "held_bytes": device_bytes,
             "over_budget_bytes": device_bytes - MB}
         # and as query totals, where `scan_rows` goes
         assert S.stage_totals(again) == {
             "scan_rows": 0, "scan_batches": 0, "scan_device_batches": 0,
-            "scan_cached": 3, "shards_cached": 3, "source_evictions": 0,
+            "scan_cached": 3, "shards_cached": 3, "shard_put_bytes": 0,
+            "source_evictions": 0,
             "source_over_budget_bytes":
                 arrow_bytes - MB + device_bytes - MB,
             "join_probes": 2, "join_probes_direct": 2,
             "agg_inputs": 0, "agg_inputs_compact": 0}
         assert S.stage_totals(first)["scan_cached"] == 0
+        assert S.stage_totals(first)["shard_put_bytes"] == device_bytes
         assert S.stage_totals(first)["scan_rows"] == FACT_ROWS + 2 * KEYS
 
 
@@ -444,7 +447,8 @@ def test_a_guard_retry_reads_nothing_again(tmp_path):
     assert stats["ingest"]["over_budget_bytes"] == \
         second.args["held_bytes"] - MB > 0
     assert stats["shard"] == {
-        "cached": 2, "placed": 2, "evicted": 0,
+        "cached": 2, "placed": 2,
+        "shard_put_bytes": S._DEVICE_SHARDS.held_bytes(), "evicted": 0,
         "held_bytes": S._DEVICE_SHARDS.held_bytes(),
         "over_budget_bytes": S._DEVICE_SHARDS.held_bytes() - MB}
 

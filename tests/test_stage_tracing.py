@@ -402,6 +402,50 @@ def test_join_probe_counter_in_the_record_the_span_and_explain(tmp_path):
         tracing.find_query(serial.query_id).metric_totals
 
 
+def test_what_crossed_to_the_device_in_the_spans_and_the_record(tmp_path):
+    """A source the device cache does not serve is padded on the host
+    (`shard.pad`'s `host_bytes`) and crosses once (`shard.put`'s `bytes`
+    in `arrays` arrays): what was put is what was built plus the live
+    mask, and what the cache then holds; summed as `shard_put_bytes` on
+    `spmd.shard`, in `stage_stats` and in the query record's totals.  An
+    execute whose sources are all cached pads and puts nothing."""
+    from auron_tpu.frontend.session import AuronSession
+    from auron_tpu.it import queries
+    from auron_tpu.it.datagen import generate
+    from auron_tpu.it.oracle import PyArrowEngine
+    catalog = generate(str(tmp_path / "tpcds"), sf=0.002)
+    session = AuronSession(foreign_engine=PyArrowEngine())
+    S.clear_source_caches()
+    with conf.scoped({"auron.trace.enable": True}):
+        first, again = (session.execute(queries.build("q03", catalog))
+                        for _ in range(2))
+    assert first.spmd and again.spmd
+    spans = [s for s in first.trace.snapshot() if s.dur_ns >= 0]
+    [shard] = [s for s in spans if s.name == "spmd.shard"]
+    pads = [s for s in spans if s.name == "shard.pad"]
+    puts = [s for s in spans if s.name == "shard.put"]
+    assert len(pads) == len(puts) == shard.args["placed"] > 0
+    assert all(s.parent == shard.id for s in pads + puts)
+    for pad, put in zip(pads, puts):
+        live_bytes = pad.args["cap"]      # one device, a byte a slot
+        assert put.args["bytes"] == pad.args["host_bytes"] + live_bytes
+        assert put.args["arrays"] >= 3    # a column's two, and live
+    put_bytes = sum(s.args["bytes"] for s in puts)
+    assert shard.args["shard_put_bytes"] == put_bytes == \
+        shard.args["held_bytes"]
+    assert first.stage_stats["shard"]["shard_put_bytes"] == put_bytes
+    assert first.stage_totals()["shard_put_bytes"] == put_bytes
+    assert tracing.find_query(first.query_id).metric_totals[
+        "shard_put_bytes"] == put_bytes
+    names = {s.name for s in again.trace.snapshot()}
+    assert "shard.pad" not in names and "shard.put" not in names
+    [shard] = [s for s in again.trace.snapshot() if s.name == "spmd.shard"]
+    assert (shard.args["cached"], shard.args["placed"],
+            shard.args["shard_put_bytes"]) == (len(pads), 0, 0)
+    assert tracing.find_query(again.query_id).metric_totals[
+        "shard_put_bytes"] == 0
+
+
 # name, scope path, start_ns, duration_ns
 OPS = [
     ("while.1", "jit(program)/agg#0/reduce/while", 0.0, 100.0),
