@@ -472,7 +472,7 @@ _MIN_TILE_ROWS = 8 * _LANES
 
 
 def _host_resident(dt: DataType) -> bool:
-    return dt.is_nested or (dt.id == TypeId.DECIMAL and dt.precision > 18)
+    return dt.host_resident
 
 
 class TpuLintPass(Pass):

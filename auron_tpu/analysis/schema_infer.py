@@ -246,8 +246,7 @@ def _agg_state_fields(ctx: SchemaContext, a: AggExpr, name: str,
 
     def device(dt: DataType) -> bool:
         # columnar.batch.is_device_type without the jax import
-        return not dt.is_nested and \
-            not (dt.id == TypeId.DECIMAL and dt.precision > 18)
+        return not dt.host_resident
 
     def flat_numeric(dt: DataType) -> bool:
         return device(dt) and not dt.is_stringlike

@@ -11,7 +11,9 @@ Conversions are vectorized numpy (no per-row Python):
 - flat types: fill_null + astype + pad
 - decimal128(p<=18): unscaled int64 extracted from the 16-byte LE values
 - strings/binary: offsets+data -> fixed-width padded [cap, W] uint8 matrix
-- nested / decimal(p>18) / oversize strings: host-resident passthrough
+- nested / decimal(p>18) / oversize strings: host-resident passthrough; for
+  the stage program (`wide=True`) decimal(p>18) is the two words of each
+  16-byte value (batch.py `DeviceDecimal128Column`)
 """
 
 from __future__ import annotations
@@ -25,8 +27,8 @@ import pyarrow as pa
 
 from auron_tpu.config import conf
 from auron_tpu.columnar.batch import (
-    Batch, Column, DeviceColumn, DeviceStringColumn, HostColumn,
-    bucket_capacity, bucket_width, is_device_type,
+    Batch, Column, DeviceColumn, DeviceDecimal128Column, DeviceStringColumn,
+    HostColumn, bucket_capacity, bucket_width, is_device_type, stage_holds,
 )
 from auron_tpu.ir.schema import (
     DataType, Schema, TypeId, from_arrow_schema, to_arrow_schema, to_arrow_type,
@@ -62,17 +64,19 @@ def arrow_array_to_column(dt: DataType, arr: pa.Array, cap: int) -> Column:
 
 def arrow_array_to_host_column(dt: DataType,
                                arr: Union[pa.Array, pa.ChunkedArray], cap: int,
-                               dealt: Optional[Sequence[int]] = None
-                               ) -> Column:
+                               dealt: Optional[Sequence[int]] = None,
+                               wide: bool = False) -> Column:
     """The host half of `arrow_array_to_column`: the padded column with
     numpy leaves, or the verdict `HostColumn`.
 
     `arr` is an Array or a ChunkedArray, read chunk by chunk.  `dealt` says
     how many of its rows, in order, go to each of `len(dealt)` parts of
     `cap` slots (default: one part, all rows): part d's rows lie from slot
-    d * cap on, and every slot past them is zero and invalid."""
+    d * cap on, and every slot past them is zero and invalid.  `wide`: the
+    caller is the stage program, which holds a decimal of 19-38 digits as
+    two words a value where the serial engine keeps it on the host."""
     from auron_tpu.columnar.serde import note_copy
-    if not is_device_type(dt):
+    if not (stage_holds(dt) if wide else is_device_type(dt)):
         return HostColumn(dt, arr)
     n = len(arr)
     dealt = [n] if dealt is None else dealt
@@ -109,6 +113,17 @@ def arrow_array_to_host_column(dt: DataType,
             mat[rows][~validity[rows]] = 0
             ln[rows] = np.where(validity[rows], lengths, 0)
         return DeviceStringColumn(dt, mat, ln, validity)
+    if dt.is_wide_decimal:
+        note_copy("ingest.arrow.fixed")
+        hi = np.zeros(slots, dtype=np.int64)
+        lo = np.zeros(slots, dtype=np.uint64)
+        for chunk, at in pieces:
+            rows = slice(at, at + len(chunk))
+            words = _decimal128_words(chunk)
+            np.copyto(lo[rows], words[:, 0].view(np.uint64),
+                      where=validity[rows])
+            np.copyto(hi[rows], words[:, 1], where=validity[rows])
+        return DeviceDecimal128Column(dt, hi, lo, validity)
     # flat types: read raw fixed-width values straight from the Arrow values
     # buffer (null slots hold garbage, masked below), avoiding to_numpy's
     # object-dtype detours for date/timestamp/decimal.
@@ -181,13 +196,17 @@ def _bitpacked_values(arr: pa.Array) -> np.ndarray:
     return bits[arr.offset - 8 * lo:][:len(arr)].astype(bool)
 
 
+def _decimal128_words(arr: pa.Array) -> np.ndarray:
+    """int64[n, 2] view of a decimal128 values buffer: 16-byte LE
+    two's-complement, the low word first."""
+    raw = np.frombuffer(arr.buffers()[1], dtype=np.int64)
+    return raw[2 * arr.offset: 2 * (arr.offset + len(arr))].reshape(-1, 2)
+
+
 def _decimal128_unscaled_int64(arr: pa.Array) -> np.ndarray:
-    """decimal128 values buffer is 16-byte LE two's-complement; for p<=18 the
-    value fits the low word (high word is the sign extension)."""
-    buf = arr.buffers()[1]
-    off = arr.offset
-    raw = np.frombuffer(buf, dtype=np.int64)
-    return raw[2 * off: 2 * (off + len(arr)): 2]
+    """For p<=18 the value fits the low word (the high word is the sign
+    extension)."""
+    return _decimal128_words(arr)[:, 0]
 
 
 def _arrow_string_parts(arr: pa.Array) -> Tuple[np.ndarray, np.ndarray]:
@@ -289,6 +308,14 @@ def column_to_arrow(dt: DataType, col: Column, n: int) -> pa.Array:
             [pa.py_buffer(np.packbits(valid, bitorder="little").tobytes()),
              pa.py_buffer(offsets.tobytes()), pa.py_buffer(flat.tobytes())])
         return arr.cast(at) if arr.type != at else arr
+    if isinstance(col, DeviceDecimal128Column):
+        valid = np.asarray(col.validity)[:n]
+        pairs = np.empty((n, 2), dtype=np.int64)
+        pairs[:, 0] = np.asarray(col.lo)[:n].view(np.int64)
+        pairs[:, 1] = np.asarray(col.hi)[:n]
+        return pa.Array.from_buffers(
+            at, n, [pa.py_buffer(np.packbits(valid, bitorder="little")
+                                 .tobytes()), pa.py_buffer(pairs.tobytes())])
     # flat
     data = np.asarray(col.data)[:n]
     if dt.id == TypeId.FLOAT64 and getattr(col, "bits", None) is not None:

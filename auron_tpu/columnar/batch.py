@@ -130,6 +130,51 @@ class DeviceStringColumn:
 
 
 @dataclass
+class DeviceDecimal128Column:
+    """A DECIMAL of 19-38 digits in the stage program: the unscaled value
+    as a 128-bit two's-complement integer in two 64-bit words a row,
+    `hi` int64[capacity] (the signed high word) and `lo` uint64[capacity]
+    (the low word), value = hi * 2**64 + lo, beside the validity word every
+    column has; null and padding rows hold zeros.  THE layout: Arrow's
+    decimal128 is the same sixteen bytes (low word first), so ingest and
+    fetch are views (arrow_interop.py), and exprs/decimal128.py is the
+    arithmetic over it.  It lives in the stage program alone
+    (`stage_holds`); the serial engine keeps the type on the host
+    (`is_device_type`)."""
+    dtype: DataType
+    hi: Array         # int64 [capacity]
+    lo: Array         # uint64 [capacity]
+    validity: Array   # bool [capacity]
+
+    @property
+    def capacity(self) -> int:
+        return int(self.hi.shape[0])
+
+    def gather(self, indices: Array, valid: Array) -> "DeviceDecimal128Column":
+        h = jnp.where(valid, jnp.take(self.hi, indices, axis=0,
+                                      mode="fill", fill_value=0), 0)
+        l = jnp.where(valid, jnp.take(self.lo, indices, axis=0,
+                                      mode="fill", fill_value=0),
+                      jnp.uint64(0))
+        v = jnp.where(valid, jnp.take(self.validity, indices, axis=0,
+                                      mode="fill", fill_value=False), False)
+        return DeviceDecimal128Column(self.dtype, h, l, v)
+
+    def masked(self, keep: Array) -> "DeviceDecimal128Column":
+        """Rows outside `keep` become null (and zero)."""
+        return DeviceDecimal128Column(
+            self.dtype, jnp.where(keep, self.hi, 0),
+            jnp.where(keep, self.lo, jnp.uint64(0)),
+            jnp.logical_and(self.validity, keep))
+
+    @staticmethod
+    def nulls(dtype: DataType, capacity: int) -> "DeviceDecimal128Column":
+        return DeviceDecimal128Column(
+            dtype, jnp.zeros(capacity, jnp.int64),
+            jnp.zeros(capacity, jnp.uint64), jnp.zeros(capacity, bool))
+
+
+@dataclass
 class HostColumn:
     """Host-resident column for nested / oversized values (pyarrow array of
     length num_rows, NOT padded).  The hybrid-execution escape hatch."""
@@ -156,7 +201,8 @@ class HostColumn:
         return cached
 
 
-Column = Union[DeviceColumn, DeviceStringColumn, HostColumn]
+Column = Union[DeviceColumn, DeviceStringColumn, DeviceDecimal128Column,
+               HostColumn]
 
 
 # ---------------------------------------------------------------------------
@@ -357,6 +403,11 @@ def _gather_kernel_builder():
 def concat_device_columns(parts: List[Any]):
     """Device concat of the same logical column across batches (pure jax;
     string widths are padded to the widest part)."""
+    if isinstance(parts[0], DeviceDecimal128Column):
+        return DeviceDecimal128Column(
+            parts[0].dtype, jnp.concatenate([p.hi for p in parts]),
+            jnp.concatenate([p.lo for p in parts]),
+            jnp.concatenate([p.validity for p in parts]))
     if isinstance(parts[0], DeviceStringColumn):
         w = max(p.data.shape[1] for p in parts)
         datas = [jnp.pad(p.data, ((0, 0), (0, w - p.data.shape[1])))
@@ -378,12 +429,17 @@ def concat_device_columns(parts: List[Any]):
 
 
 def is_device_type(dt: DataType) -> bool:
-    """Can this logical type live on device?"""
-    if dt.is_nested:
-        return False
-    if dt.id == TypeId.DECIMAL and dt.precision > 18:
-        return False
-    return True
+    """Does the serial engine hold this logical type on the device?  Not
+    what `DataType.host_resident` names: nested values and wide decimals
+    are `HostColumn`s there."""
+    return not dt.host_resident
+
+
+def stage_holds(dt: DataType) -> bool:
+    """Can the stage program hold this logical type?  What the serial
+    engine holds on the device, and wide decimals
+    (`DeviceDecimal128Column`)."""
+    return is_device_type(dt) or dt.is_wide_decimal
 
 
 def _empty_column(dt: DataType, cap: int) -> Column:
@@ -434,6 +490,11 @@ jax.tree_util.register_pytree_node(
     lambda c: (((c.data, c.validity) if c.bits is None
                 else (c.data, c.validity, c.bits)), (c.dtype, c.bits is not None)),
     lambda aux, kids: DeviceColumn(aux[0], *kids),
+)
+jax.tree_util.register_pytree_node(
+    DeviceDecimal128Column,
+    lambda c: ((c.hi, c.lo, c.validity), c.dtype),
+    lambda dtype, kids: DeviceDecimal128Column(dtype, *kids),
 )
 jax.tree_util.register_pytree_node(
     DeviceStringColumn,

@@ -34,6 +34,10 @@ def cast_column(col, dst: DataType, try_: bool = False):
             return DeviceStringColumn(dst, col.data, col.lengths, col.validity)
         raise NotImplementedError(
             "string->numeric casts run on the host path")
+    if src.is_wide_decimal or dst.is_wide_decimal:
+        # the stage program's columns alone (compiler._wide_verdict)
+        from auron_tpu.exprs import decimal128 as dec128
+        return dec128.cast(col, dst)
     data, valid = col.data, col.validity
     if dst.is_stringlike:
         return _int_to_string(col, dst)

@@ -19,10 +19,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from auron_tpu.columnar.batch import (
-    Batch, DeviceColumn, DeviceStringColumn, HostColumn, is_device_type,
+    Batch, DeviceColumn, DeviceDecimal128Column, DeviceStringColumn,
+    HostColumn, is_device_type, stage_holds,
 )
 from auron_tpu.columnar.arrow_interop import arrow_array_to_column
 from auron_tpu.exprs import datetime as dt_kernels
+from auron_tpu.exprs import decimal128 as dec128
 from auron_tpu.exprs import hashing
 from auron_tpu.exprs import strings_device as S
 from auron_tpu.exprs.cast import cast_column
@@ -82,22 +84,30 @@ def _lit_value(e: E.Expr):
 # ---------------------------------------------------------------------------
 
 def device_capable(expr: E.Expr, schema: Schema,
-                   host_cols: frozenset) -> bool:
-    """Can this whole subtree run on device?"""
+                   host_cols: frozenset, wide: bool = False) -> bool:
+    """Can this whole subtree run on device?  `wide`: the caller is the
+    stage program, which also holds decimals of 19-38 digits
+    (`stage_holds`) and evaluates the expressions `_wide_verdict` names
+    over them."""
     k = expr.kind
     if k in _HOST_KINDS:
         return False
+    holds = stage_holds if wide else is_device_type
     if k == "column":
         try:
             i = schema.index_of(expr.name)
         except KeyError:
             return False
-        return expr.name not in host_cols and is_device_type(schema[i].dtype)
+        return expr.name not in host_cols and holds(schema[i].dtype)
     if k == "bound_reference":
-        return is_device_type(schema[expr.index].dtype)
+        return holds(schema[expr.index].dtype)
     if k == "literal" or k == "scalar_subquery":
         dt = expr.dtype
-        return is_device_type(dt) or dt.id == TypeId.NULL
+        return holds(dt) or dt.id == TypeId.NULL
+    if wide:
+        verdict = _wide_verdict(expr, schema, host_cols)
+        if verdict is not None:
+            return verdict
     if k == "scalar_function":
         if expr.name not in _DEVICE_FUNCS:
             return False
@@ -139,17 +149,62 @@ def device_capable(expr: E.Expr, schema: Schema,
             pschema = wire_udf_param_schema(expr, schema)  # validates
         except (TypeError, KeyError):
             return False
-        return (all(device_capable(a, schema, host_cols)
+        return (all(device_capable(a, schema, host_cols, wide)
                     for a in expr.args) and
-                device_capable(expr.body, pschema, frozenset()))
+                device_capable(expr.body, pschema, frozenset(), wide))
     try:
         dt = infer_type(expr, schema)
         if not (is_device_type(dt) or dt.id == TypeId.NULL):
             return False
     except (TypeError, KeyError):
         return False
-    return all(device_capable(c, schema, host_cols)
+    return all(device_capable(c, schema, host_cols, wide)
                for c in _expr_children(expr))
+
+
+def _typed_multiply(expr: E.Expr) -> Optional[E.BinaryExpr]:
+    """The product under `CheckOverflow(Multiply(l, r), type)`, the form in
+    which Spark 3 types a decimal product; None for anything else."""
+    if expr.kind == "scalar_function" and expr.name == "check_overflow" \
+            and expr.args and expr.args[0].kind == "binary" \
+            and expr.args[0].op == "*":
+        return expr.args[0]
+    return None
+
+
+def _wide_verdict(expr: E.Expr, schema: Schema,
+                  host_cols: frozenset) -> Optional[bool]:
+    """Whether the stage program evaluates `expr`, where a wide decimal is
+    its result or one of its operands; None where none is, and the rules
+    for everything else decide.  Over wide decimals the program has casts
+    between decimals, a typed product with a one-word factor, comparisons
+    at one scale, and the null tests (exprs/decimal128.py)."""
+    product = _typed_multiply(expr)
+    operands = [product.left, product.right] if product is not None \
+        else _expr_children(expr)
+    try:
+        out = infer_type(expr, schema)
+        types = [infer_type(c, schema) for c in operands]
+    except (TypeError, KeyError):
+        return False
+    if not (out.is_wide_decimal or any(t.is_wide_decimal for t in types)):
+        return None
+    k = expr.kind
+    if product is not None:
+        ok = dec128.multiply_ok(types[0], types[1], product.left,
+                                product.right, out)
+    elif k in ("is_null", "is_not_null"):
+        ok = True
+    elif k in ("cast", "try_cast") or (
+            k == "scalar_function" and expr.name == "check_overflow"):
+        ok = dec128.cast_ok(types[0], out)
+    elif k == "binary" and expr.op in ("==", "=", "!=", "<", "<=", ">",
+                                       ">=", "<=>"):
+        ok = dec128.compare_ok(types[0], types[1])
+    else:
+        ok = False
+    return ok and all(device_capable(c, schema, host_cols, True)
+                      for c in operands)
 
 
 def _expr_children(expr: Node) -> List[E.Expr]:
@@ -251,6 +306,8 @@ def _eval_bound(e: E.BoundReference, ctx: EvalCtx) -> Col:
 
 def _eval_literal(e, ctx: EvalCtx) -> Col:
     dt = e.dtype
+    if dt.is_wide_decimal and e.value is not None:
+        return dec128.literal_column(e.value, dt, ctx.capacity)
     return literal_column(e.value, dt, ctx.capacity)
 
 
@@ -306,6 +363,17 @@ def _eval_binary(e: E.BinaryExpr, ctx: EvalCtx) -> Col:
     r = evaluate(e.right, ctx)
     if isinstance(l, DeviceStringColumn) or isinstance(r, DeviceStringColumn):
         return _string_binary(op, l, r, ctx)
+    if isinstance(l, DeviceDecimal128Column) or \
+            isinstance(r, DeviceDecimal128Column):
+        # comparisons alone (`_wide_verdict`); a product is typed by the
+        # CheckOverflow above it (functions_device.eval_scalar_function)
+        data, both = dec128.compare(op, l, r)
+        if op == "<=>":
+            eq_nulls = jnp.logical_and(jnp.logical_not(l.validity),
+                                       jnp.logical_not(r.validity))
+            return flat(DataType.bool_(), jnp.where(both, data, eq_nulls),
+                        jnp.ones(ctx.capacity, bool))
+        return flat(DataType.bool_(), data, both)
     both = jnp.logical_and(l.validity, r.validity)
     if op in ("==", "=", "!=", "<", "<=", ">", ">=", "<=>"):
         t = promote(l.dtype, r.dtype)
@@ -633,7 +701,7 @@ def _eval_monotonic_id(e, ctx: EvalCtx) -> Col:
 
 
 def _eval_scalar_subquery(e, ctx: EvalCtx) -> Col:
-    return literal_column(e.value, e.dtype, ctx.capacity)
+    return _eval_literal(e, ctx)
 
 
 def _eval_bloom_might_contain(e, ctx: EvalCtx) -> Col:

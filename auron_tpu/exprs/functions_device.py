@@ -23,8 +23,22 @@ from auron_tpu.ir.schema import DataType, TypeId
 
 
 def eval_scalar_function(e, ctx):
-    from auron_tpu.exprs.compiler import evaluate
+    from auron_tpu.exprs.compiler import _typed_multiply, evaluate
     name = e.name
+    product = _typed_multiply(e)
+    if product is not None and e.return_type.is_decimal:
+        # Spark 3 types a decimal product by the CheckOverflow above it;
+        # with a wide decimal in it (the stage program alone holds one)
+        # the product is exact (exprs/decimal128.py)
+        from auron_tpu.exprs import decimal128 as dec128
+        from auron_tpu.exprs.typing import infer_type
+        types = [infer_type(x, ctx.schema)
+                 for x in (product.left, product.right)]
+        if all(t.is_decimal for t in types) and any(
+                t.is_wide_decimal for t in types + [e.return_type]):
+            return dec128.multiply(
+                evaluate(product.left, ctx), evaluate(product.right, ctx),
+                product.left, product.right, e.return_type)
     args = [evaluate(a, ctx) for a in e.args]
     raw = [a.value if hasattr(a, "value") else None for a in e.args]
     fn = _FUNCS.get(name)

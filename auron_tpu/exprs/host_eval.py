@@ -72,7 +72,7 @@ def arrow_to_hv(arr: pa.Array, dtype: DataType) -> HV:
                          else decimal_unscaled(v, dtype.scale)
                          for v in arr.to_pylist()], dtype=object)
         vals = np.where(mask, vals, 0)
-        return HV(vals.astype(np.int64) if dtype.precision <= 18 else vals,
+        return HV(vals if dtype.is_wide_decimal else vals.astype(np.int64),
                   mask, dtype)
     if dtype.is_stringlike or dtype.is_nested:
         vals = np.array(arr.to_pylist(), dtype=object)
@@ -171,7 +171,7 @@ def evaluate(expr: E.Expr, rb: pa.RecordBatch, schema: Schema,
                 # exact unscaling (a float round-trip or narrow decimal
                 # context would corrupt high-precision literals)
                 v = decimal_unscaled(str(v), dt.scale)
-            if dt.precision > 18:   # beyond int64: object-int column
+            if dt.is_wide_decimal:   # beyond int64: object-int column
                 return HV(np.full(n, v, dtype=object), np.ones(n, bool),
                           dt)
         if dt.is_stringlike or dt.is_nested:

@@ -485,9 +485,27 @@ def _join_on(node) -> P.JoinOn:
 
 
 def _check_no_condition(node) -> None:
-    if node.attrs.get("condition") is not None:
+    """A join's residual condition converts for an inner join alone, where
+    it is a filter over the joined rows (`_with_condition`)."""
+    if node.attrs.get("condition") is not None and \
+            node.attrs.get("join_type", "Inner") != "Inner":
         raise NotConvertible(
             f"{node.op} with post-join condition is not supported yet")
+
+
+def _with_condition(node, join: P.PlanNode, ctx: "ConvertContext"
+                    ) -> P.PlanNode:
+    """An inner join's residual condition as a filter over its output:
+    the pairs that meet the keys and fail the condition are dropped
+    after the join instead of inside it, which for an inner join is the
+    same rows."""
+    cond = node.attrs.get("condition")
+    if cond is None:
+        return join
+    preds = tuple(EC.convert_expr_with_fallback(p)
+                  for p in _split_conjunction(cond))
+    return ctx.set_parts(P.Filter(child=join, predicates=preds),
+                         ctx.parts(join))
 
 
 @_plan("SortMergeJoinExec")
@@ -520,7 +538,7 @@ def _smj(node, children, ctx) -> P.PlanNode:
         return ctx.set_parts(P.Sort(child=child, sort_exprs=want),
                              ctx.parts(child))
 
-    return ctx.set_parts(
+    return _with_condition(node, ctx.set_parts(
         P.SortMergeJoin(
             left=ensure_sorted(children[0], on.left_keys),
             right=ensure_sorted(children[1], on.right_keys),
@@ -528,7 +546,7 @@ def _smj(node, children, ctx) -> P.PlanNode:
             sort_options=tuple((True, True) for _ in range(nkeys)),
             existence_output_name=node.attrs.get("existence_name",
                                                  "exists")),
-        max(ctx.parts(children[0]), ctx.parts(children[1])))
+        max(ctx.parts(children[0]), ctx.parts(children[1]))), ctx)
 
 
 @_plan("ShuffledHashJoinExec")
@@ -536,13 +554,13 @@ def _shj(node, children, ctx) -> P.PlanNode:
     _op_enabled("shj")
     _check_no_condition(node)
     jt = EC.convert_join_type(node.attrs.get("join_type", "Inner"))
-    return ctx.set_parts(
+    return _with_condition(node, ctx.set_parts(
         P.HashJoin(left=children[0], right=children[1], on=_join_on(node),
                    join_type=jt,
                    build_side=node.attrs.get("build_side", "right"),
                    existence_output_name=node.attrs.get("existence_name",
                                                         "exists")),
-        max(ctx.parts(children[0]), ctx.parts(children[1])))
+        max(ctx.parts(children[0]), ctx.parts(children[1]))), ctx)
 
 
 @_plan("BroadcastHashJoinExec")
@@ -561,13 +579,13 @@ def _bhj(node, children, ctx) -> P.PlanNode:
     pair = [children[0], children[1]]
     pair[build_idx] = built
     probe_parts = ctx.parts(children[1 - build_idx])
-    return ctx.set_parts(
+    return _with_condition(node, ctx.set_parts(
         P.BroadcastJoin(left=pair[0], right=pair[1], on=on, join_type=jt,
                         broadcast_side=side,
                         cached_build_hash_map_id=cache_id,
                         existence_output_name=node.attrs.get(
                             "existence_name", "exists")),
-        probe_parts)
+        probe_parts), ctx)
 
 
 @_plan("ShuffleExchangeExec")

@@ -7,7 +7,9 @@ date32, timestamp(us), plus nested list/map/struct.
 
 On device (TPU), types map to:
 - BOOL/INTs/FLOATs: the corresponding jnp dtype
-- DECIMAL(p<=18, s): scaled int64 (unscaled value); p>18 is host-resident
+- DECIMAL(p<=18, s): scaled int64 (unscaled value); p>18 (`is_wide_decimal`)
+  is host-resident in the serial engine and two 64-bit words a value in the
+  stage program (columnar/batch.py `DeviceDecimal128Column`)
 - STRING/BINARY: fixed-width padded uint8 [capacity, width] + int32 lengths
 - DATE32: int32 days since epoch; TIMESTAMP: int64 microseconds
 - LIST/MAP/STRUCT: host-resident (hybrid execution), exploded on demand
@@ -110,6 +112,17 @@ class DataType:
         return self.id in (TypeId.LIST, TypeId.MAP, TypeId.STRUCT)
     @property
     def is_decimal(self) -> bool: return self.id == TypeId.DECIMAL
+    @property
+    def is_wide_decimal(self) -> bool:
+        """A decimal of 19-38 digits: its unscaled value does not fit one
+        64-bit word.  THE place that says so."""
+        return self.id == TypeId.DECIMAL and self.precision > 18
+    @property
+    def host_resident(self) -> bool:
+        """The serial engine keeps it on the host (a `HostColumn`): nested
+        values and wide decimals.  The stage program holds a wide decimal on
+        the device (columnar/batch.py `stage_holds`)."""
+        return self.is_nested or self.is_wide_decimal
 
     def numpy_dtype(self) -> np.dtype:
         """The host/device physical dtype for flat (non-string, non-nested)

@@ -27,8 +27,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from auron_tpu.columnar.batch import (
-    Batch, DeviceColumn, DeviceStringColumn, HostColumn, bucket_capacity,
-    concat_batches, concat_device_columns as _concat_cols,
+    Batch, DeviceColumn, DeviceDecimal128Column, DeviceStringColumn,
+    HostColumn, bucket_capacity, concat_batches,
+    concat_device_columns as _concat_cols,
 )
 from auron_tpu.config import conf
 from auron_tpu.exprs.compiler import build_evaluator
@@ -70,7 +71,9 @@ jitcheck.waive_retraces(
 class AggExec(Operator, MemConsumer):
     def __init__(self, child: Operator, exec_mode: str, grouping,
                  grouping_names, aggs: Tuple[AggExpr, ...], agg_names,
-                 supports_partial_skipping: bool = False):
+                 supports_partial_skipping: bool = False,
+                 wide: bool = False):
+        # `wide`: built by the stage program for its specs (make_spec)
         in_schema = child.schema
         self.exec_mode = exec_mode
         self.grouping = tuple(grouping)
@@ -98,7 +101,8 @@ class AggExec(Operator, MemConsumer):
                 in_dts = tuple(_t(c) for c in a.children)
             self.specs.append(make_spec(a.fn, in_dt or DataType.int64(),
                                         a.return_type, name, a.udaf,
-                                        wire=a.wire, in_dtypes=in_dts))
+                                        wire=a.wire, in_dtypes=in_dts,
+                                        wide=wide))
 
         key_fields = tuple(
             Field(n, infer_type(g, in_schema))
@@ -905,7 +909,9 @@ def _clip_states(states: List[Any], n_groups: int) -> List[Any]:
     for s in states:
         cap = s.capacity
         live = jnp.arange(cap, dtype=jnp.int32) < n_groups
-        if isinstance(s, DeviceStringColumn):
+        if isinstance(s, DeviceDecimal128Column):
+            out.append(s.masked(live))
+        elif isinstance(s, DeviceStringColumn):
             out.append(DeviceStringColumn(
                 s.dtype, jnp.where(live[:, None], s.data, 0),
                 jnp.where(live, s.lengths, 0),

@@ -206,6 +206,95 @@ class AvgSpec(AggSpec):
         return flat(DataType.float64(), avg, cnt.data > 0)
 
 
+class WideSumSpec(AggSpec):
+    """`Sum` whose result is a decimal of 19-38 digits, in the stage
+    program (exprs/decimal128.py): Spark's buffer (sum, isEmpty).  The sum
+    is exact, with the carries between the words; one that passes the
+    result's precision is null and stays null through every merge
+    (non-ANSI overflow), which is what isEmpty is for: a null sum beside
+    isEmpty = false is an overflow, not a group without rows."""
+    n_states = 2
+
+    def state_fields(self):
+        return [Field(f"{self.name}#sum", self.out_dtype),
+                Field(f"{self.name}#isEmpty", DataType.bool_(),
+                      nullable=False)]
+
+    def _pack(self, total, any_rows, n):
+        return [total, DeviceColumn(DataType.bool_(),
+                                    jnp.logical_not(any_rows),
+                                    jnp.ones(n, bool))]
+
+    def update_segments(self, cols, seg, n):
+        from auron_tpu.exprs import decimal128 as dec128
+        c = cols[0]
+        if c.dtype.scale != self.out_dtype.scale:
+            c = dec128.cast(c, self.out_dtype)
+        total = dec128.sum_state(self.out_dtype, c, c.validity,
+                                 jnp.zeros(c.validity.shape, bool), seg, n)
+        return self._pack(
+            total, _seg_sum(c.validity.astype(jnp.int32), seg, n) > 0, n)
+
+    def merge_segments(self, states, seg, n):
+        from auron_tpu.exprs import decimal128 as dec128
+        s, empty = states
+        some = jnp.logical_and(empty.validity,
+                               jnp.logical_not(empty.data.astype(bool)))
+        total = dec128.sum_state(self.out_dtype, s, some,
+                                 jnp.logical_not(s.validity), seg, n)
+        return self._pack(total,
+                          _seg_sum(some.astype(jnp.int32), seg, n) > 0, n)
+
+    def eval_final(self, states):
+        s, empty = states
+        return s.masked(jnp.logical_not(empty.data.astype(bool)))
+
+
+class WideAvgSpec(AggSpec):
+    """`Average` over a decimal that Spark leaves a decimal (result
+    precision over 15 digits, so no rewrite to doubles), in the stage
+    program: the buffer (sum decimal(p + 10, s), count) and the result
+    `Divide(sum, count)` at Spark's adjusted type cast to the result
+    type, two roundings (exprs/decimal128.py `average`).  A sum past its
+    precision is null and poisons every merge it enters."""
+    n_states = 2
+
+    def _sum_dtype(self) -> DataType:
+        if self.in_dtype.is_decimal:
+            p, s = self.in_dtype.precision, self.in_dtype.scale
+        else:
+            # final mode: the input's type is not in the state schema;
+            # the result's is decimal(p + 4, s + 4)
+            p, s = self.out_dtype.precision - 4, self.out_dtype.scale - 4
+        return DataType.decimal(min(38, p + 10), s)
+
+    def state_fields(self):
+        return [Field(f"{self.name}#sum", self._sum_dtype()),
+                Field(f"{self.name}#count", DataType.int64(), nullable=False)]
+
+    def update_segments(self, cols, seg, n):
+        from auron_tpu.exprs import decimal128 as dec128
+        c = cols[0]
+        total = dec128.sum_state(self._sum_dtype(), c, c.validity,
+                                 jnp.zeros(c.validity.shape, bool), seg, n)
+        cnt = _seg_sum(c.validity.astype(jnp.int64), seg, n)
+        return [total, DeviceColumn(DataType.int64(), cnt, jnp.ones(n, bool))]
+
+    def merge_segments(self, states, seg, n):
+        from auron_tpu.exprs import decimal128 as dec128
+        s, cnt = states
+        rows = jnp.where(cnt.validity, cnt.data, 0)
+        total = dec128.sum_state(s.dtype, s, rows > 0,
+                                 jnp.logical_not(s.validity), seg, n)
+        return [total, DeviceColumn(DataType.int64(), _seg_sum(rows, seg, n),
+                                    jnp.ones(n, bool))]
+
+    def eval_final(self, states):
+        from auron_tpu.exprs import decimal128 as dec128
+        s, cnt = states
+        return dec128.average(s, cnt.data, self.out_dtype)
+
+
 class StddevSpec(AggSpec):
     """stddev_samp / var_samp over (sum, sum-of-squares, count) power-sum
     state.  The reference's central-moment accumulators (Spark's
@@ -727,8 +816,19 @@ _DEVICE_AGG_FNS = {"sum", "count", "min", "max", "avg", "first",
 
 def make_spec(fn: str, in_dtype: DataType, out_dtype: DataType, name: str,
               udaf_blob=None, wire=None,
-              in_dtypes: Optional[Tuple[DataType, ...]] = None) -> AggSpec:
+              in_dtypes: Optional[Tuple[DataType, ...]] = None,
+              wide: bool = False) -> AggSpec:
+    """`wide`: the caller is the stage program, which holds decimals of
+    19-38 digits on the device: there a wide `Sum` and a decimal `Average`
+    that Spark leaves a decimal have device specs; in the serial engine
+    they are host specs, as they were."""
     from auron_tpu.columnar.batch import is_device_type
+
+    if wide and fn == "sum" and out_dtype.is_wide_decimal:
+        return WideSumSpec(fn, in_dtype, out_dtype, name)
+    if wide and fn == "avg" and out_dtype.is_decimal \
+            and out_dtype.precision > 15:
+        return WideAvgSpec(fn, in_dtype, out_dtype, name)
 
     def flat_numeric(dt: DataType) -> bool:
         return is_device_type(dt) and not dt.is_stringlike

@@ -330,7 +330,7 @@ def _np_encode_key(hv, asc: bool, nulls_first: bool) -> List[np.ndarray]:
             u = int(v) & ((1 << 128) - 1)
             his[i] = u >> 64
             los[i] = u & ((1 << 64) - 1)
-        if dt.precision <= 18:
+        if not dt.is_wide_decimal:
             words = [los ^ np.uint64(1 << 63)]
         else:
             words = [his ^ np.uint64(1 << 63), los]

@@ -53,7 +53,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from auron_tpu.columnar.batch import (
-    DeviceColumn, DeviceStringColumn, HostColumn, bucket_capacity,
+    DeviceColumn, DeviceDecimal128Column, DeviceStringColumn, HostColumn,
+    bucket_capacity,
 )
 from auron_tpu.exprs import hashing as H
 from auron_tpu.exprs.compiler import EvalCtx, device_capable, evaluate
@@ -229,11 +230,14 @@ def explain_stage(plan, conv_ctx,
     filed under.  `stats` (execute_plan_spmd's) marks each K=1 join with
     the probe it took, `direct` or `search`, each aggregate whose input
     is larger than its output's capacity with the input it worked on,
-    `compact` or `full`, and the live rows of it, and each boundary that
+    `compact` or `full`, the live rows of it and the rung of the capacity
+    ladder its output was cut to (`cap`), each operator whose output holds
+    wide decimals with `dec128` and how many, and each boundary that
     crossed devices with what it moved."""
     stats = stats or {}
     probes = stats.get("join_probes") or {}
     aggs = stats.get("agg_inputs") or {}
+    wide = stats.get("wide_columns") or {}
     crossed = {**(stats.get("exchanges") or {}),
                **(stats.get("broadcasts") or {})}
     exchanges = getattr(conv_ctx, "exchanges", None) or {}
@@ -247,7 +251,8 @@ def explain_stage(plan, conv_ctx,
             if label in aggs:
                 a = aggs[label]
                 detail += (f" input={a['input']}"
-                           f" live={a['live']} of {a['capacity']}")
+                           f" live={a['live']} of {a['capacity']}"
+                           f" cap={a['cap']}")
         elif isinstance(node, (P.BroadcastJoin, P.HashJoin,
                                P.SortMergeJoin)):
             detail = f" type={node.join_type}"
@@ -269,6 +274,8 @@ def explain_stage(plan, conv_ctx,
             elif c is not None:
                 detail += (f" rows={c['rows']} slots={c['slots']}"
                            f" bytes={c['buffer_bytes']}")
+        if label in wide:
+            detail += f" dec128={wide[label]}"
         lines.append(f"{'  ' * depth}{label}{detail}")
     return "\n".join(lines)
 
@@ -341,6 +348,9 @@ class _StageTracer:
         # its counts — a replicated int64 device vector); a one-device
         # program has none (_count_exchange, _count_broadcast)
         self.crossings: List[Tuple[Dict[str, Any], Any]] = []
+        # operator label -> wide decimal columns (precision over 18, two
+        # words a value) in the table it handed on
+        self.wide_columns: Dict[str, int] = {}
         # join pair-expansion factor (1 = single-candidate probe)
         self.match_factor = max(1, int(match_factor))
         # post-agg static capacity (rows/device); 0 keeps input capacity
@@ -362,7 +372,7 @@ class _StageTracer:
 
     def _eval_exprs(self, exprs, t: DeviceTable) -> List[Any]:
         for x in exprs:
-            if not device_capable(x, t.schema, frozenset()):
+            if not device_capable(x, t.schema, frozenset(), wide=True):
                 raise SpmdUnsupported(f"expr not device-capable: {x.kind}")
             if _tree_has(x, ("row_num", "monotonically_increasing_id",
                              "py_udf_wrapper", "scalar_subquery")):
@@ -374,6 +384,18 @@ class _StageTracer:
                       row_base=jnp.int64(0))
         return [evaluate(x, ctx) for x in exprs]
 
+    def _eval_keys(self, exprs, t: DeviceTable, what: str) -> List[Any]:
+        """Expressions whose order or hash the program needs.  A wide
+        decimal is a value here, never a key: `iter_spmd_rejections`
+        refuses such a plan by name before anything is read; this is the
+        same refusal for a plan whose schemas it could not follow."""
+        cols = self._eval_exprs(exprs, t)
+        for c in cols:
+            if isinstance(c, DeviceDecimal128Column):
+                raise SpmdUnsupported(
+                    f"a wide decimal ({c.dtype!r}) as {what}")
+        return cols
+
     # -- node dispatch -----------------------------------------------------
 
     def eval_node(self, node) -> DeviceTable:
@@ -384,8 +406,13 @@ class _StageTracer:
             raise SpmdUnsupported(f"operator not SPMD-compilable: {node.kind}")
         # HLO metadata only: neither _PROGRAM_CACHE's key nor JAX's
         # persistent-cache key sees it
-        with jax.named_scope(self.labels.get(id(node), node.kind)):
-            return handler(node)
+        label = self.labels.get(id(node), node.kind)
+        with jax.named_scope(label):
+            out = handler(node)
+        n_wide = sum(isinstance(c, DeviceDecimal128Column) for c in out.cols)
+        if n_wide:
+            self.wide_columns[label] = n_wide
+        return out
 
     # sources ---------------------------------------------------------------
 
@@ -434,7 +461,7 @@ class _StageTracer:
             # for nothing (a real cost at sf10 single-chip shapes)
             return t
         if part.mode == "hash":
-            keys = self._eval_exprs(part.expressions, t)
+            keys = self._eval_keys(part.expressions, t, "exchange key")
             h = H.hash_columns(keys, seed=42)
             pid = H.pmod(h, n_dev).astype(jnp.int32)
         elif part.mode == "round_robin":
@@ -451,8 +478,8 @@ class _StageTracer:
                 encoded_range_bounds, range_ids_from_words,
             )
             from auron_tpu.ops.sort_keys import encode_sort_keys as _enc
-            keys = self._eval_exprs(
-                tuple(s.child for s in part.sort_orders), t)
+            keys = self._eval_keys(
+                tuple(s.child for s in part.sort_orders), t, "exchange key")
             orders = tuple((s.asc, s.nulls_first)
                            for s in part.sort_orders)
             words = _enc(keys, orders)
@@ -644,7 +671,7 @@ class _StageTracer:
         from auron_tpu.runtime.metrics import MetricNode
         dummy.metrics = MetricNode("src")
         agg = AggExec(dummy, n.exec_mode, n.grouping, n.grouping_names,
-                      n.aggs, n.agg_names, False)
+                      n.aggs, n.agg_names, False, wide=True)
         if any(isinstance(s, HostAggSpec) for s in agg.specs):
             raise SpmdUnsupported("host-path agg function in SPMD")
         return agg
@@ -694,7 +721,7 @@ class _StageTracer:
             """The aggregate over `t`, at `t`'s capacity: its groups at the
             front of the table, and how many there are."""
             merge = n.exec_mode == "final"
-            keys = self._eval_exprs(n.grouping, t)
+            keys = self._eval_keys(n.grouping, t, "group key")
             nk = len(n.grouping)
             if merge:
                 vcols: List[List[Any]] = []
@@ -764,7 +791,7 @@ class _StageTracer:
         # driver's counter
         self.agg_inputs.append((
             {"label": self.labels.get(id(n), n.kind),
-             "capacity": t.capacity * self.n_dev},
+             "capacity": t.capacity * self.n_dev, "cap": new_cap},
             lax.psum(jnp.stack([fits.astype(jnp.int32), n_live])
                      .astype(jnp.int64), self.axis)))
         return DeviceTable(out_schema, cols, live)
@@ -839,9 +866,9 @@ class _StageTracer:
         probe = self.eval_node(left_ir)
         build = self.eval_node(right_ir)
         with jax.named_scope("probe"):
-            pkeys = self._eval_exprs(on.left_keys, probe)
+            pkeys = self._eval_keys(on.left_keys, probe, "join key")
         with jax.named_scope("build"):
-            bkeys = self._eval_exprs(on.right_keys, build)
+            bkeys = self._eval_keys(on.right_keys, build, "join key")
         semi_like = join_type in ("left_semi", "left_anti", "existence")
         K = 1 if semi_like else self.match_factor
         if K <= 1 and _direct_addressable(pkeys, bkeys):
@@ -921,8 +948,11 @@ class _StageTracer:
         matched = jnp.zeros(build.capacity, bool).at[
             jnp.where(ok, bidx, build.capacity)].set(True, mode="drop")
         live2 = jnp.logical_and(build.live, jnp.logical_not(matched))
-        null_probe = null_columns_like(probe.schema.fields,
-                                       build.capacity)
+        null_probe = [
+            DeviceDecimal128Column.nulls(f.dtype, build.capacity)
+            if f.dtype.is_wide_decimal
+            else null_columns_like([f], build.capacity)[0]
+            for f in probe.schema.fields]
         t2 = DeviceTable(schema, null_probe + list(build.cols), live2)
         return self._concat_tables(schema, [t1, t2])
 
@@ -1167,7 +1197,8 @@ class _StageTracer:
                 s.sort_exprs == n.sort_exprs[:len(s.sort_exprs)]:
             return self.eval_node(n.child)
         t = self.eval_node(n.child)
-        keys = self._eval_exprs(tuple(x.child for x in n.sort_exprs), t)
+        keys = self._eval_keys(tuple(x.child for x in n.sort_exprs), t,
+                               "sort key")
         orders = tuple((x.asc, x.nulls_first) for x in n.sort_exprs)
         words = encode_sort_keys(keys, orders)
         perm = lexsort_indices_live(words, t.live)
@@ -1214,10 +1245,12 @@ class _StageTracer:
         # so the supported set lives in ONE place (ops/window/exec.py)
         t = self.eval_node(n.child)
         cap = t.capacity
-        pcols = self._eval_exprs(n.partition_by, t)
-        ocols = self._eval_exprs(tuple(s.child for s in n.order_by), t)
-        args_u = [self._eval_exprs(
-            tuple(wf.args) + ((wf.agg.children if wf.agg else ())), t)
+        pcols = self._eval_keys(n.partition_by, t, "window argument")
+        ocols = self._eval_keys(tuple(s.child for s in n.order_by), t,
+                                "window argument")
+        args_u = [self._eval_keys(
+            tuple(wf.args) + ((wf.agg.children if wf.agg else ())), t,
+            "window argument")
             for wf in n.window_funcs]
         orders = tuple((s.asc, s.nulls_first) for s in n.order_by)
         pwords = encode_sort_keys(
@@ -1399,7 +1432,8 @@ def _shard_table(table, mesh: Mesh, axis: str) -> Tuple[Schema, List[Any],
     schema = from_arrow_schema(table.schema)
     cols: List[Any] = []
     for f, arr in zip(schema, table.columns):
-        col = arrow_array_to_host_column(f.dtype, arr, cap, dealt)
+        col = arrow_array_to_host_column(f.dtype, arr, cap, dealt,
+                                         wide=True)
         if isinstance(col, HostColumn):
             raise SpmdUnsupported("host-resident column in SPMD source")
         cols.append(col)
@@ -1921,7 +1955,8 @@ def _agg_input_marks(agg_box, agg_np, n_dev: int) -> Dict[str, dict]:
     "live": rows, "capacity": slots}} for the aggregates of one run that
     were traced with a choice: `agg_np` holds, per aggregate, how many of
     the `n_dev` devices compacted its input, and the input's live rows
-    over all devices (`capacity`: its slots over all devices)."""
+    over all devices (`capacity`: its slots over all devices; `cap`: the
+    rows a device its output was cut to, the capacity ladder's rung)."""
     counts = iter(np.asarray(agg_np).tolist() if agg_np is not None else ())
     marks = {}
     for what in agg_box:
@@ -1929,7 +1964,8 @@ def _agg_input_marks(agg_box, agg_np, n_dev: int) -> Dict[str, dict]:
         marks[what["label"]] = {
             "input": "compact" if k == n_dev else
             "full" if k == 0 else f"compact {k}/{n_dev}",
-            "live": live, "capacity": what["capacity"]}
+            "live": live, "capacity": what["capacity"],
+            "cap": what["cap"]}
     return marks
 
 
@@ -1971,11 +2007,13 @@ def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
 
 
 def _reported(probe_box, direct_np, agg_box, agg_np, cross_box, crossed_np,
-              n_dev: int) -> Dict[str, Any]:
+              wide_box, n_dev: int) -> Dict[str, Any]:
     """What one run's program reported of itself, as execute_plan_spmd's
-    `stats` hold it."""
+    `stats` hold it; `wide_columns` (operator label -> wide decimal
+    columns in its output) only where the program held one."""
     return {"join_probes": _probe_marks(probe_box, direct_np, n_dev),
             "agg_inputs": _agg_input_marks(agg_box, agg_np, n_dev),
+            **({"wide_columns": dict(wide_box)} if wide_box else {}),
             **_crossing_stats(cross_box, crossed_np)}
 
 
@@ -2024,12 +2062,29 @@ def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
 
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
     """execute_plan_spmd's `stats` as query totals: what the scan tasks
-    read, the probe counter's two numbers, the aggregate inputs' two and
-    the boundaries' counts."""
+    read, the probe counter's two numbers, the aggregate inputs' two, the
+    ladder's rung and the wide decimal columns, and the boundaries'
+    counts."""
     return {**ingest_totals(stats),
             **probe_counts(stats.get("join_probes") or {}),
             **agg_input_counts(stats.get("agg_inputs") or {}),
+            **wide_totals(stats),
             **crossing_totals(stats)}
+
+
+def wide_totals(stats: Dict[str, Any]) -> Dict[str, int]:
+    """`agg_capacity`, the largest rung of the capacity ladder an
+    aggregate's output was cut to (none where no aggregate was cut), and
+    `wide_decimal_columns`, the wide decimal columns the program's
+    operators handed on (none where it held none)."""
+    out: Dict[str, int] = {}
+    aggs = stats.get("agg_inputs") or {}
+    if aggs:
+        out["agg_capacity"] = max(a["cap"] for a in aggs.values())
+    wide = stats.get("wide_columns") or {}
+    if wide:
+        out["wide_decimal_columns"] = sum(wide.values())
+    return out
 
 
 def crossing_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
@@ -2241,6 +2296,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         cross_box: List[Dict[str, Any]] = []
         # and of each aggregate traced with a choice of input
         agg_box: List[Dict[str, Any]] = []
+        # operator label -> wide decimal columns in its output
+        wide_box: Dict[str, int] = {}
         labels = {id(node): label
                   for _depth, node, label in operator_labels(plan, conv_ctx)}
 
@@ -2262,6 +2319,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                  for label, flag in tracer.probes)
                 cross_box.extend(what for what, _n in tracer.crossings)
                 agg_box.extend(what for what, _n in tracer.agg_inputs)
+                wide_box.update(tracer.wide_columns)
             with jax.named_scope("epilogue"):
                 guards = jnp.stack(tracer.guards) if tracer.guards else \
                     jnp.zeros(0, bool)
@@ -2307,7 +2365,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                        PS(), PS(), PS(), PS()),
             check_vma=False))
     else:
-        shard, schema_box, probe_box, cross_box, agg_box = cached
+        shard, schema_box, probe_box, cross_box, agg_box, wide_box = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
     # compiles the whole stage program, so the span is the compile span
@@ -2322,7 +2380,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             shard(host_inputs)
     if cached is None:
         _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box,
-                                     cross_box, agg_box)
+                                     cross_box, agg_box, wide_box)
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
@@ -2339,7 +2397,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 (counts, guards, retry_guards, shrink_guards,
                  join_guards, probe_direct, crossed, agg_compact))
             reported = _reported(probe_box, direct_np, agg_box, agg_np,
-                                 cross_box, crossed_np, n_dev)
+                                 cross_box, crossed_np, wide_box, n_dev)
             sp.set_args(**stage_totals(reported))
         if stats is not None:
             # before the guards: a tripped exchange guard's fill is what
@@ -2485,6 +2543,92 @@ def iter_spmd_rejections(plan, conv_ctx):
             yield node, "window needs a colocating exchange under it"
         # (limit-over-sort rejection lives in _do_limit — trace-time only,
         # one authoritative copy)
+    yield from _wide_key_rejections(plan, conv_ctx)
+
+
+def _mentions_wide_decimal(root) -> bool:
+    """Does any type written in the tree (a source's schema, a cast, a
+    literal, an aggregate's result) name a decimal of 19-38 digits?  No
+    expression of a tree that names none has such a type."""
+    import dataclasses
+    stack, seen = [root], set()
+    while stack:
+        o = stack.pop()
+        if isinstance(o, DataType):
+            if o.is_wide_decimal:
+                return True
+            stack.extend(f.dtype for f in o.children or ())
+        elif isinstance(o, (tuple, list)):
+            stack.extend(o)
+        elif dataclasses.is_dataclass(o) and not isinstance(o, type) \
+                and id(o) not in seen:
+            seen.add(id(o))
+            stack.extend(getattr(o, f.name) for f in dataclasses.fields(o))
+    return False
+
+
+def _wide_key_rejections(plan, conv_ctx):
+    """(node, reason) for every place a wide decimal stands where the
+    stage program needs its order or its hash: a group key, a join key, a
+    sort key, a window's partition, order or argument, an exchange's
+    key.  The program holds a wide decimal as a value (two words a row:
+    filtered, projected, summed, averaged, compared, moved), never as a
+    key; the serial engine's host path takes such a plan."""
+    from auron_tpu.analysis.schema_infer import SchemaContext
+    from auron_tpu.exprs.typing import infer_type
+    exchanges = getattr(conv_ctx, "exchanges", None) or {}
+    broadcasts = getattr(conv_ctx, "broadcasts", None) or {}
+    jobs = [j for j in list(exchanges.values()) + list(broadcasts.values())
+            if isinstance(j.child, P.PlanNode)]
+    if not any(_mentions_wide_decimal(x) for x in [plan] + jobs):
+        return
+
+    def wide(exprs, schema):
+        for x in exprs:
+            try:
+                dt = infer_type(x, schema)
+            except Exception:   # untypable: the tracer says why
+                continue
+            if dt.is_wide_decimal:
+                return dt
+        return None
+
+    # (a tree, the expressions the exchange above it partitions by)
+    roots = [(plan, ())] + [
+        (j.child, getattr(getattr(j, "partitioning", None), "expressions",
+                          None) or ()) for j in jobs]
+    for root, exchange_keys in roots:
+        sc = SchemaContext(root)
+        if exchange_keys and sc.schema_of(root) is not None:
+            dt = wide(exchange_keys, sc.schema_of(root))
+            if dt is not None:
+                yield root, f"a wide decimal ({dt!r}) as exchange key"
+        for node, _path in sc.nodes():
+            kids = [sc.schema_of(c) for c in P.plan_children(node)]
+            if not kids or any(k is None for k in kids):
+                continue
+            if node.kind == "agg":
+                uses = [("group key", node.grouping, kids[0])]
+            elif node.kind in ("broadcast_join", "hash_join",
+                               "sort_merge_join"):
+                uses = [("join key", node.on.left_keys, kids[0]),
+                        ("join key", node.on.right_keys, kids[1])]
+            elif node.kind == "sort":
+                uses = [("sort key", [x.child for x in node.sort_exprs],
+                         kids[0])]
+            elif node.kind == "window":
+                args = [a for wf in node.window_funcs
+                        for a in tuple(wf.args) +
+                        (wf.agg.children if wf.agg else ())]
+                uses = [("window argument",
+                         list(node.partition_by) +
+                         [x.child for x in node.order_by] + args, kids[0])]
+            else:
+                continue
+            for what, exprs, schema in uses:
+                dt = wide(exprs, schema)
+                if dt is not None:
+                    yield node, f"a wide decimal ({dt!r}) as {what}"
 
 
 def precheck_plan(plan, conv_ctx) -> None:

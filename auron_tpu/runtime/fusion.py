@@ -43,7 +43,7 @@ from auron_tpu.analysis.fusion import FUSABLE_KINDS, body_chain
 from auron_tpu.ir import plan as P
 from auron_tpu.ir.expr import Expr
 from auron_tpu.ir.node import Node
-from auron_tpu.ir.schema import Schema, TypeId
+from auron_tpu.ir.schema import Schema
 
 PASS_ID = "fusion"
 
@@ -82,8 +82,7 @@ def _static_host_cols(schema: Schema) -> frozenset:
     path, not here.)"""
     out = []
     for f in schema.fields:
-        if f.dtype.is_nested or (f.dtype.id == TypeId.DECIMAL
-                                 and f.dtype.precision > 18):
+        if f.dtype.host_resident:
             out.append(f.name)
     return frozenset(out)
 

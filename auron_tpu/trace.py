@@ -151,6 +151,9 @@ _SUB_SCOPES = frozenset({"build", "probe", "exchange", "broadcast",
 # counts): `exchange/scatter` fills the send buffers, `exchange/all_to_all`
 # moves them
 _BOUNDARY_SCOPES = frozenset({"scatter", "all_to_all", "count"})
+# the 128-bit decimal kernels' scope (exprs/decimal128.py): `dec128/sum`,
+# `dec128/div`, `dec128/mul`, `dec128/cmp`, `dec128/cast`
+_WIDE_SCOPE = "dec128"
 # what jax puts between a label and a sub-scope opened inside a branch of
 # a `lax.cond` (the join's choice of probe)
 _COND_PARTS = re.compile(r"^(cond|branch_[0-9]+_fun)$")
@@ -160,11 +163,17 @@ def label_of(scope_path: str) -> Tuple[str, str]:
     """(operator label, sub-scope) of a scope path such as
     `jit(program)/agg#0/broadcast_join#2/probe/jit(_take)/gather`: the
     innermost operator label and the sub-scope right under it (two deep
-    beneath a boundary: `exchange/scatter`); the stage program's
+    beneath a boundary: `exchange/scatter`; a 128-bit kernel family
+    wherever it lies beneath the label: `dec128/sum`); the stage program's
     `epilogue`; else `unlabelled`."""
     parts = [p for p in scope_path.split("/") if not _COND_PARTS.match(p)]
     for i in range(len(parts) - 1, -1, -1):
         if _OP_LABEL.match(parts[i]):
+            if _WIDE_SCOPE in parts[i + 1:-1]:
+                # a 128-bit kernel family (exprs/decimal128.py), however
+                # deep beneath the label: `agg#3/reduce/dec128/sum/...`
+                at = parts.index(_WIDE_SCOPE, i + 1)
+                return parts[i], f"{_WIDE_SCOPE}/{parts[at + 1]}"
             below = parts[i + 1:i + 3]
             if not below or below[0] not in _SUB_SCOPES:
                 return parts[i], ""

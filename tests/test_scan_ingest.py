@@ -310,11 +310,11 @@ def test_doubles_arrive_as_the_file_holds_them_whatever_the_sidecar(
 def test_a_host_resident_column_is_handed_on_and_the_shard_refuses_it(
         fmt, what, tmp_path):
     """A string wider than `auron.string.device.max.width` and a
-    decimal(p > 18) never were on the device: the serial engine carries
+    decimal(p > 18) never were on the serial engine's device: it carries
     them as a `HostColumn`, Arrow in and the same Arrow out.  Both paths
-    hand them on as the file holds them, and `_shard_table` raises
-    `SpmdUnsupported` for either (the session then runs the plan
-    serially)."""
+    hand them on as the file holds them; `_shard_table` raises
+    `SpmdUnsupported` for the string (the session then runs the plan
+    serially) and, since PR 35, holds the decimal as two words a value."""
     if what == "wide-string":
         dtype, values = T.string(), ["x" * 40, None, "", "y" * 3]
     else:
@@ -331,6 +331,15 @@ def test_a_host_resident_column_is_handed_on_and_the_shard_refuses_it(
         assert same_table(got, t) and same_table(got, serial(node))
         assert read["device_batches"] == 0
         for table in (got, serial(node)):
+            if what == "decimal-38":
+                _schema, cols, _live, _cap = S._shard_table(
+                    table, data_mesh(1), "parts")
+                assert [(int(h) << 64) + int(lo) for h, lo in zip(
+                    cols[1].hi[:4], cols[1].lo[:4])] == [
+                    123456789012345678901234567891, 0, -1, 70000]
+                assert cols[1].validity[:4].tolist() == [
+                    True, False, True, True]
+                continue
             with pytest.raises(S.SpmdUnsupported, match="host-resident"):
                 S._shard_table(table, data_mesh(1), "parts")
 

@@ -6,6 +6,7 @@ used to make by going up through `Batch.from_arrow`, back through
 returned; and the stage driver's spans and counters say what crossed."""
 
 import datetime
+import decimal
 from decimal import Decimal
 
 import jax
@@ -275,10 +276,10 @@ def test_the_put_places_each_devices_rows_on_it(n_dev):
                 host[d * cap:(d + 1) * cap].tobytes()
 
 
+WIDE_DECIMALS = [Decimal("12345678901234567890123456.7891"), None,
+                 Decimal(-7)] * 4
+
 HOST_COLUMNS = {
-    "decimal-38": lambda: pa.array(
-        [Decimal("12345678901234567890123456.7891"), None, Decimal(7)] * 4,
-        pa.decimal128(38, 4)),
     # past the width the device holds, in the last shard alone
     "wide-string": lambda: pa.array(["a", None, "bc"] * 3 + ["x" * 40] * 3),
     "nested": lambda: pa.array([[1, 2], None, []] * 4, pa.list_(pa.int64())),
@@ -293,6 +294,27 @@ def test_a_host_resident_column_is_still_refused(what, n_dev):
         assert Batch.from_arrow(table).has_host_columns()
         with pytest.raises(S.SpmdUnsupported, match="host-resident"):
             S._shard_table(table, data_mesh(n_dev), "parts")
+
+
+@pytest.mark.parametrize("n_dev", N_DEVS)
+def test_a_wide_decimal_source_is_two_words_a_value(n_dev):
+    """Since PR 35 the stage program holds a decimal of 19-38 digits
+    (`DeviceDecimal128Column`: numpy leaves here, like every column of
+    `_shard_table`), where the serial engine still keeps it on the host."""
+    table = pa.table({"k": pa.array(range(12)),
+                      "v": pa.array(WIDE_DECIMALS, pa.decimal128(38, 4))})
+    assert Batch.from_arrow(table).has_host_columns()
+    _schema, cols, _live, cap = S._shard_table(table, data_mesh(n_dev),
+                                               "parts")
+    v = cols[1]
+    assert all(isinstance(x, np.ndarray) for x in (v.hi, v.lo, v.validity))
+    per = 12 // n_dev
+    for i, want in enumerate(WIDE_DECIMALS):
+        at = (i // per) * cap + i % per
+        assert bool(v.validity[at]) == (want is not None)
+        got = (int(v.hi[at]) << 64) + int(v.lo[at])
+        assert got == (0 if want is None else int(
+            want.scaleb(4, decimal.Context(prec=40))))
 
 
 # -- `Batch.from_arrow` returns what it returned ----------------------------

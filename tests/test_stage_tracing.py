@@ -495,6 +495,15 @@ OPS = [
      ("agg#3", "compact")),
     ("jit(p)/agg#3/cond/branch_1_fun/reduce/while/body/jit(_take)/gather",
      ("agg#3", "reduce")),
+    # the 128-bit decimal kernels, however deep beneath the label
+    ("jit(p)/agg#28/reduce/dec128/sum/jit(_take)/gather",
+     ("agg#28", "dec128/sum")),
+    ("jit(p)/agg#28/cond/branch_1_fun/reduce/dec128/div/while/body/sub",
+     ("agg#28", "dec128/div")),
+    ("jit(p)/projection#27/dec128/mul/mul", ("projection#27", "dec128/mul")),
+    ("jit(p)/filter#8/dec128/cmp/lt", ("filter#8", "dec128/cmp")),
+    ("jit(p)/agg#28/projection#31/dec128/cast/mul",
+     ("projection#31", "dec128/cast")),
     ("jit(p)/jit(_take)/gather", (trace_cli.UNLABELLED, "")),
     ("", (trace_cli.UNLABELLED, "")),
 ])
