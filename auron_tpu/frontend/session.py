@@ -84,9 +84,10 @@ class SessionResult:
     # what the stage program reported of itself (parallel/stage.py::
     # execute_plan_spmd's `stats`): `join_probes`, the probe each K=1
     # join took, and `agg_inputs`, the input each aggregate that chose
-    # worked on, by operator label; `ingest`, what the scan leaves'
-    # tasks read; over more than one device also `exchanges`,
-    # `broadcasts` and `sources`, what crossed devices
+    # worked on, by operator label; `segments`, the segment bounds the
+    # program's trace derived and the reductions over them; `ingest`, what
+    # the scan leaves' tasks read; over more than one device also
+    # `exchanges`, `broadcasts` and `sources`, what crossed devices
     stage_stats: Dict[str, object] = field(default_factory=dict)
 
     def to_pylist(self) -> List[dict]:
@@ -131,7 +132,10 @@ class SessionResult:
         that probed by direct address on every device); `agg_inputs`
         (aggregates whose input is larger than their output's capacity)
         and `agg_inputs_compact` (those whose input every device compacted
-        to that capacity first); over more than one device also
+        to that capacity first); `segment_bounds` (segment bounds derived
+        while the stage program was traced: one an aggregate body) and
+        `segment_reductions` (sorted-segment reductions that took them);
+        over more than one device also
         `exchange_rows`, `exchange_rows_moved`, `exchange_buffer_bytes`,
         `exchange_fill_pct_max`, `broadcast_rows`, `broadcast_slots` and
         `broadcast_buffer_bytes` (stage.py::crossing_totals)."""

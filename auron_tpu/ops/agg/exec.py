@@ -37,6 +37,7 @@ from auron_tpu.exprs.typing import infer_type
 from auron_tpu.ir.expr import AggExpr
 from auron_tpu.ir.schema import DataType, Field, Schema
 from auron_tpu.memmgr import MemConsumer, SpillManager
+from auron_tpu.ops import segments
 from auron_tpu.ops.agg.functions import AggSpec, HostAggSpec, make_spec
 from auron_tpu.ops.base import Operator, TaskContext, batch_size
 from auron_tpu.ops.segments import in_branch
@@ -749,7 +750,7 @@ class AggExec(Operator, MemConsumer):
         cap = bucket_capacity(1)
         empty = Batch.empty(
             self.children[0].schema if self.children else self.schema, cap)
-        seg = jnp.zeros(cap, jnp.int32)
+        seg = segments.segment_bounds(jnp.zeros(cap, jnp.int32), cap)
         out_cols: List[Any] = []
         for spec, a in zip(self.specs, self.aggs):
             zero_in = [
@@ -770,54 +771,77 @@ class AggExec(Operator, MemConsumer):
         return Batch(self.schema, out_cols, 1, cap)
 
 
+def _group_segments(keys: List[Any], live, orders):
+    """Sort + segment structure + key gather over an explicit live mask:
+    (perm, seg, n_groups, key_out).  Live rows sort first (pad rank), so
+    sorted-live = arange < sum(live); `seg` is the sorted rows' ascending
+    group number — the dead rows' is `capacity - 1` — WITH its bounds
+    (`segments.SegmentBounds`), which the boundaries found here already
+    say: group g starts at the g-th boundary row and ends where group
+    g + 1 starts, the last group at `n_live`, and the padding's segment
+    is [n_live, capacity).  Every reduction over `seg` reads them; none
+    searches for them."""
+    capacity = live.shape[0]
+    rows = jnp.arange(capacity, dtype=jnp.int32)
+    n_live = jnp.sum(live.astype(jnp.int32))
+    words = encode_sort_keys(keys, orders)
+    perm = lexsort_indices_live(words, live)
+    slive = rows < n_live
+    sorted_words = [jnp.take(w, perm) for w in words]
+    if sorted_words:
+        eq_prev = keys_equal_prev(sorted_words)
+    else:
+        # global agg: every row belongs to the single segment
+        eq_prev = rows != 0
+    is_boundary = jnp.logical_and(jnp.logical_not(eq_prev), slive)
+    seg_of_sorted = jnp.cumsum(is_boundary.astype(jnp.int32)) - 1
+    seg_of_sorted = jnp.where(slive, seg_of_sorted, capacity - 1)
+    n_groups = jnp.sum(is_boundary.astype(jnp.int32))
+    if in_branch():
+        # `jnp.nonzero(size=)` counts in 64 bits, which XLA:TPU does
+        # not compile inside a conditional's branch (ops/segments.py
+        # `inside_branch`): a boundary row's segment id is its rank,
+        # so one scatter of row numbers gives the same array
+        first_sorted_idx = jnp.zeros(capacity, jnp.int32).at[
+            jnp.where(is_boundary, seg_of_sorted, capacity)
+        ].set(rows, mode="drop")
+    else:
+        first_sorted_idx = jnp.nonzero(
+            is_boundary, size=capacity,
+            fill_value=0)[0].astype(jnp.int32)
+    # slots past the last group read start 0 and end 0: empty
+    next_start = jnp.concatenate(
+        [first_sorted_idx[1:], jnp.zeros(1, jnp.int32)])
+    ends = jnp.where(rows < n_groups - 1, next_start,
+                     jnp.where(rows == n_groups - 1, n_live, 0))
+    # with every slot live and a group of its own, `capacity - 1` is the
+    # last group's number and no padding's
+    padding = jnp.logical_and(rows == capacity - 1, n_live < capacity)
+    seg = segments.known_bounds(
+        seg_of_sorted,
+        jnp.where(padding, n_live, first_sorted_idx),
+        jnp.where(padding, capacity, ends))
+    key_src = jnp.take(perm, first_sorted_idx)
+    g_valid = rows < n_groups
+    return perm, seg, n_groups, [k.gather(key_src, g_valid) for k in keys]
+
+
 def _group_reduce_body(keys: List[Any], value_cols: List[List[Any]],
                        live, specs, orders, merge: bool):
     """Pure-jax sort-based group reduction over an explicit live mask.
-    Live rows sort first (pad rank), so sorted-live = arange < sum(live).
     Returns (out_cols, n_groups) with n_groups a device scalar.  The two
     steps carry named scopes (`group`, `reduce`): a device profile files
     the sort and the segment arithmetic apart (auron_tpu.trace device)."""
     capacity = live.shape[0]
     with jax.named_scope("group"):
-        n_live = jnp.sum(live.astype(jnp.int32))
-        words = encode_sort_keys(keys, orders)
-        perm = lexsort_indices_live(words, live)
-        slive = jnp.arange(capacity, dtype=jnp.int32) < n_live
-        sorted_words = [jnp.take(w, perm) for w in words]
-        if sorted_words:
-            eq_prev = keys_equal_prev(sorted_words)
-        else:
-            # global agg: every row belongs to the single segment
-            eq_prev = jnp.arange(capacity, dtype=jnp.int32) != 0
-        is_boundary = jnp.logical_and(jnp.logical_not(eq_prev), slive)
-        seg_of_sorted = jnp.cumsum(is_boundary.astype(jnp.int32)) - 1
-        seg_of_sorted = jnp.where(slive, seg_of_sorted, capacity - 1)
-        n_groups = jnp.sum(is_boundary.astype(jnp.int32))
-        if in_branch():
-            # `jnp.nonzero(size=)` counts in 64 bits, which XLA:TPU does
-            # not compile inside a conditional's branch (ops/segments.py
-            # `inside_branch`): a boundary row's segment id is its rank,
-            # so one scatter of row numbers gives the same array
-            first_sorted_idx = jnp.zeros(capacity, jnp.int32).at[
-                jnp.where(is_boundary, seg_of_sorted, capacity)
-            ].set(jnp.arange(capacity, dtype=jnp.int32), mode="drop")
-        else:
-            first_sorted_idx = jnp.nonzero(
-                is_boundary, size=capacity,
-                fill_value=0)[0].astype(jnp.int32)
-        key_src = jnp.take(perm, first_sorted_idx)
-        g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
-        out_cols: List[Any] = []
-        for k in keys:
-            out_cols.append(k.gather(key_src, g_valid))
+        perm, seg, n_groups, out_cols = _group_segments(keys, live, orders)
     with jax.named_scope("reduce"):
         for spec, cols in zip(specs, value_cols):
             scols = [_gather_col(c, perm) for c in cols]
             if merge:
-                states = spec.merge_segments(scols, seg_of_sorted, capacity)
+                states = spec.merge_segments(scols, seg, capacity)
             else:
-                states = spec.update_segments(scols, seg_of_sorted,
-                                              capacity)
+                states = spec.update_segments(scols, seg, capacity)
             out_cols.extend(_clip_states(states, n_groups))
     return out_cols, n_groups
 
@@ -826,26 +850,7 @@ def _sort_base_builder(orders):
     """Shared half of the split merge reduction: sort + segment structure
     + key gather (no per-spec state math)."""
     def run(keys, live):
-        capacity = live.shape[0]
-        n_live = jnp.sum(live.astype(jnp.int32))
-        words = encode_sort_keys(keys, orders)
-        perm = lexsort_indices_live(words, live)
-        slive = jnp.arange(capacity, dtype=jnp.int32) < n_live
-        sorted_words = [jnp.take(w, perm) for w in words]
-        if sorted_words:
-            eq_prev = keys_equal_prev(sorted_words)
-        else:
-            eq_prev = jnp.arange(capacity, dtype=jnp.int32) != 0
-        is_boundary = jnp.logical_and(jnp.logical_not(eq_prev), slive)
-        seg = jnp.cumsum(is_boundary.astype(jnp.int32)) - 1
-        seg = jnp.where(slive, seg, capacity - 1)
-        n_groups = jnp.sum(is_boundary.astype(jnp.int32))
-        first_idx = jnp.nonzero(is_boundary, size=capacity,
-                                fill_value=0)[0].astype(jnp.int32)
-        key_src = jnp.take(perm, first_idx)
-        g_valid = jnp.arange(capacity, dtype=jnp.int32) < n_groups
-        key_out = [k.gather(key_src, g_valid) for k in keys]
-        return perm, seg, n_groups, key_out
+        return _group_segments(keys, live, orders)
     return run
 
 

@@ -5,9 +5,18 @@ always reduces over SORTED segment ids (they come from a lexsort +
 boundary cumsum).  XLA lowers jax.ops.segment_* to scatter-(add|min|max),
 which serializes badly on TPU; for sorted ids the same reductions are
 expressible with purely gather-shaped ops — cumulative scan along rows,
-then a vectorized binary search for each segment's [start, end) range —
-the TPU-friendly form (reference analogue: Auron leans on radix-sorted
-runs for exactly this reason, agg/agg_table.rs).
+read at each segment's [start, end) range — the TPU-friendly form
+(reference analogue: Auron leans on radix-sorted runs for exactly this
+reason, agg/agg_table.rs).
+
+The ranges are known, not searched: a segment starts where the id differs
+from the row before and ends where it differs from the row after
+(`SegmentBounds`).  They are derived once per `seg` — by the caller that
+already holds the boundaries (`known_bounds`: ops/agg/exec.py) or from
+the ids alone (`segment_bounds`: two scatters) — and handed to every
+reduction over it in `seg`'s place.  A binary search per reduction
+(`jnp.searchsorted`: ceil(log2(n + 1)) dependent n-index gathers for each
+of starts and ends) was 14-36 % of the stage programs' device time (PR 36).
 
 - sum:  inclusive cumsum; total(s) = csum[end(s)-1] - csum[start(s)-1].
   Integer sums are EXACT even if the running cumsum wraps (modular diff);
@@ -18,13 +27,15 @@ runs for exactly this reason, agg/agg_table.rs).
   reset-at-segment-start combine, read at end(s)-1.
 
 All functions take 1-D x and require seg ascending (rows of equal seg
-contiguous).  Callers with possibly-unsorted ids must keep using
-jax.ops.segment_*.  Behavior matches jax.ops.segment_{sum,min,max}
-(empty segments -> 0 / +inf|max / -inf|min).
+contiguous), as an array of ids or as the `SegmentBounds` of one.
+Callers with possibly-unsorted ids must keep using jax.ops.segment_*.
+Behavior matches jax.ops.segment_{sum,min,max} (empty segments -> 0 /
++inf|max / -inf|min).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 
 import jax
@@ -81,11 +92,95 @@ def _int_cumsum(x):
     return (rows + before[:, None]).reshape(-1)[:n]
 
 
-def _segment_ranges(seg, num_segments: int):
-    sids = jnp.arange(num_segments, dtype=seg.dtype)
-    starts = jnp.searchsorted(seg, sids, side="left")
-    ends = jnp.searchsorted(seg, sids, side="right")
-    return starts, ends, ends > starts
+class counting:
+    """Trace-time context: counts the segment bounds derived
+    (`bounds`) and the reductions that took them (`reductions`) in what
+    is traced here, for the stage program's counter (parallel/stage.py:
+    `segment_bounds` / `segment_reductions`).  Thread-local, like
+    `inside_branch`."""
+
+    def __enter__(self):
+        self.bounds = 0
+        self.reductions = 0
+        self._outer = getattr(_TRACE_MODE, "counter", None)
+        _TRACE_MODE.counter = self
+        return self
+
+    def __exit__(self, *exc):
+        _TRACE_MODE.counter = self._outer
+
+
+def _count(what: str) -> None:
+    counter = getattr(_TRACE_MODE, "counter", None)
+    if counter is not None:
+        setattr(counter, what, getattr(counter, what) + 1)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class SegmentBounds:
+    """Ascending segment ids with every segment's rows [start, end):
+    what a sorted-segment reduction reads, computed once and taken in
+    `seg`'s place by all of them.  `ids` is int32[n]; `starts` and `ends`
+    are int32[num_segments], meaningful where `ends > starts` (an empty
+    segment holds whatever makes that false).  A pytree: it crosses a
+    `jit` boundary like the array it stands for, and answers `shape` as
+    that array does (a count of all rows asks it)."""
+    ids: jax.Array
+    starts: jax.Array
+    ends: jax.Array
+
+    @property
+    def shape(self):
+        return self.ids.shape
+
+    @property
+    def nonempty(self):
+        return self.ends > self.starts
+
+    def is_first(self):
+        """bool[n]: the row opens its segment (row 0 always does)."""
+        return jnp.concatenate(
+            [jnp.ones((1,), bool), self.ids[1:] != self.ids[:-1]])
+
+
+def known_bounds(ids, starts, ends) -> SegmentBounds:
+    """The bounds a caller already holds (the aggregate's boundaries:
+    ops/agg/exec.py `_group_segments`), counted as derived once."""
+    _count("bounds")
+    return SegmentBounds(ids, starts, ends)
+
+
+def segment_bounds(seg, num_segments: int) -> SegmentBounds:
+    """Bounds from ascending ids alone, gaps and empty segments included
+    (an empty segment reads [0, 0)): a row that differs from the one
+    before is its segment's start, one that differs from the one after
+    its last, and each such row writes its number to its segment's slot.
+    Two scatters of unique indices (every other row is sent past the end,
+    each to a slot of its own, and dropped); no loop, no search, 32 bits
+    throughout, so it compiles inside a conditional's branch too."""
+    if isinstance(seg, SegmentBounds):
+        if seg.starts.shape[0] != num_segments:
+            raise ValueError(
+                f"bounds of {seg.starts.shape[0]} segments handed to a "
+                f"reduction over {num_segments}")
+        return seg
+    _count("bounds")
+    n = seg.shape[0]
+    ids = seg.astype(jnp.int32)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    differs = ids[1:] != ids[:-1]
+    edge = jnp.ones((1,), bool)
+    dropped = num_segments + rows
+
+    def slots(at, values):
+        return jnp.zeros((num_segments,), jnp.int32).at[
+            jnp.where(at, ids, dropped)].set(
+                values, mode="drop", unique_indices=True)
+
+    return SegmentBounds(
+        seg, slots(jnp.concatenate([edge, differs]), rows),
+        slots(jnp.concatenate([differs, edge]), rows + 1))
 
 
 def sorted_segment_sum(x, seg, num_segments: int):
@@ -93,7 +188,9 @@ def sorted_segment_sum(x, seg, num_segments: int):
     jax.ops.segment_sum(x, seg, num_segments))."""
     if x.shape[0] == 0:
         return jnp.zeros((num_segments,), x.dtype)
-    starts, ends, nonempty = _segment_ranges(seg, num_segments)
+    seg = segment_bounds(seg, num_segments)
+    _count("reductions")
+    starts, ends, nonempty = seg.starts, seg.ends, seg.nonempty
     if jnp.issubdtype(x.dtype, jnp.floating):
         # floats must NOT use the global-cumsum difference: an all-zero
         # segment differencing two ~equal multi-million cumsums comes
@@ -101,9 +198,7 @@ def sorted_segment_sum(x, seg, num_segments: int):
         # year pivots) and explodes ratios.  A segmented scan resets the
         # running sum at each segment start, so a segment's total only
         # ever adds its OWN elements — exact zeros stay exact.
-        is_first = jnp.concatenate(
-            [jnp.ones((1,), bool), seg[1:] != seg[:-1]])
-        run = _segmented_scan(x, is_first, jnp.add)
+        run = _segmented_scan(x, seg.is_first(), jnp.add)
         total = jnp.take(run, jnp.clip(ends - 1, 0), mode="clip")
         return jnp.where(nonempty, total, jnp.zeros((), x.dtype))
     # integer sums: modular cumsum difference is EXACT even on wrap
@@ -170,11 +265,11 @@ def _sorted_segment_extreme(x, seg, num_segments: int, op_is_min: bool):
     fill = _extreme_identity(x.dtype, op_is_min)
     if x.shape[0] == 0:
         return jnp.full((num_segments,), fill, x.dtype)
-    is_first = jnp.concatenate([jnp.ones((1,), bool), seg[1:] != seg[:-1]])
-    run = segmented_running(x, is_first, op_is_min)
-    starts, ends, nonempty = _segment_ranges(seg, num_segments)
-    at_end = jnp.take(run, jnp.clip(ends - 1, 0), mode="clip")
-    return jnp.where(nonempty, at_end, jnp.asarray(fill, x.dtype))
+    seg = segment_bounds(seg, num_segments)
+    _count("reductions")
+    run = segmented_running(x, seg.is_first(), op_is_min)
+    at_end = jnp.take(run, jnp.clip(seg.ends - 1, 0), mode="clip")
+    return jnp.where(seg.nonempty, at_end, jnp.asarray(fill, x.dtype))
 
 
 def sorted_segment_min(x, seg, num_segments: int):

@@ -202,11 +202,13 @@ def segment_context(sp, so, live, cap):
     seg_start = jax.lax.cummax(jnp.where(part_bound, idx, NEG))
     og_start = jax.lax.cummax(jnp.where(order_bound, idx, NEG))
     seg_id = jnp.cumsum(part_bound.astype(jnp.int32)) - 1
-    seg_id = jnp.where(live, seg_id, cap - 1)
+    # with its bounds, derived here once for every total over partitions
+    seg_id = segments.segment_bounds(
+        jnp.where(live, seg_id, cap - 1), cap)
     # partition sizes + last index
     ones = jnp.where(live, 1, 0)
     seg_sizes = segments.sorted_segment_sum(ones, seg_id, cap)
-    part_n = jnp.take(seg_sizes, seg_id)
+    part_n = jnp.take(seg_sizes, seg_id.ids)
     seg_end = seg_start + part_n  # exclusive
 
     row_number = (idx - seg_start + 1).astype(jnp.int64)
@@ -415,7 +417,7 @@ def _seg_total(x, c):
     seg = c["seg_id"]
     cap = c["cap"]
     tot = segments.sorted_segment_sum(x, seg, cap)
-    return jnp.take(tot, seg)
+    return jnp.take(tot, seg.ids)
 
 
 def _seg_running_minmax(x, c, is_min: bool):
@@ -430,7 +432,7 @@ def _seg_total_minmax(x, c, is_min: bool):
     cap = c["cap"]
     red = segments.sorted_segment_min(x, seg, cap) if is_min else \
         segments.sorted_segment_max(x, seg, cap)
-    return jnp.take(red, seg)
+    return jnp.take(red, seg.ids)
 
 
 def _rechunk_stream(b: Batch) -> Iterator[Batch]:

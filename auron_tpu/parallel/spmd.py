@@ -101,8 +101,9 @@ def local_group_aggregate(key, value, live, dim_key, dim_val):
     slive = slive_i.astype(bool)
     boundary = jnp.logical_and(
         jnp.concatenate([jnp.ones(1, bool), sk[1:] != sk[:-1]]), slive)
-    seg = jnp.where(slive, jnp.cumsum(boundary.astype(jnp.int32)) - 1,
-                    cap2 - 1)
+    seg = segments.segment_bounds(
+        jnp.where(slive, jnp.cumsum(boundary.astype(jnp.int32)) - 1,
+                  cap2 - 1), cap2)
     sums = segments.sorted_segment_sum(jnp.where(slive, sv, 0.0), seg,
                                        cap2)
     counts = segments.sorted_segment_sum(slive.astype(jnp.int64), seg,

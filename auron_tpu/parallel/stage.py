@@ -62,6 +62,7 @@ from auron_tpu.ir import plan as P
 from auron_tpu.ir.expr import Expr
 from auron_tpu.ir.node import Node
 from auron_tpu.ir.schema import DataType, Field, Schema, TypeId
+from auron_tpu.ops import segments
 from auron_tpu.ops.segments import inside_branch
 from auron_tpu.ops.sort_keys import stable_argsort
 from auron_tpu.parallel.exchange import (
@@ -1683,10 +1684,12 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     guard tripped last): `join_probes`, {operator label: "direct" |
     "search" | "direct k/n"} for every K=1 join; `agg_inputs`, {operator
     label: input "compact" | "full" | "compact k/n", live rows, capacity}
-    for every aggregate that chose (`_agg_input_marks`); over more than one
-    device also `exchanges` and `broadcasts`, {operator label: counts} for
-    every boundary (`_crossing_stats`), and `sources`, {canonical rid:
-    rows, cap, rows on the fullest and the emptiest device}.  `ingest`
+    for every aggregate that chose (`_agg_input_marks`); `segments`, the
+    segment `bounds` derived in the program's trace and the `reductions`
+    that took them; over more than one device also `exchanges` and
+    `broadcasts`, {operator label: counts} for every boundary
+    (`_crossing_stats`), and `sources`, {canonical rid: rows, cap, rows
+    on the fullest and the emptiest device}.  `ingest`
     holds what the scan leaves' tasks read (`INGEST_COUNTS`) and `shard`
     what was placed on the device (`SHARD_COUNTS`), each with the state of
     its source cache (`CACHE_STATE`): summed over the attempts, but the
@@ -1977,6 +1980,14 @@ def agg_input_counts(aggs: Dict[str, dict]) -> Dict[str, int]:
                                       for a in aggs.values())}
 
 
+def segment_counts(counted: Dict[str, int]) -> Dict[str, int]:
+    """The counter's two numbers: segment bounds derived while the stage
+    program was traced (one an aggregate body: both sides of a choice
+    count), and the sorted-segment reductions that took them."""
+    return {"segment_bounds": counted.get("bounds", 0),
+            "segment_reductions": counted.get("reductions", 0)}
+
+
 def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
     """{"exchanges": {label: ..}, "broadcasts": {label: ..}} of one run,
     from what the tracer knew of each boundary (`cross_box`) and the
@@ -2007,12 +2018,15 @@ def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
 
 
 def _reported(probe_box, direct_np, agg_box, agg_np, cross_box, crossed_np,
-              wide_box, n_dev: int) -> Dict[str, Any]:
+              wide_box, segment_box, n_dev: int) -> Dict[str, Any]:
     """What one run's program reported of itself, as execute_plan_spmd's
     `stats` hold it; `wide_columns` (operator label -> wide decimal
-    columns in its output) only where the program held one."""
+    columns in its output) only where the program held one; `segments`,
+    the segment bounds derived while it was traced and the reductions
+    that took them (`segments.counting`)."""
     return {"join_probes": _probe_marks(probe_box, direct_np, n_dev),
             "agg_inputs": _agg_input_marks(agg_box, agg_np, n_dev),
+            "segments": dict(segment_box),
             **({"wide_columns": dict(wide_box)} if wide_box else {}),
             **_crossing_stats(cross_box, crossed_np)}
 
@@ -2063,11 +2077,12 @@ def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
     """execute_plan_spmd's `stats` as query totals: what the scan tasks
     read, the probe counter's two numbers, the aggregate inputs' two, the
-    ladder's rung and the wide decimal columns, and the boundaries'
-    counts."""
+    segment bounds' two, the ladder's rung and the wide decimal columns,
+    and the boundaries' counts."""
     return {**ingest_totals(stats),
             **probe_counts(stats.get("join_probes") or {}),
             **agg_input_counts(stats.get("agg_inputs") or {}),
+            **segment_counts(stats.get("segments") or {}),
             **wide_totals(stats),
             **crossing_totals(stats)}
 
@@ -2298,6 +2313,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         agg_box: List[Dict[str, Any]] = []
         # operator label -> wide decimal columns in its output
         wide_box: Dict[str, int] = {}
+        # segment bounds derived in the trace, and reductions over them
+        segment_box: Dict[str, int] = {}
         labels = {id(node): label
                   for _depth, node, label in operator_labels(plan, conv_ctx)}
 
@@ -2312,9 +2329,12 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                   match_factor=match_factor,
                                   agg_cap_hint=agg_cap_hint,
                                   join_compact=join_compact)
-            out = tracer.eval_node(plan)
+            with segments.counting() as counted:
+                out = tracer.eval_node(plan)
             if not schema_box:
                 schema_box.append(out.schema)
+                segment_box.update(bounds=counted.bounds,
+                                   reductions=counted.reductions)
                 probe_box.extend((label, flag is not None)
                                  for label, flag in tracer.probes)
                 cross_box.extend(what for what, _n in tracer.crossings)
@@ -2365,7 +2385,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                        PS(), PS(), PS(), PS()),
             check_vma=False))
     else:
-        shard, schema_box, probe_box, cross_box, agg_box, wide_box = cached
+        (shard, schema_box, probe_box, cross_box, agg_box, wide_box,
+         segment_box) = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
     # compiles the whole stage program, so the span is the compile span
@@ -2380,7 +2401,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             shard(host_inputs)
     if cached is None:
         _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box,
-                                     cross_box, agg_box, wide_box)
+                                     cross_box, agg_box, wide_box,
+                                     segment_box)
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
@@ -2397,7 +2419,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 (counts, guards, retry_guards, shrink_guards,
                  join_guards, probe_direct, crossed, agg_compact))
             reported = _reported(probe_box, direct_np, agg_box, agg_np,
-                                 cross_box, crossed_np, wide_box, n_dev)
+                                 cross_box, crossed_np, wide_box,
+                                 segment_box, n_dev)
             sp.set_args(**stage_totals(reported))
         if stats is not None:
             # before the guards: a tripped exchange guard's fill is what

@@ -262,7 +262,9 @@ def test_the_choice_is_one_conditional_with_no_collective_inside():
 def test_counter_in_the_record_the_span_and_explain_analyze(tmp_path):
     """`agg_inputs` / `agg_inputs_compact` in the query record's totals and
     on `spmd.wait`'s args; `input=compact live=<rows> of <capacity>` on the
-    aggregate's line of EXPLAIN ANALYZE."""
+    aggregate's line of EXPLAIN ANALYZE.  Beside them `segment_bounds`,
+    one an aggregate body traced (both sides of a choice are), and
+    `segment_reductions`, the reductions that took them."""
     from auron_tpu.frontend.session import AuronSession
     from auron_tpu.it import queries
     from auron_tpu.it.datagen import generate
@@ -288,12 +290,22 @@ def test_counter_in_the_record_the_span_and_explain_analyze(tmp_path):
     for where in (totals, wait.args, res.stage_totals()):
         assert where["agg_inputs"] == where["agg_inputs_compact"] \
             == len(aggs)
+        assert where["segment_bounds"] == len(lines) + len(aggs)
+        assert where["segment_reductions"] > where["segment_bounds"]
+    assert res.stage_stats["segments"] == {
+        "bounds": totals["segment_bounds"],
+        "reductions": totals["segment_reductions"]}
     # at the default hint q03's tables are no larger than the target: the
     # counter is there and counts nothing
     with conf.scoped({"auron.trace.enable": True}):
         plain = session.execute(plan)
     assert plain.stage_stats["agg_inputs"] == {}
     assert "input=" not in plain.explain_analyze()
-    assert tracing.find_query(plain.query_id).metric_totals[
-        "agg_inputs"] == 0
+    plain_totals = tracing.find_query(plain.query_id).metric_totals
+    assert plain_totals["agg_inputs"] == 0
+    assert plain_totals["segment_bounds"] == len(lines)
+    # the choice's untaken side held as many reductions as the taken one
+    assert (totals["segment_reductions"] * len(lines)
+            == plain_totals["segment_reductions"]
+            * (len(lines) + len(aggs)))
     assert plain.table.equals(res.table)

@@ -341,13 +341,15 @@ def test_one_device_counts_nothing(runs):
     pinned in test_one_program.py); at `rehearse_rows` no table of query
     7 (65,536 slots at most) is larger than the capacity hint's 262,144,
     so no aggregate has a choice to trace: the program's `agg_inputs` is
-    there and empty."""
+    there and empty, and its two aggregate bodies derived their segments'
+    bounds once each for the sixteen reductions over them."""
     _cat, _params, session, plan, _got = runs[SEEDS[0]]
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
     assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_probes",
-                                       "shard"]
+                                       "segments", "shard"]
     assert one.stage_stats["agg_inputs"] == {}
+    assert one.stage_stats["segments"] == {"bounds": 2, "reductions": 16}
     # the driver's own count of what the scan leaves' tasks read (PR 31)
     assert one.stage_stats["ingest"]["scans"] == 5
     assert one.stage_stats["ingest"]["device_batches"] == 0
@@ -355,6 +357,8 @@ def test_one_device_counts_nothing(runs):
     assert not set(_TOTALS) & set(totals)
     assert not set(_TOTALS) & set(_wait_args(one))
     assert totals["join_probes_direct"] == 4
+    assert (totals["segment_bounds"], totals["segment_reductions"]) == (2, 16)
+    assert _wait_args(one)["segment_bounds"] == 2
     shard = [s.args for s in one.trace.snapshot() if s.name == "spmd.shard"]
     # what `_DEVICE_SHARDS` did for the attempt (PR 33): each source
     # served or placed (shards placed for four devices serve no other

@@ -20,16 +20,19 @@ from test_spmd_stage import _lowered_join_text
 # `auron.kernel.join.probe.strategy` searchsorted; `jax.default_backend`
 # not patched: the float64 capability sites take the CPU's arm on both
 # sides).  A change that is meant to move these programs takes new digests
-# from the tree before it, the way these were taken.
+# from the tree before it, the way these were taken.  PR 36 moved the five
+# that hold an aggregate, on purpose (a sorted-segment reduction reads its
+# segments' bounds from the aggregate's boundaries: 20, 30, 40 and 32 calls
+# of `searchsorted` left them); the three join programs are a52851b's.
 CHIP_PROGRAM = {
     "agg-input-within-target":
-        "3ba4b16e5da7eea4b8bbf58f77410a309a634f6d8ea874a874b5b873713aa4fb",
+        "a46c6612703b3eea44126a17fb0994671808bf1b61d0ed5093a8e4e5bf1be517",
     "agg-shrink-off":
-        "3ba4b16e5da7eea4b8bbf58f77410a309a634f6d8ea874a874b5b873713aa4fb",
+        "a46c6612703b3eea44126a17fb0994671808bf1b61d0ed5093a8e4e5bf1be517",
     "agg-chooses":
-        "71c093f1228f8f5f3f871bd013968afa410bdb7535937db591d656d92d47a57f",
+        "3faff62eb918ac1a3b7a87795c9256bd5e6a5a788c78b5e5d3cac5c6c19da243",
     "agg-chooses-four-devices":
-        "f46cab202a7445701470292e02a6e8746557e0c8422f24f893a7e39b83c943a2",
+        "aeae6e91d2fc8762058c0ae5d79cdc52af4d406452f12f9ff8c8c179da2a7ec1",
     "join-string":
         "ed13fc3944f8778e208279e97c82dbc3f63b3972594b41de35323a214dfbfc81",
     "join-two-keys":
@@ -37,7 +40,7 @@ CHIP_PROGRAM = {
     "join-int64":
         "8b8593b5ab5b827288b42aaae7be5df7dff916cf3be418b68eafc2329a789009",
     "q07-one-device":
-        "73282d97d502c3cfed13a79142c6fb19df795fc56d42166ed6f0d70f520bdd68",
+        "d0e09e1e831635dcd445bbdb7bddaf537fffdfd774abe5f409a10dc42cefde79",
 }
 
 
@@ -78,10 +81,40 @@ _TEXT = {
 }
 
 
+_LOWERED = {}
+
+
+def _text(case, tmp_path):
+    """Lowered once a case for the tests of this file (one xdist worker
+    runs a file's tests)."""
+    if case not in _LOWERED:
+        _LOWERED[case] = _TEXT[case](tmp_path)
+    return _LOWERED[case]
+
+
 @pytest.mark.parametrize("case", sorted(CHIP_PROGRAM))
 def test_default_program_is_the_chips_program(case, tmp_path):
-    text = _TEXT[case](tmp_path)
+    text = _text(case, tmp_path)
     assert hashlib.sha256(text.encode()).hexdigest() == CHIP_PROGRAM[case]
+
+
+# the K = 1 joins of each program: the search side of `_lookup_adaptive`
+# holds the one `searchsorted` a join keeps
+_JOINS = {"join-string": 1, "join-two-keys": 1, "join-int64": 1,
+          "q07-one-device": 4}
+
+
+@pytest.mark.parametrize("case", sorted(CHIP_PROGRAM))
+def test_no_aggregate_searches_for_its_segments(case, tmp_path):
+    """A sorted-segment reduction reads its segments' bounds from the
+    boundaries the aggregate found (ops/agg/exec.py `_group_segments`):
+    the lowered program calls `searchsorted` once a join and never from
+    an aggregate (query 7's called it 36 times, 32 of them for the bounds
+    of the 16 reductions of `agg#3` and `agg#1`)."""
+    import re
+    text = _text(case, tmp_path)
+    assert len(re.findall(r"call @\S*searchsorted", text)) == \
+        _JOINS.get(case, 0)
 
 
 RETIRED_OPTIONS = [

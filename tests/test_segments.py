@@ -1,5 +1,7 @@
 """sorted_segment_* vs jax.ops.segment_* equivalence (fuzzed)."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -165,3 +167,180 @@ def test_integer_running_sum_inside_a_branch_is_the_same_sum(n, dtype):
         same = np.asarray(
             segments.sorted_segment_sum(jnp.asarray(x), seg, 16))
     assert np.array_equal(sums, same)
+
+
+# -- bounds known, not searched -----------------------------------------------
+
+def _searched_bounds(seg, num_segments):
+    """What `_segment_ranges` read before PR 36: two binary searches."""
+    sids = np.arange(num_segments)
+    starts = np.searchsorted(seg, sids, side="left")
+    ends = np.searchsorted(seg, sids, side="right")
+    return starts, ends, ends > starts
+
+
+def _seg_case(case):
+    """(ascending ids, num_segments) of one shape of `seg`."""
+    rng = np.random.default_rng(len(case))
+    return {
+        # ids drawn with repeats out of 300: gaps between the segments
+        "gaps": (np.sort(rng.integers(0, 300, 500)), 300),
+        # nothing below 40 nor above 59 of 100
+        "empty-leading-and-trailing": (
+            np.sort(rng.integers(40, 60, 257)), 100),
+        "one-segment": (np.zeros(64, np.int64), 1),
+        "one-segment-of-many": (np.full(64, 7), 64),
+        "every-row-its-own": (np.arange(128), 128),
+        # an aggregate over no live row: all in the padding's segment
+        "all-padding": (np.full(256, 255), 256),
+        # live groups, then the padding's segment capacity - 1
+        "groups-then-padding": (
+            np.concatenate([np.repeat(np.arange(20), 5),
+                            np.full(28, 127)]), 128),
+        "n-1": (np.zeros(1, np.int64), 1),
+        "n-1-last-of-many": (np.full(1, 4), 5),
+        "more-segments-than-rows": (np.sort(rng.integers(0, 2000, 100)),
+                                    2000),
+    }[case]
+
+
+_SEG_CASES = ["gaps", "empty-leading-and-trailing", "one-segment",
+              "one-segment-of-many", "every-row-its-own", "all-padding",
+              "groups-then-padding", "n-1", "n-1-last-of-many",
+              "more-segments-than-rows"]
+
+
+@pytest.mark.parametrize("branch", [False, True], ids=["plain", "branch"])
+@pytest.mark.parametrize("case", _SEG_CASES)
+def test_bounds_from_the_boundaries_equal_the_searched_ones(case, branch):
+    """`segment_bounds` against the two `searchsorted` it replaced:
+    `nonempty` for every segment, `starts` and `ends` wherever it holds;
+    an empty segment reads [0, 0).  And the three reductions given the
+    bounds return what they return given the ids."""
+    ids, num_segments = _seg_case(case)
+    seg = jnp.asarray(ids.astype(np.int32))
+    starts, ends, nonempty = _searched_bounds(ids, num_segments)
+    with segments.inside_branch() if branch else contextlib.nullcontext():
+        got = jax.jit(lambda s: segments.segment_bounds(s, num_segments))(
+            seg)
+    assert isinstance(got, segments.SegmentBounds)
+    assert got.shape == seg.shape
+    np.testing.assert_array_equal(np.asarray(got.ids), ids)
+    np.testing.assert_array_equal(np.asarray(got.nonempty), nonempty)
+    np.testing.assert_array_equal(np.asarray(got.starts)[nonempty],
+                                  starts[nonempty])
+    np.testing.assert_array_equal(np.asarray(got.ends)[nonempty],
+                                  ends[nonempty])
+    assert not np.asarray(got.starts)[~nonempty].any()
+    assert not np.asarray(got.ends)[~nonempty].any()
+    np.testing.assert_array_equal(
+        np.asarray(got.is_first()),
+        np.concatenate([[True], ids[1:] != ids[:-1]]))
+    rng = np.random.default_rng(7)
+    for x in (rng.integers(-1000, 1000, ids.shape[0]),
+              rng.normal(0, 10, ids.shape[0])):
+        x = jnp.asarray(x)
+        for op, ref in [
+                (segments.sorted_segment_sum, jax.ops.segment_sum),
+                (segments.sorted_segment_min, None),
+                (segments.sorted_segment_max, None)]:
+            alone = np.asarray(op(x, seg, num_segments))
+            shared = np.asarray(op(x, got, num_segments))
+            np.testing.assert_array_equal(shared, alone)
+            if ref is not None and x.dtype == jnp.int64:
+                np.testing.assert_array_equal(
+                    alone, np.asarray(ref(x, seg, num_segments)))
+
+
+def test_bounds_are_derived_once_and_counted():
+    """Handed to the reductions, bounds are neither derived again nor
+    searched for; `counting` counts both, nested or not; bounds of
+    another number of segments are refused."""
+    seg = jnp.asarray(np.sort(
+        np.random.default_rng(3).integers(0, 16, 200)).astype(np.int32))
+    x = jnp.arange(200, dtype=jnp.int64)
+
+    def shared(x, seg):
+        b = segments.segment_bounds(seg, 16)
+        return (segments.sorted_segment_sum(x, b, 16),
+                segments.sorted_segment_min(x, b, 16),
+                segments.sorted_segment_max(x.astype(jnp.float64), b, 16))
+
+    def alone(x, seg):
+        return (segments.sorted_segment_sum(x, seg, 16),
+                segments.sorted_segment_min(x, seg, 16))
+
+    with segments.counting() as counted:
+        text = str(jax.make_jaxpr(shared)(x, seg))
+    assert (counted.bounds, counted.reductions) == (1, 3)
+    assert text.count("= scatter[") == 2
+    with segments.counting() as counted:
+        with segments.counting() as inner:
+            text = str(jax.make_jaxpr(alone)(x, seg))
+        assert (inner.bounds, inner.reductions) == (2, 2)
+        segments.known_bounds(seg, seg[:16], seg[:16])
+    assert (counted.bounds, counted.reductions) == (1, 0)
+    assert text.count("= scatter[") == 4
+    assert getattr(segments._TRACE_MODE, "counter", None) is None
+    for traced in (shared, alone):
+        assert "while" not in str(jax.make_jaxpr(
+            lambda x, seg: traced(x, seg)[0])(x, seg))
+    with pytest.raises(ValueError):
+        segments.sorted_segment_sum(
+            x, segments.segment_bounds(seg, 16), 17)
+
+
+def test_no_search_is_reachable_from_the_segment_kernels():
+    import inspect
+    source = inspect.getsource(segments)
+    code = [ln.split("#")[0] for ln in source.splitlines()]
+    assert not any("searchsorted(" in ln for ln in code)
+
+
+@pytest.mark.parametrize("branch", [False, True], ids=["plain", "branch"])
+@pytest.mark.parametrize("live_rows,groups", [
+    (0, 1), (1, 1), (300, 7), (300, 300), (512, 40), (512, 512), (511, 511),
+], ids=["none-live", "one-row", "few-groups", "all-distinct", "all-live",
+        "all-live-all-distinct", "one-dead-all-distinct"])
+def test_an_aggregates_bounds_equal_the_searched_ones(live_rows, groups,
+                                                      branch):
+    """`_group_segments` (ops/agg/exec.py) says its segments' bounds from
+    the boundaries it found — group g from its first row to the next
+    group's, the last to `n_live`, the padding's segment `capacity - 1`
+    over the dead rows — and they are what a search of its ids finds, with
+    no scatter added outside a branch and none beyond the boundary rows'
+    inside one."""
+    from auron_tpu.columnar.batch import DeviceColumn
+    from auron_tpu.ir.schema import DataType
+    from auron_tpu.ops.agg.exec import _group_segments
+    cap = 512
+    rng = np.random.default_rng(live_rows + groups)
+    key = rng.permutation(np.arange(cap) % groups).astype(np.int64)
+    live = rng.permutation(np.arange(cap) < live_rows)
+    if groups == live_rows:
+        key = np.arange(cap, dtype=np.int64)
+
+    def run(key, live):
+        col = DeviceColumn(DataType.int64(), key, jnp.ones(cap, bool))
+        perm, seg, n_groups, _keys = _group_segments(
+            [col], live, ((True, True),))
+        return perm, seg, n_groups
+
+    marked = segments.inside_branch() if branch else \
+        contextlib.nullcontext()
+    with segments.counting() as counted, marked:
+        jaxpr = jax.make_jaxpr(run)(key, live)
+        # (jax keeps a function's trace: this one runs the jaxpr's)
+        _perm, seg, n_groups = jax.jit(run)(key, live)
+    assert counted.bounds == 1 and counted.reductions == 0
+    assert str(jaxpr).count("= scatter[") == (1 if branch else 0)
+    assert "while" not in str(jaxpr).replace("while_loop", "")
+    ids = np.asarray(seg.ids)
+    assert (np.diff(ids) >= 0).all()
+    starts, ends, nonempty = _searched_bounds(ids, cap)
+    assert int(n_groups) == len(set(key[live].tolist()))
+    np.testing.assert_array_equal(np.asarray(seg.nonempty), nonempty)
+    np.testing.assert_array_equal(np.asarray(seg.starts)[nonempty],
+                                  starts[nonempty])
+    np.testing.assert_array_equal(np.asarray(seg.ends)[nonempty],
+                                  ends[nonempty])

@@ -176,7 +176,9 @@ def test_a_leaf_over_the_budget_stays_beside_its_plans_leaves(tmp_path):
             "source_over_budget_bytes":
                 arrow_bytes - MB + device_bytes - MB,
             "join_probes": 2, "join_probes_direct": 2,
-            "agg_inputs": 0, "agg_inputs_compact": 0}
+            "agg_inputs": 0, "agg_inputs_compact": 0,
+            # the plan holds no aggregate
+            "segment_bounds": 0, "segment_reductions": 0}
         assert S.stage_totals(first)["scan_cached"] == 0
         assert S.stage_totals(first)["shard_put_bytes"] == device_bytes
         assert S.stage_totals(first)["scan_rows"] == FACT_ROWS + 2 * KEYS
