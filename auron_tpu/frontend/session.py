@@ -251,6 +251,9 @@ class AuronSession:
             totals = metric_totals(trees)
             if res is not None:
                 totals.update(res.stage_totals())
+            # every blocking device->host fetch of the execute: the stage
+            # driver's (`spmd.wait`, `spmd.fetch`) and the serial engine's
+            totals["host_syncs"] = st.get("host_syncs", 0)
             # the minimal lifecycle timeline of a direct execute (the
             # serving schedulers patch/record the full queued ->
             # admitted -> ... machine over this)
@@ -330,10 +333,8 @@ class AuronSession:
                 sources = {rid: self._source_table(src, ctx)
                            for rid, src in ctx.sources.items()}
                 stage_stats: Dict[str, object] = {}
-                with tracing.span("spmd.execute", cat="spmd"):
-                    table = execute_plan_spmd(converted, ctx, mesh,
-                                              sources, axis=mesh_axis,
-                                              stats=stage_stats)
+                table = execute_plan_spmd(converted, ctx, mesh, sources,
+                                          axis=mesh_axis, stats=stage_stats)
                 res = SessionResult(table=table, converted=converted,
                                     tags=tags, ctx=ctx, spmd=True,
                                     stage_stats=stage_stats)
@@ -525,13 +526,9 @@ class AuronSession:
                     ctx.exchanges[rid], ctx)
         stats = {rid: p["stats"] for rid, p in pending.items()
                  if p.get("stats") is not None}
-        with tracing.span("aqe.replan", cat="plan",
-                          exchanges=len(stats)):
-            plan, decisions, actions = adaptive.replan(plan, ctx, stats)
+        plan, decisions, actions = adaptive.replan(plan, ctx, stats)
         for d in decisions:
-            doc = d.to_dict()
-            self._aqe_decisions.append(doc)
-            tracing.event("aqe.decision", cat="plan", **doc)
+            self._aqe_decisions.append(d.to_dict())
             log.info("aqe: %s %s: %s", d.kind, d.exchange, d.reason)
         for rid, pend in pending.items():
             self._adaptive_fetch(ctx.exchanges[rid], ctx, resources,
@@ -545,10 +542,7 @@ class AuronSession:
             est = adaptive.stage_mem_estimate(qid, stats.values())
             age = _time.time() - self._wall_start \
                 if self._wall_start else 0.0
-            new_res = adaptive.stage_boundary_reforecast(qid, est, age)
-            if new_res is not None:
-                tracing.event("aqe.reforecast", cat="plan",
-                              reservation=new_res, estimate=est)
+            adaptive.stage_boundary_reforecast(qid, est, age)
         return resources, plan
 
     def _adaptive_map_side(self, job: ShuffleJob,
@@ -630,7 +624,7 @@ class AuronSession:
         event + one log line) for the durable->local fallback.  With a
         SHARDED side-car client the stickiness is per shard: only the
         shuffle ids owned by the dead endpoint fall back to local."""
-        from auron_tpu.runtime import counters, tracing
+        from auron_tpu.runtime import counters
         from auron_tpu.shuffle_rss.shard_map import (
             ShardedDurableShuffleClient,
         )
@@ -643,8 +637,6 @@ class AuronSession:
             self._rss_degraded = True
             scope = "this query"
         counters.bump("rss_degrades")
-        tracing.event("rss.degrade", cat="shuffle", rid=rid,
-                      error=str(err))
         log.warning(
             "durable shuffle degraded to executor-local for %s "
             "(rid %s): %s", scope, rid, err)
@@ -712,18 +704,15 @@ class AuronSession:
         import io
 
         from auron_tpu.columnar import serde as batch_serde
-        from auron_tpu.runtime import tracing
-        with tracing.span("broadcast.collect", cat="exchange",
-                          rid=job.rid):
-            table = self._run_converted(job.child, ctx)
-            sink = io.BytesIO()
-            # broadcast bytes never leave the process: the local
-            # exchange codec policy applies (none by default)
-            codec = batch_serde.exchange_codec("local")
-            for rb in table.to_batches():
-                if rb.num_rows:
-                    batch_serde.write_one_batch(rb, sink, codec=codec)
-            resources.put(job.rid, sink.getvalue())
+        table = self._run_converted(job.child, ctx)
+        sink = io.BytesIO()
+        # broadcast bytes never leave the process: the local
+        # exchange codec policy applies (none by default)
+        codec = batch_serde.exchange_codec("local")
+        for rb in table.to_batches():
+            if rb.num_rows:
+                batch_serde.write_one_batch(rb, sink, codec=codec)
+        resources.put(job.rid, sink.getvalue())
 
     def _materialize_exchange(self, job: ShuffleJob, ctx: ConvertContext,
                               resources: ResourceRegistry) -> None:
@@ -941,7 +930,7 @@ class AuronSession:
                                n_reduce: int) -> List[List[bytes]]:
         """Fetch half of the commit protocol: integrity-checked fetch
         with ONE targeted-regeneration round for damaged map outputs."""
-        from auron_tpu.runtime import counters, tracing
+        from auron_tpu.runtime import counters
         svc = self.shuffle_service
         map_parts = ctx.parts(job.child)
         blocks, bad = self._durable_fetch(sid, n_reduce, man)
@@ -949,8 +938,6 @@ class AuronSession:
             # missing/corrupt committed block: deterministic, so
             # regenerate those map outputs and fetch once more
             counters.bump("rss_fetch_regens")
-            tracing.event("rss.fetch.regen", cat="shuffle",
-                          rid=job.rid, sid=sid, maps=sorted(bad))
             log.warning(
                 "durable shuffle %s: fetch failed integrity for "
                 "map output(s) %s; regenerating via targeted "

@@ -159,6 +159,41 @@ def compact_indices(mask, capacity: int):
     return idx, count
 
 
+def cut_batches(batches: Iterator[Batch], offset: int,
+                limit: Optional[int]) -> Iterator[Batch]:
+    """`batches` without their first `offset` rows, cut after `limit` more
+    (None: no cut): LimitExec's body and a sort's fetch.  Each batch's cut
+    is a leaf span, `limit.cut`, closed before the batch is handed on;
+    with neither offset nor limit the batches pass untouched and
+    uncounted."""
+    from auron_tpu.runtime import tracing
+    if not offset and limit is None:
+        yield from batches
+        return
+    to_skip = offset
+    remaining = limit if limit is not None else 1 << 62
+    for b in batches:
+        if remaining <= 0:
+            return
+        with tracing.span("limit.cut", cat="op") as sp:
+            rows_in = b.num_rows
+            if to_skip >= rows_in:
+                to_skip -= rows_in
+                b = None
+            else:
+                if to_skip > 0:
+                    idx = jnp.arange(b.capacity, dtype=jnp.int32) + to_skip
+                    b = b.gather(idx, rows_in - to_skip)
+                    to_skip = 0
+                if b.num_rows > remaining:
+                    b = b.head(remaining)
+                remaining -= b.num_rows
+            sp.set_args(rows_in=rows_in,
+                        rows_out=0 if b is None else b.num_rows)
+        if b is not None:
+            yield b
+
+
 def batch_size() -> int:
     return int(conf.get("auron.batch.size"))
 

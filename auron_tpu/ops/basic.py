@@ -19,9 +19,9 @@ from auron_tpu.exprs.compiler import build_evaluator, build_predicate
 from auron_tpu.ir.schema import Field, Schema
 from auron_tpu.exprs.typing import infer_type
 from auron_tpu.ops.base import (
-    Operator, TaskContext, batch_size, compact_indices,
+    Operator, TaskContext, batch_size, compact_indices, cut_batches,
 )
-from auron_tpu.runtime import jitcheck
+from auron_tpu.runtime import jitcheck, tracing
 
 # ONE compact-gather program serves every filter's column structure
 # (jax.jit's per-aval cache) — distinct signatures track workload
@@ -43,11 +43,19 @@ class ProjectExec(Operator):
 
     def execute(self, ctx: TaskContext) -> Iterator[Batch]:
         for b in self.child_stream(ctx):
-            cols = self._eval(b, partition_id=ctx.partition_id,
-                              row_base=self._row_base)
+            with tracing.span("project.eval", cat="op",
+                              exprs=len(self.exprs)) as sp:
+                cols = self._eval(b, partition_id=ctx.partition_id,
+                                  row_base=self._row_base)
+                out = b.with_columns(self.schema, cols)
+                if sp.armed:
+                    # a lazy batch's count is on the device: not fetched
+                    # for a trace's sake
+                    sp.set_args(
+                        rows=b.num_rows if b.num_rows_known else -1)
             if self._eval.uses_row_base:
                 self._row_base += b.num_rows
-            yield b.with_columns(self.schema, cols)
+            yield out
 
 
 class FilterExec(Operator):
@@ -130,22 +138,7 @@ class LimitExec(Operator):
         self.offset = offset
 
     def execute(self, ctx: TaskContext) -> Iterator[Batch]:
-        to_skip = self.offset
-        remaining = self.limit
-        for b in self.child_stream(ctx):
-            if remaining <= 0:
-                return
-            if to_skip >= b.num_rows:
-                to_skip -= b.num_rows
-                continue
-            if to_skip > 0:
-                idx = jnp.arange(b.capacity, dtype=jnp.int32) + to_skip
-                b = b.gather(idx, b.num_rows - to_skip)
-                to_skip = 0
-            if b.num_rows > remaining:
-                b = b.head(remaining)
-            remaining -= b.num_rows
-            yield b
+        return cut_batches(self.child_stream(ctx), self.offset, self.limit)
 
 
 class UnionExec(Operator):

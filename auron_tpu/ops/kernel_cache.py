@@ -23,7 +23,7 @@ from typing import Any, Callable, Dict, Hashable, Tuple
 
 import jax
 
-from auron_tpu.runtime import jitcheck
+from auron_tpu.runtime import jitcheck, tracing
 
 _CACHE: Dict[Hashable, Any] = {}
 _STATS = {"hits": 0, "misses": 0}
@@ -60,8 +60,7 @@ def cached_jit(key: Hashable, builder: Callable[[], Callable],
         # trace (jax compiles lazily at first call, so this is an
         # instant, not a duration — fragment.compile/spmd.compile carry
         # the durations)
-        from auron_tpu.runtime.tracing import event
-        event("kernel.build", cat="compile")
+        tracing.event("kernel.build", cat="compile")
     else:
         _STATS["hits"] += 1
     return fn
@@ -70,8 +69,10 @@ def cached_jit(key: Hashable, builder: Callable[[], Callable],
 def host_sync(x: Any) -> Any:
     """The sanctioned device->host fetch (see module docstring).  Returns
     numpy/python values; accepts any pytree (fetched as one unit so a
-    packed scalar pair costs one round trip)."""
+    packed scalar pair costs one round trip).  Each call is a blocking
+    round trip, counted for the ambient query (`host_syncs`)."""
     jitcheck.note_sync("host_sync")
+    tracing.stats_bump("host_syncs")
     with jax.transfer_guard("allow"):
         return jax.device_get(x)
 

@@ -20,6 +20,7 @@ from auron_tpu.columnar.batch import Batch
 from auron_tpu.config import conf
 from auron_tpu.ir.schema import Schema
 from auron_tpu.ops.base import Operator, TaskContext
+from auron_tpu.runtime import tracing
 
 
 class IpcReaderExec(Operator):
@@ -160,17 +161,24 @@ class FFIReaderExec(Operator):
         src = ctx.resources.get(self.resource_id)
         budget_mb = int(conf.get("auron.ffi.ingest.cache.mb"))
         for rb in _iter_arrow(src):
-            if budget_mb <= 0 or not isinstance(rb, pa.RecordBatch):
-                yield Batch.from_arrow(rb, schema=self.schema)
-                continue
-            hit = _ingest_cache_get(rb)
-            if hit is not None and hit.schema == self.schema:
-                self.metrics.add("ffi_ingest_cache_hits", 1)
-                yield hit
-                continue
-            b = Batch.from_arrow(rb, schema=self.schema)
-            _ingest_cache_put(rb, b, budget_mb)
+            # host -> device, or the ingest cache's hit in its place
+            with tracing.span("ffi.to_device", cat="scan") as sp:
+                b, cached = self._to_device(rb, budget_mb)
+                if sp.armed:
+                    sp.set_args(rows=rb.num_rows, bytes=rb.nbytes,
+                                cached=int(cached))
             yield b
+
+    def _to_device(self, rb, budget_mb: int) -> "tuple[Batch, bool]":
+        if budget_mb <= 0 or not isinstance(rb, pa.RecordBatch):
+            return Batch.from_arrow(rb, schema=self.schema), False
+        hit = _ingest_cache_get(rb)
+        if hit is not None and hit.schema == self.schema:
+            self.metrics.add("ffi_ingest_cache_hits", 1)
+            return hit, True
+        b = Batch.from_arrow(rb, schema=self.schema)
+        _ingest_cache_put(rb, b, budget_mb)
+        return b, False
 
 
 # RecordBatch identity (id()) -> (weakref to the source, decoded Batch,

@@ -336,25 +336,17 @@ class InProcessShuffleService:
                 from auron_tpu.runtime.retry import (
                     RetryPolicy, call_with_retry,
                 )
-                from auron_tpu.runtime.tracing import span
-                with span("shuffle.commit", cat="shuffle"):
-                    call_with_retry(self._commit,
-                                    policy=RetryPolicy.from_conf(),
-                                    label="in-process shuffle commit")
+                call_with_retry(self._commit,
+                                policy=RetryPolicy.from_conf(),
+                                label="in-process shuffle commit")
         return _W()
 
     def reduce_blocks(self, shuffle_id: str, reduce_pid: int) -> List[bytes]:
         from auron_tpu.faults import fault_point
-        from auron_tpu.runtime.tracing import span
-        with span("shuffle.fetch.part", cat="shuffle",
-                  partition=reduce_pid) as sp:
-            fault_point("shuffle.fetch")
-            with self._lock:
-                entries = list(self._blocks.get((shuffle_id, reduce_pid),
-                                                []))
-            out = [d for _mid, d in sorted(entries, key=lambda e: e[0])]
-            sp.set_args(nbytes=sum(len(d) for d in out))
-            return out
+        fault_point("shuffle.fetch")
+        with self._lock:
+            entries = list(self._blocks.get((shuffle_id, reduce_pid), []))
+        return [d for _mid, d in sorted(entries, key=lambda e: e[0])]
 
     def clear(self, shuffle_id: str) -> None:
         with self._lock:

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 from typing import Iterator, List, Optional, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
@@ -22,10 +21,13 @@ from auron_tpu.exprs.compiler import build_evaluator
 from auron_tpu.ir.expr import SortExpr
 from auron_tpu.ir.schema import Schema
 from auron_tpu.memmgr import MemConsumer, SpillManager
-from auron_tpu.ops.base import Operator, TaskContext, batch_size
+from auron_tpu.ops.base import (
+    Operator, TaskContext, batch_size, cut_batches,
+)
 from auron_tpu.ops.sort_keys import (
     encode_sort_keys, lexsort_indices,
 )
+from auron_tpu.runtime import tracing
 
 NUM_MAX_MERGING_BATCHES = 16  # mirror of sort_exec.rs multi-level merge cap
 
@@ -64,15 +66,28 @@ class SortExec(Operator, MemConsumer):
     # -- sorting ------------------------------------------------------------
 
     def _sort_batch(self, b: Batch) -> Batch:
-        key_cols = self._key_eval(b)
-        if any(isinstance(c, HostColumn) for c in key_cols):
-            out = self._sort_batch_host(b)
+        # leaf spans around this operator's own steps on a batch it holds
+        # (each times the host: the kernels are enqueued, nothing blocks)
+        rows = b.num_rows
+        with tracing.span("sort.keys", cat="op", rows=rows,
+                          key_columns=len(self.sort_exprs)):
+            key_cols = self._key_eval(b)
+            host = any(isinstance(c, HostColumn) for c in key_cols)
+            words = None if host else \
+                encode_sort_keys(key_cols, self._orders)
+        if host:
+            with tracing.span("sort.order", cat="op", rows=rows,
+                              host=True, blocked=True):
+                out = self._sort_batch_host(b)
         else:
-            words = encode_sort_keys(key_cols, self._orders)
-            perm = lexsort_indices(words, b.num_rows, b.capacity)
-            out = b.gather(perm, b.num_rows)
+            with tracing.span("sort.order", cat="op", rows=rows):
+                perm = lexsort_indices(words, rows, b.capacity)
+            with tracing.span("sort.take", cat="op", rows=rows):
+                out = b.gather(perm, rows)
         if self.fetch_limit is not None:
-            out = out.head(self.fetch_offset + self.fetch_limit)
+            with tracing.span("sort.cut", cat="op", rows_in=rows) as sp:
+                out = out.head(self.fetch_offset + self.fetch_limit)
+                sp.set_args(rows_out=out.num_rows)
         return out
 
     def _sort_batch_host(self, b: Batch) -> Batch:
@@ -92,9 +107,15 @@ class SortExec(Operator, MemConsumer):
         """Sort all staged batches into one run (list of output batches)."""
         if not self._staged:
             return []
-        merged = concat_batches(self.schema, self._staged)
-        out = self._sort_batch(merged)
-        return _rechunk(out, batch_size())
+        with tracing.span("sort.run", cat="op", keys=len(self.sort_exprs),
+                          batches_in=len(self._staged)) as run:
+            with tracing.span("sort.concat", cat="op") as sp:
+                merged = concat_batches(self.schema, self._staged)
+                sp.set_args(rows=merged.num_rows)
+            run.set_args(rows=merged.num_rows, capacity=merged.capacity)
+            out = self._sort_batch(merged)
+            with tracing.span("sort.rechunk", cat="op", rows=out.num_rows):
+                return _rechunk(out, batch_size())
 
     # -- execution ----------------------------------------------------------
 
@@ -111,13 +132,13 @@ class SortExec(Operator, MemConsumer):
                     out = self._sort_staged()
                     self._staged = []
                     self.update_mem_used(0)
-                    yield from _apply_offset(iter(out), self.fetch_offset,
-                                             self.fetch_limit)
+                    yield from cut_batches(iter(out), self.fetch_offset,
+                                           self.fetch_limit)
                     return
                 # final in-memory run joins the spilled runs
                 if self._staged:
                     self.spill()
-                yield from _apply_offset(
+                yield from cut_batches(
                     self._merge_spills(), self.fetch_offset,
                     self.fetch_limit)
         finally:
@@ -137,30 +158,6 @@ def _rechunk(b: Batch, target: int) -> List[Batch]:
     for off in range(0, b.num_rows, target):
         out.append(Batch.from_arrow(arrow.slice(off, target)))
     return out
-
-
-def _apply_offset(batches: Iterator[Batch], offset: int,
-                  limit: Optional[int]) -> Iterator[Batch]:
-    if not offset and limit is None:
-        yield from batches
-        return
-    from auron_tpu.ops.basic import LimitExec  # reuse its streaming logic
-    to_skip = offset
-    remaining = limit if limit is not None else 1 << 62
-    for b in batches:
-        if remaining <= 0:
-            return
-        if to_skip >= b.num_rows:
-            to_skip -= b.num_rows
-            continue
-        if to_skip > 0:
-            idx = jnp.arange(b.capacity, dtype=jnp.int32) + to_skip
-            b = b.gather(idx, b.num_rows - to_skip)
-            to_skip = 0
-        if b.num_rows > remaining:
-            b = b.head(remaining)
-        remaining -= b.num_rows
-        yield b
 
 
 # ---------------------------------------------------------------------------

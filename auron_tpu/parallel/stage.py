@@ -2389,13 +2389,12 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
          segment_box) = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
-    # compiles the whole stage program, so the span is the compile span
-    # (first launch included); cache hits record a pure run span (both
-    # are children of the enclosing spmd.launch)
+    # compiles the whole stage program, so the span is the compile span;
+    # cache hits record a pure run span (both are children of the
+    # enclosing spmd.launch)
     with tracing.span(
             "spmd.compile" if cached is None else "spmd.run",
-            cat="spmd", devices=n_dev,
-            first_launch_included=cached is None):
+            cat="spmd", devices=n_dev):
         (out_cols, out_live, counts, guards, retry_guards, shrink_guards,
          join_guards, probe_direct, crossed, agg_compact) = \
             shard(host_inputs)
@@ -2456,15 +2455,20 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             out_live_np, out_cols_np = host_sync((out_live, out_cols))
             sp.set_args(**_note_gather(counts_np, out_live_np,
                                        out_cols_np))
-        live_np = np.asarray(out_live_np)
-        arrays = []
-        for f, c in zip(out_schema, out_cols_np):
+        # the fetched slots' live rows as an Arrow table, on the host
+        with tracing.span("spmd.to_arrow", cat="spmd") as sp:
             from auron_tpu.columnar.arrow_interop import column_to_arrow
+            live_np = np.asarray(out_live_np)
             total = live_np.shape[0]
-            arr = column_to_arrow(f.dtype, c, total)
-            arrays.append(arr.filter(pa.array(live_np)))
-        table = pa.Table.from_arrays(
-            arrays, schema=to_arrow_schema(out_schema))
+            arrays = []
+            for f, c in zip(out_schema, out_cols_np):
+                arr = column_to_arrow(f.dtype, c, total)
+                arrays.append(arr.filter(pa.array(live_np)))
+            table = pa.Table.from_arrays(
+                arrays, schema=to_arrow_schema(out_schema))
+            if sp.armed:
+                sp.set_args(rows=table.num_rows, slots=total,
+                            columns=len(arrays), bytes=table.nbytes)
 
     # 4. replay the peeled tail through the serial engine
     if tail:

@@ -41,7 +41,7 @@ the `adaptive` contract pass in analysis/adaptive.py) before execution;
 a rewrite that fails verification is DROPPED with a structured decision
 diagnostic, never executed.  Decisions land on `SessionResult.
 aqe_decisions`, the query history record (`/queries/<id>`), EXPLAIN
-ANALYZE, the `aqe.replan` trace span and the
+ANALYZE and the
 `auron_adaptive_{broadcast,coalesce,skew_split}_total` counters.
 
 The unified `CostModel` holds LIVE per-signature execution history

@@ -95,6 +95,10 @@ def execute_plan(plan: P.PlanNode, partition_id: int = 0,
     return execute_task(td, resources, arrow)
 
 
+def _count_operators(root: Operator) -> int:
+    return 1 + sum(_count_operators(c) for c in root.children)
+
+
 def task_attempt_counts() -> tuple:
     """(started, completed) task attempts this process — the chaos sweep
     bounds started_with_faults <= factor * started_fault_free.  Counters
@@ -144,7 +148,10 @@ def execute_task(task: P.TaskDefinition,
             # runtime construction sits inside the task scope so
             # plan-verifier diagnostics (create_verified_plan) and
             # planner errors carry the [stage N part M] prefix
-            rt = NativeExecutionRuntime(task, resources)
+            with tracing.span("task.plan", cat="task") as sp:
+                rt = NativeExecutionRuntime(task, resources)
+                if sp.armed:
+                    sp.set_args(operators=_count_operators(rt.root))
             rt_box[:] = [rt]
             # the per-batch pull loop is THE hot path: every implicit
             # device->host transfer in it must route through host_sync
@@ -157,8 +164,12 @@ def execute_task(task: P.TaskDefinition,
                 for rb in rt.batches(arrow):
                     if isinstance(rb, Batch):
                         from_device += 1
-                        with tracing.span("task.to_host", cat="task"):
+                        with tracing.span("task.to_host", cat="task",
+                                          blocked=True) as sp:
                             rb = rb.to_arrow()
+                            if sp.armed:
+                                sp.set_args(rows=rb.num_rows,
+                                            bytes=rb.nbytes)
                     if rb.num_rows > 0:
                         out.append(rb)
                 return out, from_device
@@ -178,12 +189,18 @@ def execute_task(task: P.TaskDefinition,
     try:
         with tracing.span("task.execute", cat="task",
                           stage=task.stage_id,
-                          partition=task.partition_id):
+                          partition=task.partition_id) as sp:
+            stats = tracing.current_stats() if sp.armed else None
+            syncs0 = stats.get("host_syncs") if stats is not None else 0
             out, from_device = retry.call_with_retry(
                 _attempt, policy=retry.RetryPolicy.from_conf(),
                 label=f"task stage={task.stage_id} "
                       f"part={task.partition_id}",
                 classify=_device_retryable, on_retry=_count_retry)
+            if stats is not None:
+                # the query's blocking fetches while this task ran: the
+                # task's own where no other task of the query ran beside it
+                sp.set_args(syncs=stats.get("host_syncs") - syncs0)
     except BaseException:
         counters.bump("tasks_failed")
         raise

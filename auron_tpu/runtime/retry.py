@@ -240,9 +240,5 @@ def call_with_retry(fn: Callable[[], Any],
                 # outer sites don't retry the retries
                 e.auron_retry_exhausted = True  # type: ignore[attr-defined]
                 _bump("exhausted")
-                from auron_tpu.runtime import tracing
-                tracing.event("retry.exhausted", cat="retry",
-                              label=label or "call", attempts=attempt,
-                              error=f"{type(e).__name__}: {e}")
             raise
     raise AssertionError("unreachable")   # pragma: no cover

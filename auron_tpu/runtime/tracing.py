@@ -6,7 +6,7 @@ LIFECYCLE — where wall time went between "the driver saw a plan" and
 "the last batch crossed the FFI".  This module is that record: named
 spans for plan conversion, analyzer verify, fusion rewrite, SPMD stage
 compile/launch, per-(stage, partition) task execution, shuffle
-push/fetch, spill write/read, engine-service calls and retry/fallback
+push/fetch, spill write/read and retry/fallback
 attempts, exportable as Chrome-trace/Perfetto JSON (load in
 chrome://tracing or ui.perfetto.dev).
 
@@ -330,8 +330,11 @@ class QueryStats:
     counters keep serving `/metrics` totals unchanged."""
 
     __slots__ = ("_lock", "_counts")
+    # `host_syncs`: blocking device->host fetches (ops/kernel_cache.py::
+    # host_sync, the one sanctioned fetch) the query made, the stage
+    # driver's and the serial engine's alike
     KEYS = ("attempts", "retries", "fallbacks", "mem_spills",
-            "mem_spill_bytes")
+            "mem_spill_bytes", "host_syncs")
 
     def __init__(self):
         self._lock = lockcheck.Lock("trace.stats")
@@ -340,6 +343,10 @@ class QueryStats:
     def bump(self, key: str, delta: int = 1) -> None:
         with self._lock:
             self._counts[key] = self._counts.get(key, 0) + int(delta)
+
+    def get(self, key: str) -> int:
+        with self._lock:
+            return self._counts.get(key, 0)
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:
@@ -550,29 +557,49 @@ def _span_children(spans: List[Dict]) -> Dict[int, List[int]]:
     return children
 
 
+def _self_times(spans: List[Dict]) -> List[float]:
+    """Each complete event's duration less its children's on its own
+    thread, children by the recorded `parent` (an event without an id is
+    all its own).  A child on another thread (a scan task under
+    `spmd.ingest`) ran beside its parent, which was waiting: that wait is
+    the parent's own."""
+    own = [float(ev.get("dur", 0)) for ev in spans]
+    by_id = {(ev.get("pid"), (ev.get("args") or {}).get("id")): i
+             for i, ev in enumerate(spans)}
+    for ev in spans:
+        parent = (ev.get("args") or {}).get("parent")
+        i = by_id.get((ev.get("pid"), parent)) if parent else None
+        if i is not None and spans[i].get("tid") == ev.get("tid"):
+            own[i] -= ev.get("dur", 0)
+    return [max(0.0, t) for t in own]
+
+
 def summarize_chrome_trace(doc: Dict, top: int = 10) -> str:
-    """Human summary: per-name aggregates (count/total/max) sorted by
-    total time, plus the critical path — from the longest span, the
-    chain of largest enclosed spans."""
+    """Human summary: per-name aggregates (count/total/self/max) sorted by
+    total time — self being a span's duration less its children's, the
+    decomposition the per-layer metrics give — plus the critical path:
+    from the longest span, the chain of largest enclosed spans."""
     spans = _complete_events(doc)
     if not spans:
         return "no complete spans in trace"
     agg: Dict[str, List[float]] = {}
-    for ev in spans:
-        a = agg.setdefault(ev["name"], [0, 0.0, 0.0])
+    for ev, own in zip(spans, _self_times(spans)):
+        a = agg.setdefault(ev["name"], [0, 0.0, 0.0, 0.0])
         a[0] += 1
         a[1] += ev.get("dur", 0)
         a[2] = max(a[2], ev.get("dur", 0))
+        a[3] += own
     total_span = max(spans, key=lambda e: e.get("dur", 0))
     lines = [f"{len(spans)} spans, "
              f"{len(agg)} distinct names, "
              f"longest: {total_span['name']} "
              f"{total_span.get('dur', 0) / 1000.0:.3f}ms"]
-    lines.append(f"{'name':32} {'count':>6} {'total_ms':>10} {'max_ms':>10}")
+    lines.append(f"{'name':32} {'count':>6} {'total_ms':>10} "
+                 f"{'self_ms':>10} {'max_ms':>10}")
     by_total = sorted(agg.items(), key=lambda kv: -kv[1][1])[:top]
-    for name, (n, tot, mx) in by_total:
+    for name, (n, tot, mx, own) in by_total:
         lines.append(f"{name[:32]:32} {n:6d} {tot / 1000.0:10.3f} "
-                     f"{mx / 1000.0:10.3f}")
+                     f"{own / 1000.0:10.3f} {mx / 1000.0:10.3f}")
     # critical path: descend from the longest span into the largest
     # enclosed span at each level
     children = _span_children(spans)

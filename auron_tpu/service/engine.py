@@ -381,13 +381,9 @@ class EngineClient:
                 raise
             return resp
 
-        from auron_tpu.runtime.tracing import span
-        with span("service.call", cat="service",
-                  cmd=str(header.get("cmd"))):
-            resp = call_with_retry(
-                _once, policy=RetryPolicy.from_conf(),
-                label=f"engine {header.get('cmd')} to "
-                      f"{self.host}:{self.port}")
+        resp = call_with_retry(
+            _once, policy=RetryPolicy.from_conf(),
+            label=f"engine {header.get('cmd')} to {self.host}:{self.port}")
         wirecheck.check_response("engine", str(header.get("cmd")), resp)
         if not resp.get("ok"):
             raise RemoteExecutionError(resp.get("error", "request failed"))
@@ -431,15 +427,12 @@ class EngineClient:
         rng = random.Random(policy.seed)
         attempts = max(1, policy.max_attempts)
         attempt = 1
-        from auron_tpu.runtime.tracing import span
         while True:
             yielded = False
             try:
-                with span("service.execute.send", cat="service",
-                          attempt=attempt, nbytes=len(data)):
-                    fault_point("service.call")
-                    s = self._ensure_sock()
-                    send_msg(s, exec_header, data)
+                fault_point("service.call")
+                s = self._ensure_sock()
+                send_msg(s, exec_header, data)
                 while True:
                     header, payload = recv_msg(s)
                     wirecheck.check_stream_frame("engine", "execute",

@@ -9,7 +9,8 @@
 writes the Chrome-trace JSON (load in chrome://tracing or
 ui.perfetto.dev); `validate` re-checks the schema invariants the
 Perfetto importer relies on (exit 2 on any error); `summary` prints
-per-span aggregates and the critical path.  This is the command-line
+per-span aggregates (total and self time: a span's duration less its
+children's) and the critical path.  This is the command-line
 face of runtime/tracing.py, wired into CI by tools/trace_check.sh.
 
 `device` is the stage path's per-operator view: it reads the

@@ -137,6 +137,29 @@ def test_summarize_critical_path():
     assert "root" in text and "child" in text
 
 
+def test_summarize_prints_self_time_by_parent():
+    """Self time is a span's duration less its children's, by the
+    recorded `parent`: the decomposition the per-layer metrics give."""
+    def ev(name, ts, dur, id_, parent, tid=1):
+        return {"name": name, "ph": "X", "ts": ts, "dur": dur, "pid": 1,
+                "tid": tid, "args": {"id": id_, "parent": parent}}
+    doc = {"traceEvents": [
+        ev("task.execute", 0, 10_000, 1, 0),
+        ev("sort.run", 1_000, 6_000, 2, 1),
+        ev("sort.keys", 1_500, 4_000, 3, 2),
+        ev("project.eval", 8_000, 1_000, 4, 1),
+        # a child on another thread ran beside its parent
+        ev("scan.decode", 2_000, 9_000, 5, 1, tid=2),
+        {"name": "foreign", "ph": "X", "ts": 0, "dur": 500, "pid": 1,
+         "tid": 1}]}
+    lines = tracing.summarize_chrome_trace(doc).splitlines()
+    assert lines[1].split() == ["name", "count", "total_ms", "self_ms",
+                                "max_ms"]
+    own = {ln.split()[0]: float(ln.split()[3]) for ln in lines[2:8]}
+    assert own == {"task.execute": 3.0, "sort.run": 2.0, "sort.keys": 4.0,
+                   "project.eval": 1.0, "scan.decode": 9.0, "foreign": 0.5}
+
+
 # ---------------------------------------------------------------------------
 # correlation key: query id through logging + task pool
 # ---------------------------------------------------------------------------

@@ -111,6 +111,31 @@ def test_sort_fetch_limit():
     assert [r["k"] for r in out] == [0] * 7
 
 
+@pytest.mark.parametrize("limit,offset", [(7, 0), (7, 3), (60, 30),
+                                          (10, 120), (500, 140)])
+@pytest.mark.parametrize("through", ["limit", "sort-fetch"])
+def test_limit_and_sort_fetch_share_one_cut(through, limit, offset):
+    """LimitExec and a sort's fetch are one body (`cut_batches`): the
+    same rows from either, and one `limit.cut` span a batch handed in,
+    closed before the batch is handed on."""
+    from auron_tpu.runtime import tracing
+    rows = [{"k": i} for i in range(150)]       # three batches of 50
+    if through == "limit":
+        op = LimitExec(scan_of(rows), limit, offset)
+    else:
+        op = SortExec(scan_of(rows), [SortExpr(child=col("k"))],
+                      fetch_limit=limit, fetch_offset=offset)
+    rec = tracing.TraceRecorder("cut", max_events=1000)
+    with tracing.trace_scope(recorder=rec):
+        out = collect(op)
+    assert [r["k"] for r in out] == list(range(150))[offset:offset + limit]
+    cuts = [s for s in rec.snapshot() if s.name == "limit.cut"]
+    assert cuts and sum(s.args["rows_out"] for s in cuts) == len(out)
+    assert all(s.args["rows_out"] <= s.args["rows_in"] for s in cuts)
+    for a, b in zip(cuts, cuts[1:]):
+        assert a.t0_ns + a.dur_ns <= b.t0_ns
+
+
 def test_external_sort_spill_fuzz():
     """Tiny memory budget forces spills; result must equal full sort."""
     from auron_tpu.config import conf

@@ -404,7 +404,10 @@ def test_a_scan_task_is_a_task(tmp_path):
     assert by_id[task.parent].name == "spmd.ingest"
     assert (task.args["stage"], task.args["partition"]) == (0, 0)
     names = [s.name for s in spans if s.parent == task.id]
-    assert "plan.verify" in names and "task.to_host" not in names
+    assert "task.plan" in names and "task.to_host" not in names
+    # the verified plan: under the runtime's construction (PR 37)
+    [verify] = [s for s in spans if s.name == "plan.verify"]
+    assert by_id[verify.parent].name == "task.plan"
     # a pull a batch and the one that finds the end
     assert names.count("scan.decode") == 2
     assert names.count("scan.to_device") == 1
