@@ -693,12 +693,14 @@ SPMD_AGG_CAPACITY_HINT = conf.define(
     "the hint trips a runtime guard and the query climbs a capacity "
     "ladder: 4x the hint per retry up to 16x, then shrink disabled "
     "(the working rung is remembered per program).  It is also the "
-    "compaction target of an agg's INPUT: where the input's capacity is "
-    "larger than this one, the stage program counts the live input rows "
-    "and, per device, brings them to the front of a table of this "
-    "capacity before it aggregates whenever they fit (they do after "
-    "selective joins), so the agg costs what its output costs; otherwise "
-    "it aggregates at the input's capacity and cuts.  0 disables both.",
+    "compaction target of an agg's INPUT: the stage program counts the "
+    "live input rows and, per device, brings them to the front of the "
+    "narrowest table that holds them — of this capacity, or of a fourth "
+    "or a thirty-second of it where that is under 131072 rows (65536 and "
+    "8192 at the default) — before it aggregates (they fit after "
+    "selective joins), so the agg costs what its live rows cost; more "
+    "live rows than this capacity and it aggregates at the input's "
+    "capacity and cuts.  0 disables both.",
 )
 SPMD_JOIN_COMPACT = conf.define(
     "auron.spmd.join.compact.enable", True,

@@ -84,7 +84,8 @@ class SessionResult:
     # what the stage program reported of itself (parallel/stage.py::
     # execute_plan_spmd's `stats`): `join_probes`, the probe each K=1
     # join took, and `agg_inputs`, the input each aggregate that chose
-    # worked on, by operator label; `segments`, the segment bounds the
+    # worked on and the width its body ran at, by operator label;
+    # `segments`, the segment bounds the
     # program's trace derived and the reductions over them; `ingest`, what
     # the scan leaves' tasks read; over more than one device also
     # `exchanges`, `broadcasts` and `sources`, what crossed devices
@@ -130,9 +131,11 @@ class SessionResult:
         them; 0 where a query fits the budgets);
         `join_probes` (K=1 joins run) and `join_probes_direct` (those
         that probed by direct address on every device); `agg_inputs`
-        (aggregates whose input is larger than their output's capacity)
-        and `agg_inputs_compact` (those whose input every device compacted
-        to that capacity first); `segment_bounds` (segment bounds derived
+        (aggregates with a width to choose under their input's: the
+        capacity their output is cut to, or a rung below it),
+        `agg_inputs_compact` (those whose input every device compacted
+        to such a width first) and `agg_inputs_below_cap` (those every
+        device ran under that capacity, at a rung); `segment_bounds` (segment bounds derived
         while the stage program was traced: one an aggregate body) and
         `segment_reductions` (sorted-segment reductions that took them);
         over more than one device also

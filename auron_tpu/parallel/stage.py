@@ -16,11 +16,11 @@ program over a `jax.sharding.Mesh`:
 - broadcast exchange: `lax.all_gather` materializes the build side on
   every device (NativeBroadcastExchangeBase.collectNative analogue);
 - group aggregation: the same sort-based `_group_reduce_body` kernel the
-  serial engine uses, traced inline — on the table it is given, or, where
-  that table is larger than the capacity the output is cut to and its
-  live rows fit that capacity, on those rows compacted to it (`_do_agg`:
-  chosen in the program from the live count, per device, like the probe
-  below);
+  serial engine uses, traced inline — on the table it is given, or on its
+  live rows compacted to the narrowest of a short ladder of widths that
+  holds them: the capacity the output is cut to and two rungs below it
+  (`_do_agg`: chosen in the program from the live count, per device, like
+  the probe below);
 - broadcast/hash/sort-merge join: one lookup contract, (build row, found)
   per probe row, computed one of two ways.  A single integer or date key
   whose live build keys span less than the build side's capacity probes a
@@ -146,6 +146,24 @@ def _compact_front(t: DeviceTable, n_live, new_cap: int) -> DeviceTable:
         return DeviceTable(t.schema, cols, ok)
 
 
+# an aggregate's rungs end under this many rows: every rung is one more
+# body, and a distinct sort takes 34 s and more to compile for a v5e from
+# 2^17 rows on, 7 s at 2^14 (PR 22, tests/test_tpu_compile.py)
+_RUNG_ROWS_END = 1 << 17
+
+
+def _agg_rungs(new_cap: int, capacity: int) -> List[int]:
+    """The widths below `new_cap` an aggregate over a `capacity`-row
+    input may run at, ascending: a fourth and a thirty-second of
+    `new_cap` (65,536 and 8,192 rows at the hint's 262,144; 32,768 alone
+    at its first climb, 1,048,576), each a capacity bucket like every
+    other, and each only where it is narrower than the input and under
+    `_RUNG_ROWS_END`."""
+    end = min(new_cap, capacity, _RUNG_ROWS_END)
+    return sorted({r for r in (bucket_capacity(new_cap // 32),
+                               bucket_capacity(new_cap // 4)) if r < end})
+
+
 def _direct_addressable(pkeys, bkeys) -> bool:
     """The static half of the direct-address probe's test: one key pair,
     both device columns of one integer or date type (not decimal, string
@@ -229,10 +247,10 @@ def explain_stage(plan, conv_ctx,
     """The stage path's EXPLAIN text: the driver-side tail, then every
     operator of the stage program under the label its device time is
     filed under.  `stats` (execute_plan_spmd's) marks each K=1 join with
-    the probe it took, `direct` or `search`, each aggregate whose input
-    is larger than its output's capacity with the input it worked on,
-    `compact` or `full`, the live rows of it and the rung of the capacity
-    ladder its output was cut to (`cap`), each operator whose output holds
+    the probe it took, `direct` or `search`, each aggregate that chose a
+    width with the input it worked on, `compact` or `full`, the width its
+    body ran at (`rows`), the live rows of its input and the rung of the
+    capacity ladder (`cap`), each operator whose output holds
     wide decimals with `dec128` and how many, and each boundary that
     crossed devices with what it moved."""
     stats = stats or {}
@@ -251,7 +269,7 @@ def explain_stage(plan, conv_ctx,
             detail = f" mode={node.exec_mode}"
             if label in aggs:
                 a = aggs[label]
-                detail += (f" input={a['input']}"
+                detail += (f" input={a['input']} rows={a['rows']}"
                            f" live={a['live']} of {a['capacity']}"
                            f" cap={a['cap']}")
         elif isinstance(node, (P.BroadcastJoin, P.HashJoin,
@@ -339,10 +357,11 @@ class _StageTracer:
         # number of devices that took the direct-address probe — a device
         # scalar — or None where the join traced the search alone)
         self.probes: List[Tuple[str, Any]] = []
-        # one entry per aggregate whose input is larger than the capacity
-        # its output is cut to, in trace order: (its label and its input's
-        # slots over all devices, [devices that compacted the input, live
-        # input rows] — a replicated int64 device vector)
+        # one entry per aggregate traced with a choice of width, in trace
+        # order: (its label, its input's slots over all devices, the rung
+        # of the capacity ladder and the widths it chose among; [devices
+        # that compacted the input, live input rows, then the devices at
+        # each of those widths] — a replicated int64 device vector)
         self.agg_inputs: List[Tuple[Dict[str, Any], Any]] = []
         # one entry per exchange or broadcast boundary that crossed
         # devices, in trace order: (what is known of it at trace time,
@@ -684,22 +703,28 @@ class _StageTracer:
     def _do_agg(self, n: P.Agg) -> DeviceTable:
         """One aggregate.  Its output is cut to `new_cap` rows (the
         capacity hint's bucket) wherever its input is larger than that,
-        and there its cost follows the table it is GIVEN, so the program
-        counts the input's live rows and chooses, per device (`lax.cond`,
-        no collective inside a branch):
+        and its cost follows the width of the table it is GIVEN, so the
+        program counts the input's live rows and chooses that width, per
+        device (one `lax.switch`, no collective inside a branch), from a
+        short ladder — the narrowest that holds the live rows:
 
-        - `n_live <= new_cap` — compact: the live rows are brought to the
-          front of a `new_cap`-row table (`_compact_front`) and the body
-          runs at `new_cap` rows.  An aggregate has no more groups than
+        - a rung below `new_cap` (`_agg_rungs`), or `new_cap` itself where
+          the input is larger — compact: the live rows are brought to the
+          front of a table that wide (`_compact_front`), the body runs at
+          that width, and the groups are handed on with dead rows behind
+          them at the width the operator hands on anyway (`new_cap`, or
+          the input's capacity where nothing is cut), so nothing
+          downstream changes shape.  An aggregate has no more groups than
           live rows (a global one over no rows has its one identity row),
-          so this side's output fits and its guard flag is constant False;
-        - otherwise — full: the body at the input's capacity, then
-          `_shrink_front`'s cut to `new_cap` rows and its flag
-          (`n_groups > new_cap`).
+          so these sides' output fits and their guard flag is constant
+          False;
+        - otherwise — full: the body at the input's capacity, then, where
+          that is larger than `new_cap`, `_shrink_front`'s cut to it and
+          its flag (`n_groups > new_cap`).
 
-        Both sides hand back a `new_cap`-row table of one schema; the
-        `psum` that makes the flag the shrink guard sits after the choice.
-        With the shrink off, or an input of no more than `new_cap` rows,
+        Every side hands back a table of one shape and schema; the `psum`
+        that makes the flag the shrink guard sits after the choice.  With
+        the shrink off, or an input no wider than the narrowest rung,
         there is neither cut nor choice: the body at the input's capacity
         and nothing else."""
         from auron_tpu.ops.agg.exec import _group_reduce_body
@@ -768,33 +793,54 @@ class _StageTracer:
 
         new_cap = bucket_capacity(self.agg_cap_hint) \
             if self.agg_cap_hint > 0 else 0
-        if new_cap <= 0 or new_cap >= t.capacity:
-            # the shrink is off, or the input is no larger than what the
-            # output would be cut to: no cut, no guard, no choice
+        cut = 0 < new_cap < t.capacity
+        out_cap = new_cap if cut else t.capacity
+        # the widths a compacted input may take, ascending
+        widths = _agg_rungs(new_cap, t.capacity) + ([new_cap] if cut else [])
+        if not widths:
+            # the shrink is off, or the input is no wider than the
+            # narrowest rung: no cut, no guard, no choice
             return aggregate_over(t)[0]
         n_live = jnp.sum(t.live.astype(jnp.int32))
-        fits = n_live <= new_cap
 
-        def compact_side():
-            out, _n_groups = aggregate_over(
-                _compact_front(t, n_live, new_cap))
-            return out.cols, out.live, jnp.bool_(False)
+        def compact_side(width: int):
+            def side():
+                out, _n_groups = aggregate_over(
+                    _compact_front(t, n_live, width))
+                with jax.named_scope("compact"):
+                    cols, live = jax.tree.map(
+                        lambda x: jnp.pad(x, [(0, out_cap - width)]
+                                          + [(0, 0)] * (x.ndim - 1)),
+                        (out.cols, out.live))
+                return cols, live, jnp.bool_(False)
+            return side
 
         def full_side():
-            out, over = self._shrink_front(*aggregate_over(t), new_cap)
+            out, n_groups = aggregate_over(t)
+            over = jnp.bool_(False)
+            if cut:
+                out, over = self._shrink_front(out, n_groups, new_cap)
             return out.cols, out.live, over
 
+        # the narrowest width that holds the live rows; past them all, full
+        chosen = sum((n_live > w).astype(jnp.int32) for w in widths)
         with inside_branch():
-            cols, live, over = lax.cond(fits, compact_side, full_side)
-        self.shrink_guards.append(
-            lax.psum(over.astype(jnp.int32), self.axis) > 0)
-        # devices on the compact side and the rows they looked at, for the
-        # driver's counter
+            cols, live, over = lax.switch(
+                chosen, [compact_side(w) for w in widths] + [full_side])
+        if cut:
+            self.shrink_guards.append(
+                lax.psum(over.astype(jnp.int32), self.axis) > 0)
+        # devices that compacted their input, the rows they looked at and
+        # the devices at each width, for the driver's counter
+        took = jnp.arange(len(widths) + 1, dtype=jnp.int32) == chosen
         self.agg_inputs.append((
             {"label": self.labels.get(id(n), n.kind),
-             "capacity": t.capacity * self.n_dev, "cap": new_cap},
-            lax.psum(jnp.stack([fits.astype(jnp.int32), n_live])
-                     .astype(jnp.int64), self.axis)))
+             "capacity": t.capacity * self.n_dev, "cap": new_cap,
+             "widths": (*widths, t.capacity)},
+            lax.psum(jnp.concatenate([
+                jnp.stack([(chosen < len(widths)).astype(jnp.int32),
+                           n_live]), took.astype(jnp.int32)])
+                .astype(jnp.int64), self.axis)))
         return DeviceTable(out_schema, cols, live)
 
     def _shrink_front(self, t: DeviceTable, n_live,
@@ -1683,8 +1729,9 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     reported of itself (the attempt that gave the result, or the one whose
     guard tripped last): `join_probes`, {operator label: "direct" |
     "search" | "direct k/n"} for every K=1 join; `agg_inputs`, {operator
-    label: input "compact" | "full" | "compact k/n", live rows, capacity}
-    for every aggregate that chose (`_agg_input_marks`); `segments`, the
+    label: input "compact" | "full" | "compact k/n", the width its body
+    ran at, live rows, capacity} for every aggregate that chose
+    (`_agg_input_marks`); `segments`, the
     segment `bounds` derived in the program's trace and the `reductions`
     that took them; over more than one device also `exchanges` and
     `broadcasts`, {operator label: counts} for every boundary
@@ -1955,29 +2002,43 @@ def probe_counts(probes: Dict[str, str]) -> Dict[str, int]:
 
 def _agg_input_marks(agg_box, agg_np, n_dev: int) -> Dict[str, dict]:
     """{operator label: {"input": "compact" | "full" | "compact k/n",
-    "live": rows, "capacity": slots}} for the aggregates of one run that
-    were traced with a choice: `agg_np` holds, per aggregate, how many of
-    the `n_dev` devices compacted its input, and the input's live rows
-    over all devices (`capacity`: its slots over all devices; `cap`: the
-    rows a device its output was cut to, the capacity ladder's rung)."""
+    "rows": width, "live": rows, "capacity": slots, "cap": rung}} for the
+    aggregates of one run that were traced with a choice: `agg_np` holds,
+    per aggregate, how many of the `n_dev` devices compacted its input,
+    the input's live rows over all devices, and how many devices ran the
+    body at each of its widths (`rows`: the width the body ran at, or
+    `a/b` where devices differ; `capacity`: the input's slots over all
+    devices; `cap`: the rows a device its output is cut to where its
+    input is larger, the capacity ladder's rung)."""
     counts = iter(np.asarray(agg_np).tolist() if agg_np is not None else ())
     marks = {}
     for what in agg_box:
         k, live = next(counts), next(counts)
+        ran = [w for w in what["widths"] if next(counts)]
         marks[what["label"]] = {
             "input": "compact" if k == n_dev else
             "full" if k == 0 else f"compact {k}/{n_dev}",
+            "rows": ran[0] if len(ran) == 1 else "/".join(map(str, ran)),
             "live": live, "capacity": what["capacity"],
             "cap": what["cap"]}
     return marks
 
 
+def agg_widths(mark: dict) -> List[int]:
+    """The widths the devices ran one aggregate's body at, ascending, from
+    its mark's `rows`."""
+    return [int(w) for w in str(mark["rows"]).split("/")]
+
+
 def agg_input_counts(aggs: Dict[str, dict]) -> Dict[str, int]:
-    """The counter's two numbers: aggregates run with a choice of input,
-    and those of them whose input every device compacted."""
+    """The counter's three numbers: aggregates run with a choice of
+    width, those of them whose input every device compacted, and those
+    whose body every device ran under the capacity ladder's rung."""
     return {"agg_inputs": len(aggs),
             "agg_inputs_compact": sum(a["input"] == "compact"
-                                      for a in aggs.values())}
+                                      for a in aggs.values()),
+            "agg_inputs_below_cap": sum(agg_widths(a)[-1] < a["cap"]
+                                        for a in aggs.values())}
 
 
 def segment_counts(counted: Dict[str, int]) -> Dict[str, int]:
@@ -2076,7 +2137,7 @@ def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
 
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
     """execute_plan_spmd's `stats` as query totals: what the scan tasks
-    read, the probe counter's two numbers, the aggregate inputs' two, the
+    read, the probe counter's two numbers, the aggregate inputs' three, the
     segment bounds' two, the ladder's rung and the wide decimal columns,
     and the boundaries' counts."""
     return {**ingest_totals(stats),
@@ -2278,6 +2339,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         agg_cap_hint = int(_conf.get("auron.spmd.agg.capacity.hint"))
     cache_key = (
         plan, axis, n_dev, match_factor, agg_cap_hint, join_compact,
+        # the bucket an aggregate's rungs are rounded up to
+        int(_conf.get("auron.batch.capacity.min")),
         _mesh_fingerprint(mesh),
         # EVERY config the tracer (or kernels it calls) reads at trace
         # time must appear here: rid canonicalization makes equal plans
@@ -2360,7 +2423,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                     [n for _what, n in tracer.crossings]) \
                     if tracer.crossings else None
                 # per aggregate traced with a choice, the devices that
-                # compacted its input and its live rows; likewise
+                # compacted its input, its live rows and the devices at
+                # each width; likewise
                 agg_compact = jnp.concatenate(
                     [n for _what, n in tracer.agg_inputs]) \
                     if tracer.agg_inputs else None

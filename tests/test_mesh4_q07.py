@@ -340,16 +340,22 @@ def test_one_device_counts_nothing(runs):
     """A program over one device has no boundary to count at (its text is
     pinned in test_one_program.py); at `rehearse_rows` no table of query
     7 (65,536 slots at most) is larger than the capacity hint's 262,144,
-    so no aggregate has a choice to trace: the program's `agg_inputs` is
-    there and empty, and its two aggregate bodies derived their segments'
-    bounds once each for the sixteen reductions over them."""
+    so no aggregate is cut — but the hint's lower rung, 8,192 rows, is
+    narrower than both aggregates' 65,536-row inputs (the upper one,
+    65,536, is not), so each chooses between the two widths, and the few
+    dozen live rows take the rung.  Two aggregates, two bodies each: four
+    derivations of segment bounds for thirty-two reductions."""
     _cat, _params, session, plan, _got = runs[SEEDS[0]]
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
     assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_probes",
                                        "segments", "shard"]
-    assert one.stage_stats["agg_inputs"] == {}
-    assert one.stage_stats["segments"] == {"bounds": 2, "reductions": 16}
+    aggs = one.stage_stats["agg_inputs"]
+    assert sorted(aggs) == ["agg#1", "agg#3"]
+    for a in aggs.values():
+        assert (a["input"], a["rows"], a["capacity"], a["cap"]) == \
+            ("compact", 8192, 65536, 262144) and 0 < a["live"] <= 8192
+    assert one.stage_stats["segments"] == {"bounds": 4, "reductions": 32}
     # the driver's own count of what the scan leaves' tasks read (PR 31)
     assert one.stage_stats["ingest"]["scans"] == 5
     assert one.stage_stats["ingest"]["device_batches"] == 0
@@ -357,8 +363,11 @@ def test_one_device_counts_nothing(runs):
     assert not set(_TOTALS) & set(totals)
     assert not set(_TOTALS) & set(_wait_args(one))
     assert totals["join_probes_direct"] == 4
-    assert (totals["segment_bounds"], totals["segment_reductions"]) == (2, 16)
-    assert _wait_args(one)["segment_bounds"] == 2
+    assert (totals["segment_bounds"], totals["segment_reductions"]) == (4, 32)
+    assert _wait_args(one)["segment_bounds"] == 4
+    for where in (totals, _wait_args(one)):
+        assert (where["agg_inputs"], where["agg_inputs_compact"],
+                where["agg_inputs_below_cap"]) == (2, 2, 2)
     shard = [s.args for s in one.trace.snapshot() if s.name == "spmd.shard"]
     # what `_DEVICE_SHARDS` did for the attempt (PR 33): each source
     # served or placed (shards placed for four devices serve no other

@@ -9,7 +9,9 @@ import pathlib
 import pytest
 
 from auron_tpu.parallel.mesh import data_mesh
-from test_agg_input_compaction import HINT, _lowered as _agg_text
+from test_agg_input_compaction import (
+    HINT, RUNGS, _lowered as _agg_text, case_branches,
+)
 from test_spmd_stage import _lowered_join_text
 
 # sha256 of each plan's lowered text at commit a52851b, the parent of the PR
@@ -23,16 +25,23 @@ from test_spmd_stage import _lowered_join_text
 # from the tree before it, the way these were taken.  PR 36 moved the five
 # that hold an aggregate, on purpose (a sorted-segment reduction reads its
 # segments' bounds from the aggregate's boundaries: 20, 30, 40 and 32 calls
-# of `searchsorted` left them); the three join programs are a52851b's.
+# of `searchsorted` left them); PR 38 moved the three in which an
+# aggregate chooses (the choice is a `lax.switch` over a ladder of widths and
+# the counter says which each device took; query 7's two aggregates, which
+# chose nothing at `rehearse_rows`, now choose between a rung of 8,192 rows
+# and their 65,536-row inputs) and added `agg-chooses-rungs`; the two that
+# choose nothing and the three join programs are what they were.
 CHIP_PROGRAM = {
     "agg-input-within-target":
         "a46c6612703b3eea44126a17fb0994671808bf1b61d0ed5093a8e4e5bf1be517",
     "agg-shrink-off":
         "a46c6612703b3eea44126a17fb0994671808bf1b61d0ed5093a8e4e5bf1be517",
     "agg-chooses":
-        "3faff62eb918ac1a3b7a87795c9256bd5e6a5a788c78b5e5d3cac5c6c19da243",
+        "41758e9e2fa27ccc92a6ef39042b48b6df1d89d28462130b79ff95405a44fa52",
     "agg-chooses-four-devices":
-        "aeae6e91d2fc8762058c0ae5d79cdc52af4d406452f12f9ff8c8c179da2a7ec1",
+        "8c742f669a97288cc6761850d707965769fa36249d2d00a0c3b41bdb17d4e492",
+    "agg-chooses-rungs":
+        "97ec57c5cab51bacc28f2af64f3130040c6e8a9d6ca3ede2b81eb49caf61beb3",
     "join-string":
         "ed13fc3944f8778e208279e97c82dbc3f63b3972594b41de35323a214dfbfc81",
     "join-two-keys":
@@ -40,7 +49,7 @@ CHIP_PROGRAM = {
     "join-int64":
         "8b8593b5ab5b827288b42aaae7be5df7dff916cf3be418b68eafc2329a789009",
     "q07-one-device":
-        "d0e09e1e831635dcd445bbdb7bddaf537fffdfd774abe5f409a10dc42cefde79",
+        "675865dbb1d921827538059c5d939347cac44e9f22c0c742df7de6572743fdd0",
 }
 
 
@@ -73,6 +82,8 @@ _TEXT = {
     # the exchange's sort-and-scatter, its all_to_all and the counts of
     # what crossed are in the program only over more than one device
     "agg-chooses-four-devices": lambda _tmp: _agg_text(HINT, n_dev=4),
+    # two rungs under the target, 32 and 256 rows
+    "agg-chooses-rungs": lambda _tmp: _agg_text(RUNGS),
     # test_spmd_stage's broadcast joins, on one device
     "join-string": lambda _tmp: _lowered_join_text("string", n_dev=1),
     "join-two-keys": lambda _tmp: _lowered_join_text("two-keys", n_dev=1),
@@ -115,6 +126,26 @@ def test_no_aggregate_searches_for_its_segments(case, tmp_path):
     text = _text(case, tmp_path)
     assert len(re.findall(r"call @\S*searchsorted", text)) == \
         _JOINS.get(case, 0)
+
+
+# the sides of every conditional of each program, in the text's order: a
+# K = 1 join over a directly addressable key chooses between two probes,
+# an aggregate among the widths under its input and the input's own
+_SIDES = {
+    "join-int64": [2],
+    "agg-chooses": [2],                     # the target or the input
+    "agg-chooses-four-devices": [2, 2],
+    # 32, 256, 1,024 or the input's 8,192; 32, 256 or the input's 1,024
+    "agg-chooses-rungs": [4, 3],
+    # four probes; `agg#3` and `agg#1`, each the hint's rung of 8,192 rows
+    # or its input's 65,536 (its other rung, 65,536, is no narrower)
+    "q07-one-device": [2, 2, 2, 2, 2, 2],
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHIP_PROGRAM))
+def test_every_conditional_has_the_sides_it_chooses_among(case, tmp_path):
+    assert case_branches(_text(case, tmp_path)) == _SIDES.get(case, [])
 
 
 RETIRED_OPTIONS = [

@@ -248,9 +248,17 @@ def test_past_the_capacity_hint_the_first_execute_climbs_and_the_second_knows(
     aggs = second.stage_stats["agg_inputs"]
     assert {a["cap"] for a in aggs.values()} == {4096}
     assert two.metric_totals["agg_capacity"] == 4096
-    groups = [a["live"] for a in aggs.values() if a["capacity"] == 16384]
-    assert groups and all(1024 < live <= 4096 for live in groups)
-    assert all(a["input"] == "compact" for a in aggs.values())
+    cut = [a for a in aggs.values() if a["capacity"] == 16384]
+    assert cut and all(a["input"] == "compact" and a["rows"] == 4096
+                       and 1024 < a["live"] <= 4096 for a in cut)
+    # the rung has a rung of its own, 1,024 rows, so the aggregates over
+    # the 4,096-row tables choose too: the average by store alone has few
+    # enough rows for it (as the cell's `agg#28` alone takes 32,768 under
+    # 1,048,576), the three others run at their input's width
+    uncut = [a for a in aggs.values() if a["capacity"] == 4096]
+    assert sorted(a["rows"] for a in uncut) == [1024, 4096, 4096, 4096]
+    assert [a["input"] for a in uncut if a["rows"] == 1024] == ["compact"]
+    assert two.metric_totals["agg_inputs_below_cap"] == 1
     text = second.explain_analyze()
     assert text.count(" cap=4096") == len(aggs) >= 2
     # the attempt that tripped worked on the full table: more live rows

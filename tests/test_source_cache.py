@@ -177,6 +177,7 @@ def test_a_leaf_over_the_budget_stays_beside_its_plans_leaves(tmp_path):
                 arrow_bytes - MB + device_bytes - MB,
             "join_probes": 2, "join_probes_direct": 2,
             "agg_inputs": 0, "agg_inputs_compact": 0,
+            "agg_inputs_below_cap": 0,
             # the plan holds no aggregate
             "segment_bounds": 0, "segment_reductions": 0}
         assert S.stage_totals(first)["scan_cached"] == 0

@@ -175,9 +175,10 @@ def test_compact_scope_under_an_aggregate_that_chose(files):
         line.split()[0] for line in explained.splitlines()
         if "mode=partial" in line or "mode=final" in line][::-1]
     # the running count, the scatter of row numbers and the columns'
-    # gathers on the compact side (branch 1); the cut on the full side
-    for branch, op in ((1, "jit(cumsum)"), (1, "scatter"),
-                       (1, "jit(_take)"), (0, "slice")):
+    # gathers on the compact side (the switch's sides ascend by width:
+    # branch 0); the cut on the full side, the last
+    for branch, op in ((0, "jit(cumsum)"), (0, "scatter"),
+                       (0, "jit(_take)"), (1, "slice")):
         assert f"{partial}/cond/branch_{branch}_fun/compact/{op}" in text, op
     assert f"{final}/cond/" not in text and f"{final}/compact/" not in text
     # the body's scopes on both sides of the choice

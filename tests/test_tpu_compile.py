@@ -327,3 +327,69 @@ def test_live_row_compaction_at_sf1_capacity(one_chip, tpu_branches,
     # holds whoever first traced a shared inner jit (`jnp.where` inside
     # `sorted_segment_sum`, in the test above)
     assert " sort(" not in compiled.as_text()
+
+
+RUNGS = (1 << 13, 1 << 15, 1 << 16)     # the ladder's rungs the cells reach
+
+
+def test_aggregate_body_at_every_rung_inside_a_switch(one_chip, tpu_branches,
+                                                      no_persistent_cache):
+    """An aggregate's choice of width (parallel/stage.py `_do_agg`): the
+    live rows of a `LADDER_CAP`-row table compacted to a rung, the final
+    body there — an int64 sum, the 128-bit sum and count of a decimal
+    average with its division, a count — and the groups padded back, each
+    rung a branch of one `lax.switch`: the hint's rungs of 8,192 and
+    65,536 rows and 32,768, the rung of `tpcds-sf10.q01`'s 1,048,576.  A
+    kernel under a conditional is another compile than the same kernel
+    outside one (ops/segments.py `inside_branch`)."""
+    from jax import lax
+    from auron_tpu.columnar.batch import DeviceDecimal128Column
+    from auron_tpu.ir.schema import Field, Schema
+    from auron_tpu.ops.agg.exec import _group_reduce_body
+    from auron_tpu.ops.agg.functions import make_spec
+    from auron_tpu.ops.segments import inside_branch
+    from auron_tpu.parallel.stage import DeviceTable, _compact_front
+    money, total = DataType.decimal(7, 2), DataType.decimal(17, 2)
+    specs = [make_spec("sum", money, total, "total"),
+             make_spec("avg", total, DataType.decimal(21, 6), "mean",
+                       wide=True),
+             make_spec("count", I64, I64, "cnt")]
+    fields = [Field("k", I64)] + [f for s in specs for f in s.state_fields()]
+
+    def column(dt):
+        if not dt.is_wide_decimal:
+            return _column(one_chip, dt, LADDER_CAP)
+        return DeviceDecimal128Column(
+            dt, _shape(one_chip, LADDER_CAP, jnp.int64),
+            _shape(one_chip, LADDER_CAP, jnp.uint64),
+            _shape(one_chip, LADDER_CAP, jnp.bool_))
+
+    def at(rung, t, n_live):
+        def side():
+            c = _compact_front(t, n_live, rung)
+            states, off = [], 1
+            for s in specs:
+                k = len(s.state_fields())
+                states.append(c.cols[off:off + k])
+                off += k
+            out, n_groups = _group_reduce_body(
+                c.cols[:1], states, c.live, specs, ((True, True),), True)
+            finals, off = [out[0]], 1
+            for s in specs:
+                k = len(s.state_fields())
+                finals.append(s.eval_final(out[off:off + k]))
+                off += k
+            return jax.tree.map(
+                lambda x: jnp.pad(x, (0, LADDER_CAP - rung)), finals), \
+                n_groups
+        return side
+
+    def either(cols, live):
+        t = DeviceTable(Schema(tuple(fields)), cols, live)
+        n_live = jnp.sum(live.astype(jnp.int32))
+        with inside_branch():
+            return lax.switch(
+                sum((n_live > r).astype(jnp.int32) for r in RUNGS[:-1]),
+                [at(r, t, n_live) for r in RUNGS])
+    _compile(either, [column(f.dtype) for f in fields],
+             _shape(one_chip, LADDER_CAP, jnp.bool_))
