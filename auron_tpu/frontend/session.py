@@ -83,8 +83,10 @@ class SessionResult:
     exchange_stats: List[dict] = field(default_factory=list)
     # what the stage program reported of itself (parallel/stage.py::
     # execute_plan_spmd's `stats`): `join_probes`, the probe each K=1
-    # join took, and `agg_inputs`, the input each aggregate that chose
-    # worked on and the width its body ran at, by operator label;
+    # join took, `agg_inputs`, the input each aggregate that chose
+    # worked on and the width its body ran at, and `join_chains`, the side
+    # each join chain took under its first join's label and the width its
+    # later joins ran at, by operator label;
     # `segments`, the segment bounds the
     # program's trace derived and the reductions over them; `ingest`, what
     # the scan leaves' tasks read; over more than one device also
@@ -135,7 +137,10 @@ class SessionResult:
         capacity their output is cut to, or a rung below it),
         `agg_inputs_compact` (those whose input every device compacted
         to such a width first) and `agg_inputs_below_cap` (those every
-        device ran under that capacity, at a rung); `segment_bounds` (segment bounds derived
+        device ran under that capacity, at a rung); `join_chains` (chains
+        of inner joins over a scan with a width to choose for their later
+        joins) and `join_chains_compact` (those every device ran at a
+        rung); `segment_bounds` (segment bounds derived
         while the stage program was traced: one an aggregate body) and
         `segment_reductions` (sorted-segment reductions that took them);
         over more than one device also

@@ -31,7 +31,10 @@ program over a `jax.sharding.Mesh`:
   side's u64 key hashes and binary-searches them.  Duplicate build keys
   under a pair-emitting join trip a retryable guard and the driver
   re-traces with K-way pair expansion (double `searchsorted`); past the
-  factor the plan falls back to the serial engine.
+  factor the plan falls back to the serial engine.  In a chain of inner
+  joins over a source the later joins probe only the rows the first one
+  left, compacted to the narrowest of a short ladder of widths that holds
+  them (`_join_chain`: chosen in the program, per device, like the rest).
 
 Anything the compiler cannot express raises `SpmdUnsupported`; callers
 (AuronSession.execute with a mesh) fall back to the per-partition serial
@@ -41,6 +44,8 @@ unconvertible plan sections (AuronConvertStrategy).
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 from dataclasses import dataclass
 from typing import (
     Any, Callable, Collection, Dict, List, Optional, Tuple,
@@ -164,6 +169,152 @@ def _agg_rungs(new_cap: int, capacity: int) -> List[int]:
                                bucket_capacity(new_cap // 4)) if r < end})
 
 
+# a join chain's joins probe one another through these operators alone
+_CHAIN_LINKS = (P.Projection, P.Filter)
+# and its first join probes one of these: a source whose buffer is sized
+# by its whole table, so the first join's selectivity is not bounded there
+_CHAIN_SOURCES = (P.ParquetScan, P.OrcScan, P.FFIReader)
+
+
+def _below_links(node):
+    while isinstance(node, _CHAIN_LINKS):
+        node = node.child
+    return node
+
+
+def join_chain(top) -> List[P.BroadcastJoin]:
+    """The joins of the chain `top` ends, `top` first and the chain's first
+    join last: inner broadcast joins, each probing the output of the one
+    below it through projections and filters alone, the first of them
+    probing a source (`_CHAIN_SOURCES`) through projections and filters
+    alone.  Empty where `top` ends no chain of two joins or more: a left,
+    semi or anti join breaks a chain, and so does a probe side that
+    reaches an aggregate or an exchange."""
+    joins = []
+    node = top
+    while isinstance(node, P.BroadcastJoin) and \
+            node.join_type == "inner" and node.broadcast_side == "right":
+        joins.append(node)
+        node = _below_links(node.left)
+    if len(joins) < 2 or not isinstance(node, _CHAIN_SOURCES):
+        return []
+    return joins
+
+
+def _chain_rungs(capacity: int) -> List[int]:
+    """The widths a join chain over a `capacity`-row source may run its
+    later joins at, ascending: a sixty-fourth and an eighth of it (65,536
+    and 524,288 rows at 4,194,304), each a capacity bucket, and each only
+    where it is at least `auron.batch.capacity.min` rows.  None of them
+    sorts anything, so unlike an aggregate's rungs they need no ceiling."""
+    from auron_tpu.config import conf as _conf
+    least = int(_conf.get("auron.batch.capacity.min"))
+    return sorted({bucket_capacity(capacity // d) for d in (64, 8)
+                   if capacity // d >= least})
+
+
+def _pad_rows(tree, rows: int):
+    """Every array of `tree` padded with zeros (dead rows) to `rows` rows."""
+    return jax.tree.map(
+        lambda x: jnp.pad(x, [(0, rows - x.shape[0])]
+                          + [(0, 0)] * (x.ndim - 1)), tree)
+
+
+# XLA:TPU lays a [rows, width] byte array out with its width padded to 128
+# lanes, eight times a 16-byte string's bytes (4 GB at 33,554,432 rows): a
+# join chain's sides gather a build side's string, and hand every string
+# out of the choice, as 1-D columns of 32-bit words, laid out as they are
+
+
+def _byte_words(data) -> List[Any]:
+    """A [rows, width] byte array as 1-D columns of 32-bit words, four
+    bytes a word, the first the lowest (of bytes, where the width is not a
+    multiple of four)."""
+    width = data.shape[1]
+    if width % 4:
+        return [data[:, k] for k in range(width)]
+    d = data.astype(jnp.uint32)
+    return [d[:, 4 * k] | d[:, 4 * k + 1] << 8 | d[:, 4 * k + 2] << 16
+            | d[:, 4 * k + 3] << 24 for k in range(width // 4)]
+
+
+def _word_bytes(words: List[Any]):
+    """`_byte_words`' columns as the [rows, width] byte array again."""
+    if words[0].dtype == jnp.uint8:
+        return jnp.stack(words, axis=1)
+    return jnp.stack([(w >> (8 * j) & 0xFF).astype(jnp.uint8)
+                      for w in words for j in range(4)], axis=1)
+
+
+def _as_words(cols) -> List[Any]:
+    return [dataclasses.replace(c, data=_byte_words(c.data))
+            if isinstance(c, DeviceStringColumn) else c for c in cols]
+
+
+def _as_bytes(cols) -> List[Any]:
+    return [dataclasses.replace(c, data=_word_bytes(c.data))
+            if isinstance(c, DeviceStringColumn) else c for c in cols]
+
+
+def _take_rows(c, bidx, ok):
+    """Column `c`'s rows `bidx` where `ok`, and a dead row elsewhere — as
+    `c.gather`, but a string's bytes gathered a word column at a time
+    (`_byte_words`): a gather of [rows, width] bytes at the source's
+    capacity inside a chain's full side would be laid out padded."""
+    if not isinstance(c, DeviceStringColumn):
+        return c.gather(bidx, ok)
+
+    def take(x):
+        return jnp.where(ok, jnp.take(x, bidx, axis=0, mode="fill",
+                                      fill_value=0),
+                         jnp.zeros((), x.dtype))
+    return DeviceStringColumn(
+        c.dtype, _word_bytes([take(w) for w in _byte_words(c.data)]),
+        take(c.lengths), take(c.validity))
+
+
+def _projection_schema(n: P.Projection, below: Schema) -> Schema:
+    from auron_tpu.exprs.typing import infer_type
+    return Schema(tuple(Field(nm, infer_type(x, below))
+                        for nm, x in zip(n.names, n.exprs)))
+
+
+def _linked_schema(node, known: Dict[int, Schema]) -> Schema:
+    """The schema of `node`'s output, where `node` is a chain's link (or a
+    join) above a node whose output schema `known` holds — without tracing
+    the link."""
+    if id(node) in known:
+        return known[id(node)]
+    below = _linked_schema(node.child, known)
+    return below if isinstance(node, P.Filter) else \
+        _projection_schema(node, below)
+
+
+def _direct_key_types(ptypes: List[DataType], bkeys) -> bool:
+    """`_direct_addressable` from the probe keys' types, for a probe side
+    that is not traced yet."""
+    return len(ptypes) == 1 and len(bkeys) == 1 and \
+        isinstance(bkeys[0], DeviceColumn) and \
+        (ptypes[0].is_integral or ptypes[0].id == TypeId.DATE32) and \
+        ptypes[0].id == bkeys[0].dtype.id and \
+        np.dtype(ptypes[0].numpy_dtype()) == bkeys[0].data.dtype
+
+
+@dataclass
+class _ChainJoin:
+    """A later join of a join chain as its build half left it: what the
+    probe half reads inside every side of the chain's choice."""
+    label: str
+    build: DeviceTable
+    bkeys: List[Any]
+    # (kmin, table, order, sorted_bh), or (order, sorted_bh) where the
+    # keys are not directly addressable
+    half: Tuple[Any, ...]
+    trip: Any
+    # the devices' choice of probe; None where the search is the only one
+    dense: Any
+
+
 def _direct_addressable(pkeys, bkeys) -> bool:
     """The static half of the direct-address probe's test: one key pair,
     both device columns of one integer or date type (not decimal, string
@@ -250,12 +401,16 @@ def explain_stage(plan, conv_ctx,
     the probe it took, `direct` or `search`, each aggregate that chose a
     width with the input it worked on, `compact` or `full`, the width its
     body ran at (`rows`), the live rows of its input and the rung of the
-    capacity ladder (`cap`), each operator whose output holds
+    capacity ladder (`cap`), the first join of each join chain that chose
+    a width with the side its later joins took (`chain=compact|full`), the
+    width they ran at and the live rows it left, each operator whose
+    output holds
     wide decimals with `dec128` and how many, and each boundary that
     crossed devices with what it moved."""
     stats = stats or {}
     probes = stats.get("join_probes") or {}
     aggs = stats.get("agg_inputs") or {}
+    chains = stats.get("join_chains") or {}
     wide = stats.get("wide_columns") or {}
     crossed = {**(stats.get("exchanges") or {}),
                **(stats.get("broadcasts") or {})}
@@ -277,6 +432,10 @@ def explain_stage(plan, conv_ctx,
             detail = f" type={node.join_type}"
             if label in probes:
                 detail += f" probe={probes[label]}"
+            if label in chains:
+                c = chains[label]
+                detail += (f" chain={c['chain']} rows={c['rows']}"
+                           f" live={c['live']} of {c['capacity']}")
         elif isinstance(node, P.IpcReader):
             if node.resource_id in exchanges:
                 detail = " exchange:" + \
@@ -363,6 +522,16 @@ class _StageTracer:
         # that compacted the input, live input rows, then the devices at
         # each of those widths] — a replicated int64 device vector)
         self.agg_inputs: List[Tuple[Dict[str, Any], Any]] = []
+        # one entry per join chain traced with a choice of width, likewise
+        # (its first join's label, its slots over all devices and the
+        # widths; [devices that compacted, live rows after the first join,
+        # then the devices at each width])
+        self.join_chains: List[Tuple[Dict[str, Any], Any]] = []
+        # while a side of a chain's choice is traced: (the chain's first
+        # join, what stands for its output on that side, the later joins
+        # by node id as their build halves left them)
+        self._chain: Optional[Tuple[Any, Callable[[], DeviceTable],
+                                    Dict[int, _ChainJoin]]] = None
         # one entry per exchange or broadcast boundary that crossed
         # devices, in trace order: (what is known of it at trace time,
         # its counts — a replicated int64 device vector); a one-device
@@ -660,10 +829,7 @@ class _StageTracer:
     def _do_projection(self, n: P.Projection) -> DeviceTable:
         t = self.eval_node(n.child)
         cols = self._eval_exprs(n.exprs, t)
-        from auron_tpu.exprs.typing import infer_type
-        fields = tuple(Field(nm, infer_type(x, t.schema))
-                       for nm, x in zip(n.names, n.exprs))
-        return DeviceTable(Schema(fields), cols, t.live)
+        return DeviceTable(_projection_schema(n, t.schema), cols, t.live)
 
     def _do_rename_columns(self, n: P.RenameColumns) -> DeviceTable:
         t = self.eval_node(n.child)
@@ -808,10 +974,7 @@ class _StageTracer:
                 out, _n_groups = aggregate_over(
                     _compact_front(t, n_live, width))
                 with jax.named_scope("compact"):
-                    cols, live = jax.tree.map(
-                        lambda x: jnp.pad(x, [(0, out_cap - width)]
-                                          + [(0, 0)] * (x.ndim - 1)),
-                        (out.cols, out.live))
+                    cols, live = _pad_rows((out.cols, out.live), out_cap)
                 return cols, live, jnp.bool_(False)
             return side
 
@@ -830,18 +993,22 @@ class _StageTracer:
         if cut:
             self.shrink_guards.append(
                 lax.psum(over.astype(jnp.int32), self.axis) > 0)
-        # devices that compacted their input, the rows they looked at and
-        # the devices at each width, for the driver's counter
-        took = jnp.arange(len(widths) + 1, dtype=jnp.int32) == chosen
         self.agg_inputs.append((
             {"label": self.labels.get(id(n), n.kind),
              "capacity": t.capacity * self.n_dev, "cap": new_cap,
              "widths": (*widths, t.capacity)},
-            lax.psum(jnp.concatenate([
-                jnp.stack([(chosen < len(widths)).astype(jnp.int32),
-                           n_live]), took.astype(jnp.int32)])
-                .astype(jnp.int64), self.axis)))
+            self._choice_counts(chosen, n_live, len(widths))))
         return DeviceTable(out_schema, cols, live)
+
+    def _choice_counts(self, chosen, n_live, n_widths: int):
+        """For the driver's counter of a choice of width among `n_widths`
+        and the input's own: the devices that compacted, the live rows
+        they looked at and the devices at each width, summed over
+        devices."""
+        took = jnp.arange(n_widths + 1, dtype=jnp.int32) == chosen
+        return lax.psum(jnp.concatenate([
+            jnp.stack([(chosen < n_widths).astype(jnp.int32), n_live]),
+            took.astype(jnp.int32)]).astype(jnp.int64), self.axis)
 
     def _shrink_front(self, t: DeviceTable, n_live,
                       new_cap: int) -> Tuple[DeviceTable, Array]:
@@ -865,6 +1032,16 @@ class _StageTracer:
         # build side is REPLICATED on every device: emitting unmatched
         # build rows (full/right) would duplicate them per device, so
         # those types are precheck-rejected for broadcast joins
+        if self._chain is not None:
+            head, head_out, later = self._chain
+            if n is head:
+                return head_out()
+            if id(n) in later:
+                return self._chain_probe(n, later[id(n)])
+        elif self.match_factor == 1:
+            joins = join_chain(n)
+            if joins and _chain_rungs(self._source_capacity(joins[-1])):
+                return self._join_chain(joins)
         return self._join(n.left, n.right, n.on, n.join_type,
                           build_side=n.broadcast_side,
                           existence_name=n.existence_output_name,
@@ -1061,40 +1238,13 @@ class _StageTracer:
         sorted-hash search.  `lax.cond`: only the chosen side executes,
         each device decides for its own build shard, and no collective
         sits inside a branch."""
-        cap = build.capacity
         pdata, bdata = _key_words(pk.data), _key_words(bk.data)
-        with jax.named_scope("build"):
-            bvalid = jnp.logical_and(build.live, bk.validity)
-            info = jnp.iinfo(bdata.dtype)
-            kmin = jnp.min(jnp.where(bvalid, bdata, info.max))
-            kmax = jnp.max(jnp.where(bvalid, bdata, info.min))
-            # an unsigned wrap-around difference is exact for any pair of
-            # signed keys: no int64 extreme overflows it
-            dense = jnp.logical_and(
-                jnp.any(bvalid),
-                _unsigned(kmax) - _unsigned(kmin) < cap)
+        bvalid, kmin, dense = self._key_range(build, bk, bdata)
 
         def direct():
-            with jax.named_scope("build"):
-                # dead and null-key rows scatter out of range and drop
-                slot = jnp.where(bvalid, _unsigned(bdata) - _unsigned(kmin),
-                                 cap).astype(jnp.int32)
-                table = jnp.full(cap, -1, jnp.int32).at[slot].set(
-                    jnp.arange(cap, dtype=jnp.int32), mode="drop")
-                # a duplicate key lost its slot to another row
-                trip = jnp.bool_(False) if semi_like else \
-                    jnp.sum((table >= 0).astype(jnp.int32)) != \
-                    jnp.sum(bvalid.astype(jnp.int32))
-            with jax.named_scope("probe"):
-                off = _unsigned(pdata) - _unsigned(kmin)
-                in_range = off < cap
-                bidx = table.at[
-                    jnp.where(in_range, off, 0).astype(jnp.int32)
-                ].get(mode="promise_in_bounds")
-                ok = jnp.logical_and(
-                    jnp.logical_and(probe.live, pk.validity),
-                    jnp.logical_and(in_range, bidx >= 0))
-                return jnp.maximum(bidx, 0), ok, trip
+            table, trip = self._direct_table(build, bvalid, bdata, kmin,
+                                             semi_like)
+            return (*self._direct_probe(probe, pk, pdata, kmin, table), trip)
 
         def search():
             with jax.named_scope("build"):
@@ -1113,10 +1263,228 @@ class _StageTracer:
             (label, lax.psum(dense.astype(jnp.int32), self.axis)))
         return bidx, ok
 
+    @staticmethod
+    def _key_range(build, bk, bdata):
+        """(bvalid, kmin, dense): the build rows with a key, the least of
+        their keys, and whether the keys span less than the build side's
+        capacity — the direct-address table's test."""
+        with jax.named_scope("build"):
+            bvalid = jnp.logical_and(build.live, bk.validity)
+            info = jnp.iinfo(bdata.dtype)
+            kmin = jnp.min(jnp.where(bvalid, bdata, info.max))
+            kmax = jnp.max(jnp.where(bvalid, bdata, info.min))
+            # an unsigned wrap-around difference is exact for any pair of
+            # signed keys: no int64 extreme overflows it
+            dense = jnp.logical_and(
+                jnp.any(bvalid),
+                _unsigned(kmax) - _unsigned(kmin) < build.capacity)
+        return bvalid, kmin, dense
+
+    @staticmethod
+    def _direct_table(build, bvalid, bdata, kmin, semi_like: bool):
+        """(table, trip): each key's build row at `key - kmin`, -1 where
+        no key lands, and the duplicate-key trip."""
+        cap = build.capacity
+        with jax.named_scope("build"):
+            # dead and null-key rows scatter out of range and drop
+            slot = jnp.where(bvalid, _unsigned(bdata) - _unsigned(kmin),
+                             cap).astype(jnp.int32)
+            table = jnp.full(cap, -1, jnp.int32).at[slot].set(
+                jnp.arange(cap, dtype=jnp.int32), mode="drop")
+            # a duplicate key lost its slot to another row
+            trip = jnp.bool_(False) if semi_like else \
+                jnp.sum((table >= 0).astype(jnp.int32)) != \
+                jnp.sum(bvalid.astype(jnp.int32))
+        return table, trip
+
+    @staticmethod
+    def _direct_probe(probe, pk, pdata, kmin, table):
+        """(bidx, ok) by direct address: one gather of the probe's
+        capacity."""
+        cap = table.shape[0]
+        with jax.named_scope("probe"):
+            off = _unsigned(pdata) - _unsigned(kmin)
+            in_range = off < cap
+            bidx = table.at[
+                jnp.where(in_range, off, 0).astype(jnp.int32)
+            ].get(mode="promise_in_bounds")
+            ok = jnp.logical_and(
+                jnp.logical_and(probe.live, pk.validity),
+                jnp.logical_and(in_range, bidx >= 0))
+            return jnp.maximum(bidx, 0), ok
+
+    # -- a join chain: its later joins at the width of its live rows --------
+
+    def _source_capacity(self, head) -> int:
+        """The capacity of the source a chain's first join probes; 0 where
+        it is not bound (the join then raises as it always did)."""
+        src = _below_links(head.left)
+        rid = src.resource_id if isinstance(src, P.FFIReader) else \
+            self.scan_rids.get(id(src), "?")
+        t = self.bindings.get(rid)
+        return t.capacity if t is not None else 0
+
+    def _join_chain(self, joins: List[P.BroadcastJoin]) -> DeviceTable:
+        """A chain of K = 1 inner joins over a source (`join_chain`, top
+        first).  Its first join keeps few of the source's rows as a rule,
+        yet every later probe gathers at the source's full capacity, so the
+        program counts the rows the first join left and chooses, per device
+        (one `lax.switch`, no collective inside a side), the narrowest
+        width of a short ladder that holds them (`_chain_rungs`):
+
+        - a rung — those rows are brought to the front of a table that
+          wide (`_compact_front`: stable, no sort), the later joins run
+          there with their projections and filters, and their output goes
+          on with dead rows behind it at the source's capacity, so nothing
+          downstream changes shape;
+        - otherwise — full: the later joins at the source's capacity, as
+          without a chain.
+
+        The first join runs before the choice, as it always did, and so do
+        the later joins' build sides — their broadcasts are collectives —
+        and build halves (`_chain_build_half`); inside a side each later
+        join runs its probe half alone (`_chain_probe`).  Their guards'
+        and counters' `psum`s sit after the choice."""
+        from auron_tpu.exprs.typing import infer_type
+        from auron_tpu.ops.joins.exec import join_output_schema
+        top, head = joins[0], joins[-1]
+        t = self.eval_node(head)
+        schemas = {id(head): t.schema}
+        later: Dict[int, _ChainJoin] = {}
+        for j in reversed(joins[:-1]):
+            label = self.labels.get(id(j), j.kind)
+            # the top's own scope is open already
+            with jax.named_scope(label) if j is not top else \
+                    contextlib.nullcontext():
+                build = self.eval_node(j.right)
+                with jax.named_scope("build"):
+                    bkeys = self._eval_keys(j.on.right_keys, build,
+                                            "join key")
+                probe_schema = _linked_schema(j.left, schemas)
+                later[id(j)] = self._chain_build_half(
+                    label, build, bkeys,
+                    [infer_type(k, probe_schema) for k in j.on.left_keys])
+            schemas[id(j)] = join_output_schema(
+                probe_schema, build.schema, j.join_type,
+                j.existence_output_name)
+        n_live = jnp.sum(t.live.astype(jnp.int32))
+        cap = t.capacity
+        rungs = _chain_rungs(cap)
+
+        def later_joins(head_out: Callable[[], DeviceTable]) -> DeviceTable:
+            self._chain = (head, head_out, later)
+            try:
+                return self._chain_probe(top, later[id(top)])
+            finally:
+                self._chain = None
+
+        def rung_side(width: int):
+            def side():
+                out = later_joins(
+                    lambda: _compact_front(t, n_live, width))
+                with jax.named_scope("compact"):
+                    return _pad_rows((_as_words(out.cols), out.live), cap)
+            return side
+
+        def full_side():
+            out = later_joins(lambda: t)
+            return _as_words(out.cols), out.live
+
+        # the narrowest width that holds the live rows; past them all, full
+        chosen = sum((n_live > w).astype(jnp.int32) for w in rungs)
+        with inside_branch():
+            words, live = lax.switch(
+                chosen, [rung_side(w) for w in rungs] + [full_side])
+        cols = _as_bytes(words)
+        for c in later.values():
+            self._trip_guard(c.trip, False)
+            self.probes.append((c.label, None if c.dense is None else
+                                lax.psum(c.dense.astype(jnp.int32),
+                                         self.axis)))
+        self.join_chains.append((
+            {"label": self.labels.get(id(head), head.kind),
+             "capacity": cap * self.n_dev, "widths": (*rungs, cap)},
+            self._choice_counts(chosen, n_live, len(rungs))))
+        return DeviceTable(schemas[id(top)], cols, live)
+
+    def _chain_build_half(self, label: str, build: DeviceTable, bkeys,
+                          ptypes: List[DataType]) -> _ChainJoin:
+        """What a later join of a chain computes from its build side alone,
+        once, before the chain's choice: where the keys are directly
+        addressable by type, the key range, the choice between the probes
+        (`dense`), and under one `lax.cond` on it the direct table or the
+        sorted build hashes — the other side's arrays stand in as
+        placeholders of their shapes; otherwise the sorted build hashes.
+        The duplicate-key trip depends on the build side alone, so it is
+        computed here too."""
+        if not _direct_key_types(ptypes, bkeys):
+            with jax.named_scope("build"):
+                order, sorted_bh = self._sorted_build_hashes(build, bkeys)
+                trip = self._search_trip(bkeys, order, sorted_bh, False)
+            return _ChainJoin(label, build, bkeys, (order, sorted_bh), trip,
+                              None)
+        [bk] = bkeys
+        bdata = _key_words(bk.data)
+        bvalid, kmin, dense = self._key_range(build, bk, bdata)
+        cap = build.capacity
+
+        def direct():
+            table, trip = self._direct_table(build, bvalid, bdata, kmin,
+                                             False)
+            return (table, jnp.zeros(cap, jnp.int32),
+                    jnp.zeros(cap, jnp.uint64), trip)
+
+        def search():
+            with jax.named_scope("build"):
+                order, sorted_bh = self._sorted_build_hashes(build, bkeys)
+                trip = self._search_trip(bkeys, order, sorted_bh, False)
+            return jnp.full(cap, -1, jnp.int32), order, sorted_bh, trip
+
+        table, order, sorted_bh, trip = lax.cond(dense, direct, search)
+        return _ChainJoin(label, build, bkeys, (kmin, table, order, sorted_bh),
+                          trip, dense)
+
+    def _chain_probe(self, n: P.BroadcastJoin, c: _ChainJoin) -> DeviceTable:
+        """A later join of a chain inside a side of its choice: its probe
+        side at that side's width, the probe half of its lookup and its
+        output."""
+        probe = self.eval_node(n.left)
+        with jax.named_scope("probe"):
+            pkeys = self._eval_keys(n.on.left_keys, probe, "join key")
+            if _direct_addressable(pkeys, c.bkeys) != (c.dense is not None):
+                # the build half was traced for the keys' types as the
+                # plan's schemas give them
+                raise SpmdUnsupported(
+                    f"join chain: {c.label}'s probe keys are not of the "
+                    "types their schema gives")
+            bidx, ok = self._chain_lookup(probe, pkeys, c)
+            return self._join_emit(probe, c.build, bidx, ok, n.join_type,
+                                   n.existence_output_name,
+                                   take_build=_take_rows)
+
+    def _chain_lookup(self, probe, pkeys, c: _ChainJoin):
+        """(bidx, ok) from a build half: the direct gather, or the search
+        and the exact-key filter, under the same `lax.cond` on `dense`
+        where the build half has one."""
+        def search():
+            ph = self._probe_hashes(probe, pkeys)
+            return self._search_probe(c.build, pkeys, c.bkeys,
+                                      *c.half[-2:], ph)
+        if c.dense is None:
+            return search()
+        kmin, table = c.half[:2]
+        [pk] = pkeys
+        return lax.cond(
+            c.dense,
+            lambda: self._direct_probe(probe, pk, _key_words(pk.data), kmin,
+                                       table),
+            search)
+
     def _join_emit(self, probe, build, bidx, ok, join_type,
-                   existence_name):
+                   existence_name, take_build=None):
         """The join's output from the lookup: `ok` marks the probe rows
-        that found their key, `bidx` the build row each found."""
+        that found their key, `bidx` the build row each found (each build
+        column's through `c.gather`, or `take_build`)."""
         from auron_tpu.ops.joins.exec import join_output_schema
         schema = join_output_schema(probe.schema, build.schema, join_type,
                                     existence_name)
@@ -1131,7 +1499,8 @@ class _StageTracer:
                 jnp.ones(probe.capacity, bool))
             return DeviceTable(schema, list(probe.cols) + [exists],
                                probe.live)
-        bcols = [c.gather(bidx, ok) for c in build.cols]
+        bcols = [take_build(c, bidx, ok) if take_build else
+                 c.gather(bidx, ok) for c in build.cols]
         out_cols = list(probe.cols) + bcols
         if join_type in ("full", "right"):
             live1 = probe.live if join_type == "full" \
@@ -1730,8 +2099,11 @@ def execute_plan_spmd(plan: P.PlanNode, conv_ctx, mesh: Mesh,
     guard tripped last): `join_probes`, {operator label: "direct" |
     "search" | "direct k/n"} for every K=1 join; `agg_inputs`, {operator
     label: input "compact" | "full" | "compact k/n", the width its body
-    ran at, live rows, capacity} for every aggregate that chose
-    (`_agg_input_marks`); `segments`, the
+    ran at, live rows, capacity} for every aggregate that chose and
+    `join_chains`, {its first join's label: chain "compact" | "full" |
+    "compact k/n", the width its later joins ran at, live rows after the
+    first join, capacity} for every join chain that chose
+    (`_width_marks`); `segments`, the
     segment `bounds` derived in the program's trace and the `reductions`
     that took them; over more than one device also `exchanges` and
     `broadcasts`, {operator label: counts} for every boundary
@@ -2000,27 +2372,29 @@ def probe_counts(probes: Dict[str, str]) -> Dict[str, int]:
                                       for m in probes.values())}
 
 
-def _agg_input_marks(agg_box, agg_np, n_dev: int) -> Dict[str, dict]:
-    """{operator label: {"input": "compact" | "full" | "compact k/n",
-    "rows": width, "live": rows, "capacity": slots, "cap": rung}} for the
-    aggregates of one run that were traced with a choice: `agg_np` holds,
-    per aggregate, how many of the `n_dev` devices compacted its input,
-    the input's live rows over all devices, and how many devices ran the
-    body at each of its widths (`rows`: the width the body ran at, or
-    `a/b` where devices differ; `capacity`: the input's slots over all
-    devices; `cap`: the rows a device its output is cut to where its
-    input is larger, the capacity ladder's rung)."""
-    counts = iter(np.asarray(agg_np).tolist() if agg_np is not None else ())
+def _width_marks(box, counts_np, n_dev: int, side: str) -> Dict[str, dict]:
+    """{operator label: {side: "compact" | "full" | "compact k/n",
+    "rows": width, "live": rows, "capacity": slots}} for the operators of
+    one run that were traced with a choice of width — the aggregates
+    (`side` "input"; each also with `cap`, the rows a device its output is
+    cut to where its input is larger, the capacity ladder's rung) or the
+    join chains (`side` "chain", under their first join's label):
+    `counts_np` holds, per operator, how many of the `n_dev` devices
+    compacted, the live rows over all devices, and how many devices ran at
+    each of its widths (`rows`: the width they ran at, or `a/b` where
+    devices differ; `capacity`: the slots over all devices)."""
+    counts = iter(np.asarray(counts_np).tolist()
+                  if counts_np is not None else ())
     marks = {}
-    for what in agg_box:
+    for what in box:
         k, live = next(counts), next(counts)
         ran = [w for w in what["widths"] if next(counts)]
         marks[what["label"]] = {
-            "input": "compact" if k == n_dev else
+            side: "compact" if k == n_dev else
             "full" if k == 0 else f"compact {k}/{n_dev}",
             "rows": ran[0] if len(ran) == 1 else "/".join(map(str, ran)),
             "live": live, "capacity": what["capacity"],
-            "cap": what["cap"]}
+            **({"cap": what["cap"]} if "cap" in what else {})}
     return marks
 
 
@@ -2039,6 +2413,15 @@ def agg_input_counts(aggs: Dict[str, dict]) -> Dict[str, int]:
                                       for a in aggs.values()),
             "agg_inputs_below_cap": sum(agg_widths(a)[-1] < a["cap"]
                                         for a in aggs.values())}
+
+
+def chain_counts(chains: Dict[str, dict]) -> Dict[str, int]:
+    """The counter's two numbers: join chains traced with a choice of
+    width, and those of them whose later joins every device ran at a
+    rung."""
+    return {"join_chains": len(chains),
+            "join_chains_compact": sum(c["chain"] == "compact"
+                                       for c in chains.values())}
 
 
 def segment_counts(counted: Dict[str, int]) -> Dict[str, int]:
@@ -2078,15 +2461,18 @@ def _crossing_stats(cross_box, cross_np) -> Dict[str, Dict[str, dict]]:
     return out
 
 
-def _reported(probe_box, direct_np, agg_box, agg_np, cross_box, crossed_np,
-              wide_box, segment_box, n_dev: int) -> Dict[str, Any]:
+def _reported(probe_box, direct_np, agg_box, agg_np, chain_box, chain_np,
+              cross_box, crossed_np, wide_box, segment_box,
+              n_dev: int) -> Dict[str, Any]:
     """What one run's program reported of itself, as execute_plan_spmd's
     `stats` hold it; `wide_columns` (operator label -> wide decimal
     columns in its output) only where the program held one; `segments`,
     the segment bounds derived while it was traced and the reductions
     that took them (`segments.counting`)."""
     return {"join_probes": _probe_marks(probe_box, direct_np, n_dev),
-            "agg_inputs": _agg_input_marks(agg_box, agg_np, n_dev),
+            "agg_inputs": _width_marks(agg_box, agg_np, n_dev, "input"),
+            "join_chains": _width_marks(chain_box, chain_np, n_dev,
+                                        "chain"),
             "segments": dict(segment_box),
             **({"wide_columns": dict(wide_box)} if wide_box else {}),
             **_crossing_stats(cross_box, crossed_np)}
@@ -2138,11 +2524,12 @@ def ingest_totals(stats: Dict[str, Any]) -> Dict[str, int]:
 def stage_totals(stats: Dict[str, Any]) -> Dict[str, Any]:
     """execute_plan_spmd's `stats` as query totals: what the scan tasks
     read, the probe counter's two numbers, the aggregate inputs' three, the
-    segment bounds' two, the ladder's rung and the wide decimal columns,
-    and the boundaries' counts."""
+    join chains' two, the segment bounds' two, the ladder's rung and the
+    wide decimal columns, and the boundaries' counts."""
     return {**ingest_totals(stats),
             **probe_counts(stats.get("join_probes") or {}),
             **agg_input_counts(stats.get("agg_inputs") or {}),
+            **chain_counts(stats.get("join_chains") or {}),
             **segment_counts(stats.get("segments") or {}),
             **wide_totals(stats),
             **crossing_totals(stats)}
@@ -2374,6 +2761,8 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         cross_box: List[Dict[str, Any]] = []
         # and of each aggregate traced with a choice of input
         agg_box: List[Dict[str, Any]] = []
+        # and of each join chain traced with a choice of width
+        chain_box: List[Dict[str, Any]] = []
         # operator label -> wide decimal columns in its output
         wide_box: Dict[str, int] = {}
         # segment bounds derived in the trace, and reductions over them
@@ -2402,6 +2791,7 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                                  for label, flag in tracer.probes)
                 cross_box.extend(what for what, _n in tracer.crossings)
                 agg_box.extend(what for what, _n in tracer.agg_inputs)
+                chain_box.extend(what for what, _n in tracer.join_chains)
                 wide_box.update(tracer.wide_columns)
             with jax.named_scope("epilogue"):
                 guards = jnp.stack(tracer.guards) if tracer.guards else \
@@ -2428,6 +2818,10 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 agg_compact = jnp.concatenate(
                     [n for _what, n in tracer.agg_inputs]) \
                     if tracer.agg_inputs else None
+                # per join chain traced with a choice, likewise
+                chain_counts = jnp.concatenate(
+                    [n for _what, n in tracer.join_chains]) \
+                    if tracer.join_chains else None
                 cols, live = out.cols, out.live
                 count = jnp.sum(live.astype(jnp.int32))[None]
                 # compact live rows to the shard front so the host
@@ -2440,17 +2834,17 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
                 live = ok
             return (cols, live, count, guards, retry_guards,
                     shrink_guards, join_guards, probe_direct, crossed,
-                    agg_compact)
+                    agg_compact, chain_counts)
 
         shard = jitcheck.site("spmd.stage").jit(jax.shard_map(
             program, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: PS(axis), host_inputs),),
             out_specs=(PS(axis), PS(axis), PS(axis), PS(), PS(), PS(),
-                       PS(), PS(), PS(), PS()),
+                       PS(), PS(), PS(), PS(), PS()),
             check_vma=False))
     else:
-        (shard, schema_box, probe_box, cross_box, agg_box, wide_box,
-         segment_box) = cached
+        (shard, schema_box, probe_box, cross_box, agg_box, chain_box,
+         wide_box, segment_box) = cached
 
     # jax.jit is lazy: on a cache miss the first call below traces +
     # compiles the whole stage program, so the span is the compile span;
@@ -2460,12 +2854,12 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
             "spmd.compile" if cached is None else "spmd.run",
             cat="spmd", devices=n_dev):
         (out_cols, out_live, counts, guards, retry_guards, shrink_guards,
-         join_guards, probe_direct, crossed, agg_compact) = \
+         join_guards, probe_direct, crossed, agg_compact, chain_counts) = \
             shard(host_inputs)
     if cached is None:
         _PROGRAM_CACHE[cache_key] = (shard, schema_box, probe_box,
-                                     cross_box, agg_box, wide_box,
-                                     segment_box)
+                                     cross_box, agg_box, chain_box,
+                                     wide_box, segment_box)
     out_schema = schema_box[0]
 
     from auron_tpu.ops.kernel_cache import host_sync
@@ -2478,12 +2872,13 @@ def _execute_plan_spmd_once_impl(plan: P.PlanNode, conv_ctx, mesh: Mesh,
         # the stage program (`spmd.run` was its enqueue).
         with tracing.span("spmd.wait", cat="spmd") as sp:
             (counts_np, guards_np, retry_np, shrink_np, join_np,
-             direct_np, crossed_np, agg_np) = host_sync(
+             direct_np, crossed_np, agg_np, chain_np) = host_sync(
                 (counts, guards, retry_guards, shrink_guards,
-                 join_guards, probe_direct, crossed, agg_compact))
+                 join_guards, probe_direct, crossed, agg_compact,
+                 chain_counts))
             reported = _reported(probe_box, direct_np, agg_box, agg_np,
-                                 cross_box, crossed_np, wide_box,
-                                 segment_box, n_dev)
+                                 chain_box, chain_np, cross_box, crossed_np,
+                                 wide_box, segment_box, n_dev)
             sp.set_args(**stage_totals(reported))
         if stats is not None:
             # before the guards: a tripped exchange guard's fill is what

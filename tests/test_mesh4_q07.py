@@ -312,6 +312,11 @@ def test_counters_reach_the_record_the_span_and_explain_analyze(runs):
     assert res.stage_totals() == {k: totals[k] for k in res.stage_totals()}
     assert set(_TOTALS) <= set(totals)
     assert (totals["join_probes"], totals["join_probes_direct"]) == (4, 4)
+    # 10,000 fact rows a device in 16,384 slots: the chain's one rung is
+    # 2,048 rows (a sixty-fourth, 256, is under the least bucket)
+    assert (totals["join_chains"], totals["join_chains_compact"]) == (1, 1)
+    assert res.stage_stats["join_chains"]["broadcast_join#11"]["rows"] == \
+        2048
     [ex] = res.stage_stats["exchanges"].values()
     assert totals["exchange_rows"] == ex["rows"] >= res.table.num_rows
     assert totals["broadcast_rows"] == sum(
@@ -344,18 +349,23 @@ def test_one_device_counts_nothing(runs):
     narrower than both aggregates' 65,536-row inputs (the upper one,
     65,536, is not), so each chooses between the two widths, and the few
     dozen live rows take the rung.  Two aggregates, two bodies each: four
-    derivations of segment bounds for thirty-two reductions."""
+    derivations of segment bounds for thirty-two reductions.  The four
+    joins are a chain over store_sales' scan: the few hundred rows the
+    first one keeps run the three later ones at the rung of 1,024."""
     _cat, _params, session, plan, _got = runs[SEEDS[0]]
     with config.conf.scoped({"auron.trace.enable": True}):
         one = session.execute(plan, mesh=data_mesh(1))
-    assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_probes",
-                                       "segments", "shard"]
+    assert sorted(one.stage_stats) == ["agg_inputs", "ingest", "join_chains",
+                                       "join_probes", "segments", "shard"]
     aggs = one.stage_stats["agg_inputs"]
     assert sorted(aggs) == ["agg#1", "agg#3"]
     for a in aggs.values():
         assert (a["input"], a["rows"], a["capacity"], a["cap"]) == \
             ("compact", 8192, 65536, 262144) and 0 < a["live"] <= 8192
     assert one.stage_stats["segments"] == {"bounds": 4, "reductions": 32}
+    [(label, chain)] = one.stage_stats["join_chains"].items()
+    assert (label, chain["chain"], chain["rows"], chain["capacity"]) == \
+        ("broadcast_join#11", "compact", 1024, 65536)
     # the driver's own count of what the scan leaves' tasks read (PR 31)
     assert one.stage_stats["ingest"]["scans"] == 5
     assert one.stage_stats["ingest"]["device_batches"] == 0

@@ -30,7 +30,13 @@ from test_spmd_stage import _lowered_join_text
 # the counter says which each device took; query 7's two aggregates, which
 # chose nothing at `rehearse_rows`, now choose between a rung of 8,192 rows
 # and their 65,536-row inputs) and added `agg-chooses-rungs`; the two that
-# choose nothing and the three join programs are what they were.
+# choose nothing and the three join programs are what they were.  The join
+# chain moved `q07-one-device` alone: its four joins are a chain over the
+# scan of store_sales, so the program counts the rows the first join left and
+# runs the three later joins at the narrowest of 1,024 rows, 8,192 rows or
+# the scan's 65,536 that holds them (a `lax.switch`; the later joins' build
+# halves before it, their probe halves in each side).  It added
+# `q01-one-device`, whose text is its parent's: query 1 has no such chain.
 CHIP_PROGRAM = {
     "agg-input-within-target":
         "a46c6612703b3eea44126a17fb0994671808bf1b61d0ed5093a8e4e5bf1be517",
@@ -49,28 +55,40 @@ CHIP_PROGRAM = {
     "join-int64":
         "8b8593b5ab5b827288b42aaae7be5df7dff916cf3be418b68eafc2329a789009",
     "q07-one-device":
-        "675865dbb1d921827538059c5d939347cac44e9f22c0c742df7de6572743fdd0",
+        "d44b9c1f43037e5c0d50e9072cefe450ddea7f7f95efdc8b4382bbabe55cae6b",
+    "q01-one-device":
+        "7ba7016d1ab1d43fd962f589c7d1eb094d2e603013fd6b16b6ffd05f7a94a8c6",
 }
 
 
-def _q07_text(tmp_path):
-    """The benchmark's query 7 at its configuration's `rehearse_rows`, as
-    the session converts it, on one device."""
+def _query_text(cell_name, query, tmp_path):
+    """A benchmark cell's query at its configuration's `rehearse_rows`, with
+    the traffic's first parameter set, as the session converts it, on one
+    device."""
     from stage_spy import spied_program
     from auron_tpu.frontend import converters, strategy
     from auron_tpu.frontend.converters import ConvertContext
     from benchmarks.harness import cells, datagen
-    from benchmarks.queries import q07
-    cell = cells.load_cell("tpcds-sf1.q07")
-    cat = datagen.generate(str(tmp_path), q07.SCANS,
+    cell = cells.load_cell(cell_name)
+    cat = datagen.generate(str(tmp_path), query.SCANS,
                            cell.config["rehearse_rows"],
                            cell.config["data_seed"], 5)
-    plan = q07.build_plan(cat, cell.traffic["param_sets"][0])
+    plan = query.build_plan(cat, cell.traffic["param_sets"][0])
     ctx = ConvertContext()
     converted = converters.convert_recursively(plan, strategy.apply(plan),
                                                ctx)
     program, inputs = spied_program(converted, ctx, data_mesh(1), {})
     return program.lower(inputs).as_text()
+
+
+def _q07_text(tmp_path):
+    from benchmarks.queries import q07
+    return _query_text("tpcds-sf1.q07", q07, tmp_path)
+
+
+def _q01_text(tmp_path):
+    from benchmarks.queries import q01
+    return _query_text("tpcds-sf10.q01", q01, tmp_path)
 
 
 _TEXT = {
@@ -89,6 +107,9 @@ _TEXT = {
     "join-two-keys": lambda _tmp: _lowered_join_text("two-keys", n_dev=1),
     "join-int64": lambda _tmp: _lowered_join_text("int64", n_dev=1),
     "q07-one-device": _q07_text,
+    # query 1's template plan: two joins over scans that feed aggregates,
+    # and joins over an aggregate's output — no chain over a source
+    "q01-one-device": _q01_text,
 }
 
 
@@ -109,10 +130,11 @@ def test_default_program_is_the_chips_program(case, tmp_path):
     assert hashlib.sha256(text.encode()).hexdigest() == CHIP_PROGRAM[case]
 
 
-# the K = 1 joins of each program: the search side of `_lookup_adaptive`
-# holds the one `searchsorted` a join keeps
+# the `searchsorted` calls of each program: the search side of a K = 1
+# join's lookup holds the one a join keeps — query 7's first join one, and
+# each of its three later joins one in each of the chain's three sides
 _JOINS = {"join-string": 1, "join-two-keys": 1, "join-int64": 1,
-          "q07-one-device": 4}
+          "q07-one-device": 1 + 3 * 3, "q01-one-device": 5}
 
 
 @pytest.mark.parametrize("case", sorted(CHIP_PROGRAM))
@@ -137,9 +159,14 @@ _SIDES = {
     "agg-chooses-four-devices": [2, 2],
     # 32, 256, 1,024 or the input's 8,192; 32, 256 or the input's 1,024
     "agg-chooses-rungs": [4, 3],
-    # four probes; `agg#3` and `agg#1`, each the hint's rung of 8,192 rows
-    # or its input's 65,536 (its other rung, 65,536, is no narrower)
-    "q07-one-device": [2, 2, 2, 2, 2, 2],
+    # the first join's probe; the three later joins' build halves; the
+    # chain's 1,024, 8,192 or 65,536 rows, each side holding the later
+    # joins' probe halves; `agg#3` and `agg#1`, each the hint's rung of
+    # 8,192 rows or its input's 65,536 (its other rung, 65,536, is no
+    # narrower)
+    "q07-one-device": [2, 2, 2, 2, 3] + [2, 2, 2] * 3 + [2, 2],
+    # five probes and six aggregates' choices, in the text's order
+    "q01-one-device": [2] * 11,
 }
 
 

@@ -178,6 +178,9 @@ def test_a_leaf_over_the_budget_stays_beside_its_plans_leaves(tmp_path):
             "join_probes": 2, "join_probes_direct": 2,
             "agg_inputs": 0, "agg_inputs_compact": 0,
             "agg_inputs_below_cap": 0,
+            # the two joins are a chain over the fact source, whose few
+            # live rows run the second join at a rung
+            "join_chains": 1, "join_chains_compact": 1,
             # the plan holds no aggregate
             "segment_bounds": 0, "segment_reductions": 0}
         assert S.stage_totals(first)["scan_cached"] == 0

@@ -393,3 +393,66 @@ def test_aggregate_body_at_every_rung_inside_a_switch(one_chip, tpu_branches,
                 [at(r, t, n_live) for r in RUNGS])
     _compile(either, [column(f.dtype) for f in fields],
              _shape(one_chip, LADDER_CAP, jnp.bool_))
+
+
+def test_join_chain_rung_sides_inside_a_switch(one_chip, tpu_branches,
+                                               no_persistent_cache):
+    """A join chain's choice of width (parallel/stage.py `_join_chain`): the
+    live rows of a `CAP`-row table compacted to a rung — a sixty-fourth or
+    an eighth of it — or left as they are, and a later join's probe half
+    there, each width a branch of one `lax.switch`: the direct gather and
+    the search with its exact-key filter under the `lax.cond` on `dense`,
+    then the join's output — a string of the build side's among it, as
+    columns of 32-bit words — padded back to `CAP`.  The build half (the
+    key range, the direct table or the sorted hashes of a `SORT_CAP`-row
+    build side, the duplicate-key trip) lies before the switch."""
+    from jax import lax
+    from auron_tpu.columnar.batch import DeviceStringColumn
+    from auron_tpu.ir.schema import Field, Schema
+    from auron_tpu.ops.segments import inside_branch
+    from auron_tpu.parallel.stage import (
+        DeviceTable, _StageTracer, _as_bytes, _as_words, _chain_rungs,
+        _compact_front, _pad_rows, _take_rows,
+    )
+    tracer = _StageTracer(None, {}, None, 1, {})
+    text = DataType.string()
+    probe_schema = Schema((Field("k", I64), Field("a", F64)))
+    build_schema = Schema((Field("bk", I64), Field("w", F64),
+                           Field("id", text)))
+    rungs = _chain_rungs(CAP)
+    assert rungs == [CAP // 64, CAP // 8]
+
+    def chain(cols, live, bcols, blive):
+        t = DeviceTable(probe_schema, cols, live)
+        build = DeviceTable(build_schema, bcols, blive)
+        c = tracer._chain_build_half("join", build, bcols[:1], [I64])
+        n_live = jnp.sum(live.astype(jnp.int32))
+
+        def at(width):
+            def side():
+                probe = t if width == CAP else \
+                    _compact_front(t, n_live, width)
+                bidx, ok = tracer._chain_lookup(probe, probe.cols[:1], c)
+                out = tracer._join_emit(probe, build, bidx, ok, "inner",
+                                        "exists", take_build=_take_rows)
+                return _pad_rows((_as_words(out.cols), out.live), CAP)
+            return side
+        with inside_branch():
+            words, live = lax.switch(
+                sum((n_live > r).astype(jnp.int32) for r in rungs),
+                [at(r) for r in rungs + [CAP]])
+        return _as_bytes(words), live, c.trip
+    compiled = _compile(
+        chain, [_column(one_chip, I64, CAP),
+                _column(one_chip, F64, CAP, exact_bits=True)],
+        _shape(one_chip, CAP, jnp.bool_),
+        [_column(one_chip, I64, SORT_CAP), _column(one_chip, F64, SORT_CAP),
+         DeviceStringColumn(
+             text, jax.ShapeDtypeStruct((SORT_CAP, 16), jnp.uint8,
+                                        sharding=one_chip),
+             _shape(one_chip, SORT_CAP, jnp.int32),
+             _shape(one_chip, SORT_CAP, jnp.bool_))],
+        _shape(one_chip, SORT_CAP, jnp.bool_))
+    text = compiled.as_text()
+    # the switch's three sides and each side's choice of probe
+    assert text.count(" conditional(") >= 4
